@@ -341,9 +341,7 @@ def _sp_update_shardmap(env, cfg, mesh, axis_name=None, dp_axis_name=None):
         metrics = {k: pmesh.pmean(v, reduce_axes) for k, v in metrics.items()}
         return params, opt_state, metrics
 
-    from actor_critic_tpu.parallel.mesh import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         local_update,
         mesh=mesh,
         in_specs=(P(), P(), traj_spec, boot_spec),
@@ -384,7 +382,7 @@ def make_sp_train_step(
         # layout back into the per-step vmap (explicit-mesh axes are
         # part of the value types).
         rollout_in = jax.tree.map(
-            lambda x: pmesh.reshard(x, NamedSharding(mesh, P())),
+            lambda x: jax.sharding.reshard(x, NamedSharding(mesh, P())),
             state.rollout,
         )
         new_rollout, traj = rollout_scan(
@@ -403,7 +401,7 @@ def make_sp_train_step(
         # mesh axes are Explicit-typed, so `reshard` is the constraint
         # API — with_sharding_constraint only talks to Auto axes.)
         traj_sp = jax.tree.map(
-            lambda x: pmesh.reshard(
+            lambda x: jax.sharding.reshard(
                 x,
                 NamedSharding(
                     mesh,

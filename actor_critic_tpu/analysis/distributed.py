@@ -210,9 +210,8 @@ def _swallowing_try(
 ) -> Optional[ast.excepthandler]:
     """The first exception handler that would swallow an error raised
     at `node`: no `raise` in its body AND no collective of its own (a
-    handler performing the equivalent exchange — mesh.axis_size's
-    psum-fallback compat shim — keeps the fleet's collective count in
-    step). Only `try` bodies between the node and its enclosing def
+    handler performing the equivalent exchange keeps the fleet's
+    collective count in step). Only `try` bodies between the node and its enclosing def
     count."""
     site_nodes = [s.node for s in sites]
     child = node

@@ -211,8 +211,6 @@ def make_dp_train_step(
     [ndev] key batch; the wrapper unwraps it. `specs` defaults to the
     on-policy TrainState layout.
     """
-    from actor_critic_tpu.parallel.mesh import shard_map
-
     if specs is None:
         specs = train_state_specs()
 
@@ -221,7 +219,7 @@ def make_dp_train_step(
         new_state, metrics = train_step(state)
         return _set_key(new_state, _get_key(new_state)[None]), metrics
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(specs,),

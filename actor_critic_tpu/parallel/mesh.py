@@ -31,42 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DP_AXIS = "dp"
 MODEL_AXIS = "model"
 
-try:
-    # jax >= 0.5 promotes shard_map to the top level with the
-    # `check_vma` spelling; prefer it when present.
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        """Compat wrapper: jax 0.4.x exposes shard_map under
-        `jax.experimental` and calls the replication check `check_rep`."""
-        return _shard_map_exp(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-
-def reshard(x, sharding):
-    """Compat for `jax.sharding.reshard` (jax >= 0.6 explicit-mesh
-    constraint API): on older jax the mesh axes are Auto-typed, where
-    `with_sharding_constraint` expresses the same in-program
-    redistribution."""
-    try:
-        return jax.sharding.reshard(x, sharding)
-    except AttributeError:
-        return jax.lax.with_sharding_constraint(x, sharding)
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a mapped axis; `jax.lax.axis_size` where it exists,
-    else the `psum(1, axis)` idiom (constant-folded to a python int)."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """How to lay the process's devices out as a mesh."""

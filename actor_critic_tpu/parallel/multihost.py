@@ -63,7 +63,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 from actor_critic_tpu.algos.traj_queue import _snapshot_frozen
-from actor_critic_tpu.parallel.mesh import DP_AXIS, multihost_init, shard_map
+from actor_critic_tpu.parallel.mesh import DP_AXIS, multihost_init
 from actor_critic_tpu.utils import numguard
 
 
@@ -511,7 +511,7 @@ def make_multihost_update_step(
             P(),                                    # progress scalar
         )
         out_specs = (specs_of(params, P()), specs_of(opt_state, P()), P())
-        fn = shard_map(
+        fn = jax.shard_map(
             local_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
@@ -609,7 +609,7 @@ def make_consistency_check(mesh) -> Callable[..., tuple]:
         return jnp.stack([vsum, fp_max, fp_min, votes])
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             reduce_fn,
             mesh=mesh, in_specs=P(DP_AXIS, None), out_specs=P(),
             check_vma=False,

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from actor_critic_tpu.ops import returns
-from actor_critic_tpu.parallel import mesh as mesh_lib
 
 SP_AXIS = "sp"
 
@@ -57,7 +56,7 @@ def _halo_from_next(x_first, bootstrap, axis_name):
     leaves unaddressed receivers (the last device) at zero, which the
     `where` on the axis index then replaces.
     """
-    n = mesh_lib.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, i - 1) for i in range(1, n)]
     received = jax.lax.ppermute(x_first, axis_name, perm)
@@ -74,7 +73,7 @@ def _solve_boundary_chain(a_seg, b_seg, y_init, axis_name):
     whole chain redundantly (replicated compute beats a sequential
     D-step ppermute pipeline at these sizes, and XLA dedupes it).
     """
-    n = mesh_lib.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     a_all = jax.lax.all_gather(a_seg, axis_name)  # [D, ...] in time order
     b_all = jax.lax.all_gather(b_seg, axis_name)
@@ -215,7 +214,7 @@ def make_seqpar_fn(fn, mesh: Mesh, n_time_sharded_args: int, axis_name: str = SP
         rest = args[n_time_sharded_args:]
         in_specs = (time_spec,) * len(sharded) + (rep,) * len(rest)
 
-        shmapped = mesh_lib.shard_map(
+        shmapped = jax.shard_map(
             partial(fn, axis_name=axis_name),
             mesh=mesh,
             in_specs=in_specs,

@@ -24,7 +24,7 @@ act() over stdlib HTTP.
 
 Like the telemetry exporter, the server is a `ThreadingHTTPServer`
 daemon bound to 127.0.0.1 by default — remote traffic arrives through
-whatever tunnel/LB fronts the host. HTTP/1.1 keep-alive is on: a
+whatever proxy/LB fronts the host. HTTP/1.1 keep-alive is on: a
 closed-loop client reuses its connection, so the measured serving
 latency is the gateway's, not per-request TCP setup.
 """

@@ -1,7 +1,7 @@
 """Unified run telemetry (ISSUE 1): phase spans, resource sampling, and
 health events behind one `TelemetrySession`.
 
-The framework could already *detect* a wedged device tunnel
+The framework could already *detect* a device call that never returns
 (utils/watchdog.py) and log scalar metrics (utils/logging.py); this
 package is the layer that can *explain* a run — which loop phase
 stalled, whether device memory crept, when throughput regressed:

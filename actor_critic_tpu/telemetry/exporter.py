@@ -12,8 +12,8 @@ questions PR 1's telemetry could only answer post-mortem from files:
     GET /healthz        JSON liveness: uptime, watchdog staleness, the
                         innermost open telemetry span, age of the last
                         logged row. HTTP 503 once the watchdog is past
-                        its timeout — `curl -f` probing from
-                        scripts/tpu_watch.sh-style watchers just works.
+                        its timeout — `curl -f` probing from a
+                        shell watcher just works.
     GET /profile?iters=N   Arm an on-demand windowed jax.profiler
                         capture (telemetry/profiler.py): the next N
                         training iterations are traced into
@@ -22,8 +22,7 @@ questions PR 1's telemetry could only answer post-mortem from files:
 
 Enabled by `train.py --telemetry-port PORT` (0 picks an ephemeral port,
 printed at startup and recorded as an `exporter_start` event). Binds
-127.0.0.1 — remote scraping goes through an SSH tunnel like everything
-else on these machines.
+127.0.0.1 — remote scraping goes through SSH port forwarding.
 """
 
 from __future__ import annotations
@@ -56,7 +55,7 @@ def validate_bind(host: str, distributed: bool = False) -> str:
         raise ValueError(
             f"refusing non-loopback telemetry bind {host!r} without "
             "--distributed: /metrics exposes process internals with no "
-            "auth — bind 127.0.0.1 and scrape through an SSH tunnel, "
+            "auth — bind 127.0.0.1 and scrape through SSH port forwarding, "
             "or pass --distributed for a fleet whose ranks scrape "
             "each other"
         )

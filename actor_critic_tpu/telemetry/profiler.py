@@ -274,14 +274,36 @@ def _module_name(computation) -> str:
         return "?"
 
 
+def _mosaic_calls(computation) -> int:
+    """How many Mosaic (Pallas TPU) kernels the module about to be
+    compiled launches: `stablehlo.custom_call`s whose target is
+    `tpu_custom_call`. Lets a caller tell a program that carries the
+    compiled kernel from one that fell back to `lax.scan` or runs the
+    interpreter (both lower to plain HLO)."""
+    from jax._src.lib.mlir import ir
+
+    count = 0
+
+    def visit(op):
+        nonlocal count
+        if (
+            op.name == "stablehlo.custom_call"
+            and "tpu_custom_call" in str(op.attributes["call_target_name"])
+        ):
+            count += 1
+        return ir.WalkResult.ADVANCE
+
+    computation.operation.walk(visit)
+    return count
+
+
 def _cost_fields(executable) -> dict:
     """FLOPs / bytes-accessed from the loaded executable's
-    cost_analysis(); absent (not zero) where the backend reports none."""
+    cost_analysis() (one dict in the installed jax); absent (not zero)
+    where the backend reports none."""
     out: dict = {}
     try:
         ca = executable.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         flops = ca.get("flops")
         if flops:
             out["flops"] = float(flops)
@@ -323,6 +345,7 @@ def ensure_compile_introspection() -> bool:
             # keyword-only must still compile — introspection extracts
             # what it can and never changes the call.
             name = sig = None
+            mosaic = 0
             try:
                 computation = kwargs.get("computation", None)
                 if computation is None and len(args) > 1:
@@ -330,11 +353,12 @@ def ensure_compile_introspection() -> bool:
                 if computation is not None:
                     name = _module_name(computation)
                     sig = _signature_of(computation)
+                    mosaic = _mosaic_calls(computation)
             except Exception:
                 pass
-            from actor_critic_tpu.utils.compile_cache import cache_stats
+            from actor_critic_tpu.utils.compile_cache import thread_cache_hits
 
-            hits_before = cache_stats()["hits"]
+            hits_before = thread_cache_hits()
             t0 = time.perf_counter()
             executable = original(*args, **kwargs)
             record = {
@@ -342,13 +366,15 @@ def ensure_compile_introspection() -> bool:
                 "compile_s": round(time.perf_counter() - t0, 4),
                 **_cost_fields(executable),
             }
-            # Persistent-cache attribution: a hit event during the call
-            # means this "compile" deserialized a cached executable, not
-            # recompiled (concurrent compiles — e.g. the AOT warmup
-            # thread — can misattribute a hit across threads; that skews
-            # report cosmetics only, never the run).
-            if cache_stats()["hits"] > hits_before:
+            # Persistent-cache attribution: a hit event on THIS thread
+            # during the call means this "compile" deserialized a cached
+            # executable, not recompiled (the event fires on the
+            # compiling thread, so the AOT warmup thread's hits are
+            # never counted here).
+            if thread_cache_hits() > hits_before:
                 record["cache_hit"] = True
+            if mosaic:
+                record["mosaic_calls"] = mosaic
             if sig is not None:
                 record["signature"] = sig[:2000]
             _record_compile(record)
@@ -377,3 +403,14 @@ def compile_records() -> list[dict]:
     """Recent structured compile records (process-global ring)."""
     with _compile_lock:
         return list(_compile_records)
+
+
+def compile_records_since(count0: int) -> list[dict]:
+    """The records of the compiles after the `compile_event_count()`
+    snapshot `count0` (the newest `_COMPILE_RING_MAX` of them at most).
+    Indexed back from the MONOTONIC counter: the ring is capped, so in a
+    long process len(records) sits at capacity and slicing by list length
+    would silently return []."""
+    with _compile_lock:
+        delta = _compile_total - count0
+        return _compile_records[-delta:] if delta else []

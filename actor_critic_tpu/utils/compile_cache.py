@@ -9,11 +9,14 @@ module is the prevention layer, three parts:
 1. **Persistent compilation cache** (`enable_persistent_cache`): JAX's
    on-disk executable cache (`jax_compilation_cache_dir`) with the
    min-compile-time/min-entry-size floors dropped to zero so every
-   program is cached. `train.py --compile-cache-dir` wires it; the
-   default is a sidecar under the checkpoint dir (`<ckpt>/xla_cache`) so
-   the legs of one resumable run share it. Hit/miss counts ride the
-   `jax.monitoring` cache events into `cache_stats()` (exported at
-   `/metrics`, attributed per-function in `run_report.py`).
+   program is cached. Where it lives is decided in ONE place,
+   `resolve_cache_dir`: `JAX_COMPILATION_CACHE_DIR` when the environment
+   sets it, else `<checkout>/.jax_cache` — a fixed path, because the
+   path is part of what makes a later process find the entries again.
+   `train.py`, `scripts/serve.py` and `chip_smoke.py` all go through it.
+   Hit/miss counts ride the `jax.monitoring` cache events into
+   `cache_stats()` (exported at `/metrics`, attributed per-function in
+   `run_report.py`).
 
 2. **AOT warmup registry** (`register_warmup` / `start_warmup`): each
    jitted entry point in `algos/` registers a *planner* that derives the
@@ -63,6 +66,11 @@ from typing import Any, Callable, Optional
 # so registration is once-per-process and the counts only grow.
 _CACHE_STATS = {"hits": 0, "misses": 0}
 _stats_lock = threading.Lock()
+# The same events counted per compiling thread: a cache event fires on
+# the thread inside the compile call, so a per-thread count attributes a
+# hit to THAT compile even while the AOT warmup thread compiles beside
+# the training thread (telemetry/profiler.py reads it).
+_thread_stats = threading.local()
 _stats_installed = False
 _enabled_dir: Optional[str] = None
 
@@ -73,6 +81,7 @@ def _on_cache_event(name: str, **kwargs) -> None:
     # shared counters loses increments. Events are rare; the lock is
     # noise-level.
     if name.endswith("/cache_hits"):
+        _thread_stats.hits = getattr(_thread_stats, "hits", 0) + 1
         with _stats_lock:
             _CACHE_STATS["hits"] += 1
     elif name.endswith("/cache_misses"):
@@ -102,6 +111,11 @@ def cache_stats() -> dict:
     return dict(_CACHE_STATS)
 
 
+def thread_cache_hits() -> int:
+    """Persistent-cache hits observed on the CALLING thread."""
+    return getattr(_thread_stats, "hits", 0)
+
+
 def enabled_dir() -> Optional[str]:
     """The cache directory this process enabled, or None."""
     return _enabled_dir
@@ -125,11 +139,7 @@ def enable_persistent_cache(cache_dir: str | os.PathLike) -> str:
     # even the small compiles (dozens of sub-second utility jits add up
     # on a 1-core host).
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass  # flag spelling varies across jax versions; the dir + time
-        # floor are the load-bearing settings
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _reset_jax_cache_state()
     ensure_cache_stats_listener()
     _enabled_dir = cache_dir
@@ -141,13 +151,10 @@ def _reset_jax_cache_state() -> None:
     handle are evaluated ONCE per process at the first compile — a
     process that compiled anything before `enable_persistent_cache`
     (test suites, import-time jits) would silently keep the cache
-    disabled forever without this. Best-effort internal API."""
-    try:
-        from jax._src import compilation_cache as _cc
+    disabled forever without this. Internal API of the installed jax."""
+    from jax._src import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:
-        pass
+    _cc.reset_cache()
 
 
 class temporary_cache:
@@ -162,13 +169,11 @@ class temporary_cache:
         import jax
 
         self._prev = jax.config.jax_compilation_cache_dir
-        self._prev_floors = {}
-        for flag in ("jax_persistent_cache_min_compile_time_secs",
-                     "jax_persistent_cache_min_entry_size_bytes"):
-            try:
-                self._prev_floors[flag] = getattr(jax.config, flag)
-            except AttributeError:
-                pass
+        self._prev_floors = {
+            flag: getattr(jax.config, flag)
+            for flag in ("jax_persistent_cache_min_compile_time_secs",
+                         "jax_persistent_cache_min_entry_size_bytes")
+        }
         self._prev_enabled = _enabled_dir
         return enable_persistent_cache(self._dir)
 
@@ -181,28 +186,40 @@ class temporary_cache:
         # own cache configured must get its floors back, not keep the
         # cache-everything zeros.
         for flag, value in self._prev_floors.items():
-            try:
-                jax.config.update(flag, value)
-            except Exception:
-                pass
+            jax.config.update(flag, value)
         # Re-latch from the restored config so later compiles in this
         # process don't keep using (or skipping) the temporary dir.
         _reset_jax_cache_state()
         _enabled_dir = self._prev_enabled
 
 
-def resolve_cache_dir(
-    cli_value: Optional[str], ckpt_dir: Optional[str]
-) -> Optional[str]:
-    """`--compile-cache-dir` policy: an explicit path wins; the default
-    'auto' resolves to a `<ckpt-dir>/xla_cache` sidecar (so the legs of
-    one `run_resumable.sh` run share a cache) or to disabled when the
-    run has no checkpoint dir; 'none'/'off'/'' disable explicitly."""
-    if cli_value is None or cli_value.lower() == "auto":
-        return os.path.join(ckpt_dir, "xla_cache") if ckpt_dir else None
-    if cli_value.lower() in ("", "none", "off"):
+# <checkout>/.jax_cache, from this file's own location (utils/ → package
+# → checkout): the same directory from any working directory.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def resolve_cache_dir(cli_value: Optional[str] = None) -> Optional[str]:
+    """THE rule for where the persistent compile cache lives (train.py,
+    scripts/serve.py, chip_smoke.py; scripts/run_resumable.sh mirrors it):
+
+    - `JAX_COMPILATION_CACHE_DIR` set → that directory, whatever the
+      command line says: whoever placed the cache from outside (the chip
+      machine, CI) is the one who can find it again.
+    - not set → an explicit `--compile-cache-dir DIR`, else
+      `DEFAULT_CACHE_DIR` (`<checkout>/.jax_cache`). A cache keyed to a
+      run directory, a temporary name, a pid or a time never hits twice.
+    - 'none'/'off'/'' → None: the program enables no cache.
+    """
+    if cli_value is not None and cli_value.lower() in ("", "none", "off"):
         return None
-    return cli_value
+    return (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or cli_value
+        or DEFAULT_CACHE_DIR
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -440,6 +457,19 @@ def plan_warmup(ctx: WarmupContext) -> list[tuple[str, Callable]]:
     return out
 
 
+# Every runner this process started, oldest first. Compile failures on
+# the warmup thread are contained (a `results` row with "error"), so a
+# caller that must not miss one — chip_smoke.py — waits on these and
+# reads the rows after `train.main` returns.
+# jaxlint: thread-owned=main (appended by start() on the launching
+# thread only; readers take a snapshot)
+_STARTED_RUNNERS: list["WarmupRunner"] = []
+
+
+def started_warmups() -> tuple["WarmupRunner", ...]:
+    return tuple(_STARTED_RUNNERS)
+
+
 class WarmupRunner:
     """Background executor for one run's warmup plan.
 
@@ -462,6 +492,7 @@ class WarmupRunner:
         )
 
     def start(self) -> "WarmupRunner":
+        _STARTED_RUNNERS.append(self)
         self._thread.start()
         return self
 
@@ -481,11 +512,14 @@ class WarmupRunner:
                 _session.event("warmup_compile", **row)
             except Exception:
                 pass
+        # The thunks close over envs and jitted programs; a finished
+        # runner stays listed in _STARTED_RUNNERS and keeps only its rows.
+        self._plan = []
         self._done.set()
         try:
             _session.event(
                 "warmup_done",
-                entries=len(self._plan),
+                entries=len(self.results),
                 errors=sum(1 for r in self.results if "error" in r),
                 total_s=round(
                     sum(r.get("compile_s", 0.0) for r in self.results), 3

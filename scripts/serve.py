@@ -13,8 +13,8 @@ Checkpoints are params-only trees written by
 `serving.export_policy_params` (a training run exports its actor/policy
 params; the full trainer save tree carries optimizer/env state a server
 has no use for). Startup: the serving warmup planner AOT-compiles every
-act bucket on a background thread (`--compile-cache-dir` makes that a
-persistent-cache prewarm), then each architecture is warmed with one
+act bucket on a background thread into the persistent compile cache
+(`compile_cache.resolve_cache_dir`), then each architecture is warmed with one
 concrete dispatch per bucket BEFORE the gateway binds — steady-state
 serving is 0-recompile. `--port 0` binds an OS-assigned port and prints
 the actual one (the load generator and CI never race for a fixed port).
@@ -167,7 +167,8 @@ def main(argv=None) -> int:
     p.add_argument(
         "--compile-cache-dir", default=None,
         help="persistent XLA compile cache (warm restarts skip bucket "
-        "compiles entirely)",
+        "compiles entirely): JAX_COMPILATION_CACHE_DIR when set, else "
+        "this DIR, else <checkout>/.jax_cache; 'none' enables no cache",
     )
     p.add_argument(
         "--no-warmup", action="store_true",
@@ -265,8 +266,9 @@ def main(argv=None) -> int:
     buckets = tuple(int(b) for b in args.buckets.split(",") if b.strip())
     spec = spec_for(preset.env, preset.env_kwargs)
 
-    if args.compile_cache_dir:
-        compile_cache.enable_persistent_cache(args.compile_cache_dir)
+    cache_dir = compile_cache.resolve_cache_dir(args.compile_cache_dir)
+    if cache_dir is not None:
+        compile_cache.enable_persistent_cache(cache_dir)
 
     session = None
     if args.telemetry_dir:

@@ -5,6 +5,8 @@ first dispatch must HIT what warmup compiled), shape-stabilized chunking
 compile-count regression contract: after warmup + first dispatch, zero
 recompiles."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -45,16 +47,97 @@ def test_bucket_size_and_pad_to_bucket():
     assert same.shape == (6, 2) and mask.sum() == 6
 
 
-def test_resolve_cache_dir_policy(tmp_path):
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_resolve_cache_dir_policy(tmp_path, monkeypatch):
+    """The one rule: JAX_COMPILATION_CACHE_DIR when the environment sets
+    it (whatever the flag says), else the flag, else <checkout>/.jax_cache
+    from ANY working directory; 'none'/'off'/'' enable nothing."""
     resolve = compile_cache.resolve_cache_dir
-    ck = str(tmp_path / "ck")
-    assert resolve("auto", ck).endswith("xla_cache")
-    assert resolve("auto", None) is None
-    assert resolve(None, ck).endswith("xla_cache")
-    assert resolve("none", ck) is None
-    assert resolve("off", ck) is None
-    assert resolve("", ck) is None
-    assert resolve("/x/y", ck) == "/x/y"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)
+    assert resolve() == resolve(None) == os.path.join(REPO, ".jax_cache")
+    assert resolve("/x/y") == "/x/y"
+    for off in ("none", "NONE", "off", ""):
+        assert resolve(off) is None
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    assert resolve() == "/placed/from/outside"
+    assert resolve("/x/y") == "/placed/from/outside"
+    assert resolve("none") is None
+
+
+def test_train_main_sets_no_other_cache_dir(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, a run WITH a checkpoint dir
+    (the old `<ckpt-dir>/xla_cache` trigger) and an explicit
+    --compile-cache-dir points jax at the environment's directory and at
+    nothing else."""
+    import train
+
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    seen = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            seen.append(value)
+        return real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    # temporary_cache only as the snapshot/restore of this process's cache
+    # configuration, which train.main changes for good.
+    with compile_cache.temporary_cache(tmp_path / "snapshot"):
+        seen.clear()
+        rc = train.main([
+            "--algo", "a2c", "--env", "jax:two_state", "--iterations", "1",
+            "--set", "num_envs=4", "--set", "rollout_steps=2",
+            "--set", "hidden=8", "--quiet", "--no-warmup",
+            "--metrics", str(tmp_path / "m.jsonl"),
+            "--ckpt-dir", str(tmp_path / "ck"),
+            "--compile-cache-dir", str(tmp_path / "flag"),
+        ])
+        during_main = list(seen)
+    assert rc == 0
+    assert during_main == [placed]
+    assert not (tmp_path / "ck" / "xla_cache").exists()
+    assert not (tmp_path / "flag").exists()
+
+
+def test_run_resumable_fresh_spares_an_external_cache(tmp_path):
+    """`run_resumable.sh --fresh` wipes the compile cache the run would
+    use — unless the environment named it: a directory placed from
+    outside is never this script's to delete."""
+    import subprocess
+
+    fake_bin = tmp_path / "bin"
+    fake_bin.mkdir()
+    (fake_bin / "python").write_text("#!/bin/sh\nexit 0\n")  # stands in for train.py
+    (fake_bin / "python").chmod(0o755)
+    script = os.path.join(REPO, "scripts", "run_resumable.sh")
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PATH"] = f"{fake_bin}:{env['PATH']}"
+
+    def run(extra_env, *args):
+        cache = tmp_path / "cache"
+        cache.mkdir(exist_ok=True)
+        (cache / "entry").write_text("compiled")
+        r = subprocess.run(
+            ["bash", script, "--fresh", "--preset", "a2c_cartpole", *args],
+            env={**env, **extra_env}, capture_output=True, text=True, timeout=60,
+        )
+        assert r.returncode == 0, r.stderr
+        return (cache / "entry").exists()
+
+    cache = str(tmp_path / "cache")
+    # Named by the flag only: --fresh wipes it (the wipe itself works).
+    assert not run({}, "--compile-cache-dir", cache)
+    # Named by the environment: spared, with or without the same flag.
+    assert run({"JAX_COMPILATION_CACHE_DIR": cache})
+    assert run({"JAX_COMPILATION_CACHE_DIR": cache}, "--compile-cache-dir", cache)
+    # 'none' never resolves to a literal directory named "none".
+    assert run({}, "--compile-cache-dir", "none")
 
 
 # ------------------------------------------------------- persistent cache

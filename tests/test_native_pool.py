@@ -303,3 +303,43 @@ def test_ppo_learns_native_acrobot():
             best = max(best, m["eval_return"])
     pool.close()
     assert best >= -100.0, f"native Acrobot not learned: best eval {best}"
+
+
+def test_library_from_another_key_is_rebuilt_not_loaded(tmp_path, monkeypatch):
+    """The cached engine is keyed on source, flags and this machine's CPU
+    (`-march=native` code is only valid where it was built, and a working
+    tree can be copied whole to another machine): a library left under any
+    other key — or the pre-key `_vecenv.so` name — is never dlopen'ed; the
+    engine is rebuilt from the source and the strays are removed."""
+    import shutil
+
+    from actor_critic_tpu import native
+
+    shutil.copy(native._SRC, tmp_path / "vecenv.cpp")
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(tmp_path / "vecenv.cpp"))
+    strays = [tmp_path / "_vecenv.so", tmp_path / "_vecenv.0123456789abcdef.so"]
+    for stray in strays:
+        stray.write_bytes(b"not a shared object: dlopen would fail")
+
+    builds = []
+    real_build = native._build
+    monkeypatch.setattr(
+        native, "_build", lambda lib: (builds.append(lib), real_build(lib))
+    )
+    load = native.load.__wrapped__  # bypass the per-process lru_cache
+    lib = load()
+    here = native.lib_path()
+    assert builds == [here] and lib._name == here
+    assert hasattr(lib, "cartpole_step")
+    assert sorted(p.name for p in tmp_path.glob("_vecenv*")) == [
+        here.rsplit("/", 1)[1]
+    ], "stale libraries must not survive a build"
+    load()
+    assert builds == [here], "a library with the right key is reused"
+
+    # The same tree on another CPU: another key, so another build.
+    monkeypatch.setattr(native, "_cpu_identity", lambda: "another machine")
+    there = native.lib_path()
+    assert there != here
+    assert load()._name == there and builds == [here, there]

@@ -37,8 +37,6 @@ def test_sharded_grad_equals_full_batch_grad():
     MirroredStrategy/NCCL-equivalence property, SURVEY §2.4)."""
     from jax.sharding import PartitionSpec as P
 
-    from actor_critic_tpu.parallel.mesh import shard_map
-
     env = make_two_state_mdp()
     cfg = a2c.A2CConfig(num_envs=8, rollout_steps=4, hidden=(16,))
     net = a2c.make_network(env, cfg)
@@ -70,7 +68,7 @@ def test_sharded_grad_equals_full_batch_grad():
     g_full = loss_grads(params, traj, adv, ret)
 
     mesh = _mesh()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda p, t, a, r: loss_grads(p, t, a, r, DP_AXIS),
         mesh=mesh,
         in_specs=(P(), P(None, DP_AXIS), P(None, DP_AXIS), P(None, DP_AXIS)),

@@ -244,18 +244,18 @@ def test_measure_restores_all_seams():
     would meter (and slow) every later dispatch in the process."""
     import jax
     import jax.numpy as jnp
-    from jaxlib import xla_extension as xe
+    from jax._src.api import _post_hook_state
 
     orig = (
         jax.device_put, jax.device_get, jnp.array, jnp.asarray,
-        xe.jax_jit.global_state().post_hook,
+        _post_hook_state.get_global(),
     )
     with pytest.raises(RuntimeError):
         with perfsan.measure():
             raise RuntimeError("boom")
     now = (
         jax.device_put, jax.device_get, jnp.array, jnp.asarray,
-        xe.jax_jit.global_state().post_hook,
+        _post_hook_state.get_global(),
     )
     assert now == orig
 

@@ -113,15 +113,6 @@ def test_sp_train_step_rollout_to_update_one_program(dp_axis):
     from actor_critic_tpu.algos import impala
     from actor_critic_tpu.envs import make_two_state_mdp
 
-    if dp_axis is not None and not hasattr(jax, "shard_map"):
-        pytest.skip(
-            "jax<0.5 compat path (experimental shard_map + "
-            "with_sharding_constraint standing in for reshard): the fused "
-            "rollout on the 2-D sp×dp mesh lays the env axis out "
-            "differently, which bitwise-shifts sampled actions vs the "
-            "unsharded golden run; the sp-1d fused equivalence and the "
-            "standalone 2-D update equivalence below both still pass"
-        )
     env = make_two_state_mdp()
     # Long rollout relative to the env (horizon 8): T=64 spans many
     # episodes and divides both mesh layouts' sp size (8 and 2).
