@@ -141,6 +141,7 @@ def conduct(ctx, tee: _Tee, out: dict) -> None:
         if tracer is not None:
             tracer.finish()
             out["trace"] = tracer.reduced()
+            out["trace_path"] = tracer.path()
             tracer = None
         after = check_rows(ctx, url, params, spec)
         ctx.say(f"check after: {after}")
@@ -194,7 +195,14 @@ def run(ctx) -> dict:
     if "error" in out:
         raise harness.NoResult(3, f"serving run failed: {out['error']}")
     load, chk = out["load"], out["check"]
+    tol = harness.platform_tolerance(ctx.config, ctx.rehearsal)["act_tol"]
     return {
+        "compared": {
+            "act_err_before": [chk["before"]["act_err"], tol],
+            "act_err_after": [chk["after"]["act_err"], tol],
+            "compiles_in_window": [out["compiles_in_window"], 0],
+            "failed_requests": [load["failed"], 0],
+        },
         "correct": bool(chk["before"]["ok"] and chk["after"]["ok"]
                         and out["compiles_in_window"] == 0
                         and load["failed"] == 0 and load["ok_requests"] > 0),
@@ -212,6 +220,7 @@ def run(ctx) -> dict:
         "metrics_after": out["metrics_after"],
         "spans": harness.read_spans(telemetry_dir) if telemetry_dir else [],
         "trace": out.get("trace"),
+        "trace_path": out.get("trace_path"),
         "notes": [f"act latency: p50 {load['p50_ms']:.3f} ms, p99 "
                   f"{load['p99_ms']:.3f} ms over {load['ok_requests']} requests "
                   f"({load['requests_per_s']:.1f} requests/s, "

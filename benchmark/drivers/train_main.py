@@ -189,8 +189,16 @@ def run(ctx) -> dict:
     with open(pace_file(ctx), "w") as fh:
         json.dump({"first_iter": rows[0]["iter"],
                    "iters_per_s": (win[-1]["iter"] - win[0]["iter"]) / seconds}, fh)
+    tol = harness.platform_tolerance(ctx.config, ctx.rehearsal)
     return {
         "correct": bool(verdict["ok"] and compiles_in_window == 0 and failed == 0),
+        "compared": {
+            "adv_err": [verdict["adv_err"], tol["adv_tol"]],
+            "loss_err": [verdict["loss_err"], tol["loss_tol"]],
+            "narrow_ops": [len(verdict["narrow"]), 0],
+            "compiles_in_window": [compiles_in_window, 0],
+            "nonfinite_rows": [failed, 0],
+        },
         "attempted": win[-1]["iter"] - win[0]["iter"],
         "failed": failed,
         "end_to_end": {ctx.workload["rate_metric"]: rate},
@@ -202,5 +210,6 @@ def run(ctx) -> dict:
         "cache_stats_setup": setup_cache,
         "spans": harness.read_spans(telemetry_dir) if telemetry_dir else [],
         "trace": tracer.reduced() if tracer is not None else None,
+        "trace_path": tracer.path() if tracer is not None else None,
         "rows": win,
     }

@@ -5,9 +5,9 @@ requires is counted: the rollout's forward pass, the update's forward and
 backward pass (backward = 2 x forward), and the bootstrap forward pass once a
 trajectory. What the program recomputes or computes for convenience is not
 counted (for IMPALA: the forward pass over every step's `final_obs`, needed
-only at the rare truncated step). So `flops_util_pct` is an end-to-end
-utilization in the sense of the `on-chip-measurement` guide, section 4: it is
-not a kernel's roofline share and says nothing about idle time.
+only at the rare truncated step). So `mfu_pct` is the model FLOP/s
+utilization of the `on-chip-measurement` guide, section 4, taken end to end:
+it is not a kernel's roofline share and says nothing about idle time.
 """
 
 from __future__ import annotations

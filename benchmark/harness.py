@@ -4,8 +4,23 @@ the row watcher and the trace window.
 
 Driven by data: a cell is `workloads/<cell>.json`, which names its
 configuration (`configs/<name>.json`), its traffic mix
-(`traffic/<name>.json`), its driver (`drivers/<name>.py`) and the metrics it
-reports; a per-layer metric is `layers/<metric>.py`. Nothing here lists them.
+(`traffic/<name>.json`), its driver (`drivers/<name>.py`) and its end-to-end
+metrics; a per-layer metric is a reader, `layers/<metric>.py`. Nothing here
+lists cells, metrics or readers.
+
+Which cell reads which per-layer metric is said once (`per_layer_names`). For
+an accepted cell, one that `BENCHMARK.json` lists, the manifest says it: the
+`per_layer` entries whose `workloads` hold the cell, and the cell's own file
+has no `per_layer` key. So a per-layer metric for an accepted cell = one new
+`benchmark/layers/<name>.py` + one new entry in `BENCHMARK.json`, and no edit
+to any file that is there. A cell that is only a file (the three held out of
+the manifest by the memory floor, a test's own) differs: its file carries its
+own `per_layer` list, since no manifest entry can name it.
+
+What a reader gets: the driver's `run` dict (end-to-end numbers, rows, spans,
+compile records, the reduced trace under `trace`, and under `trace_path` the
+profiler's `.xplane.pb` itself, for a reader that reduces it its own way with
+`trace_reduce.load_xplane(run["trace_path"], ..., stats=...)`) and the `Ctx`.
 """
 
 from __future__ import annotations
@@ -40,6 +55,26 @@ def load_json(*parts: str) -> dict:
             return json.load(fh)
     except FileNotFoundError:
         raise NoResult(2, f"no file {os.path.relpath(path, ROOT)}") from None
+
+
+def load_manifest() -> dict:
+    """`BENCHMARK.json` at the root of the checkout."""
+    return load_json(os.pardir, "BENCHMARK.json")
+
+
+def per_layer_names(workload: dict) -> list[str]:
+    """The per-layer metrics a cell reads. For a cell the manifest lists:
+    its `per_layer` entries whose `workloads` hold the cell, in the
+    manifest's order (an entry without the key is read in every cell that
+    reports the end-to-end metric it moves). For a cell that is only a file:
+    the `per_layer` list of that file."""
+    manifest = load_manifest()
+    if not any(w["name"] == workload["name"] for w in manifest["workloads"]):
+        return list(workload["per_layer"])
+    reported = set(workload["end_to_end"])
+    return [e["name"] for e in manifest["per_layer"]
+            if (workload["name"] in e["workloads"] if "workloads" in e
+                else e["moves"] in reported)]
 
 
 def load_module(kind: str, name: str):
@@ -365,16 +400,21 @@ class TraceWindow:
         self._armed.set()
         self._thread.join()
 
+    def path(self) -> Optional[str]:
+        """The `.xplane.pb` of the trace taken, or None."""
+        from benchmark import trace_reduce
+
+        return trace_reduce.find_xplane(self.log_dir) if self.taken else None
+
     def reduced(self) -> Optional[dict]:
         from benchmark import trace_reduce
 
-        if not self.taken:
-            return None
-        path = trace_reduce.find_xplane(self.log_dir)
+        path = self.path()
         if path is None:
             return None
         trace = trace_reduce.load_xplane(
-            path, keep_lines=(trace_reduce.OPS_LINE, trace_reduce.MODULES_LINE))
+            path, keep_lines=(trace_reduce.OPS_LINE, trace_reduce.MODULES_LINE),
+            stats=(trace_reduce.NAME_STACK_STAT,))
         return trace_reduce.reduce(trace)
 
 

@@ -1,6 +1,7 @@
 """Operations the algorithm needs per decision (benchmark/flops.py, from
 shapes) times decisions per second of the traced run's window, over the chip's
-published bf16 peak. An end-to-end utilization, not a roofline share."""
+published bf16 peak: the whole step's model FLOP/s utilization, the share
+that bounds every claimed gain. Not a kernel's roofline share."""
 LAYER, UNIT, SOURCE = "fused trainers", "%", "host_clock"
 MOVES = "fused_steps_per_s"
 

@@ -7,11 +7,13 @@ Runs one cell on the machine it is started on and prints, as the last line of
 standard output, one JSON object with `correct`, `attempted`, `failed`,
 `metrics` and `device` (with `--trace 1` the per-layer metrics, the device's
 `busy_s` and `window_s`, and `breakdown`; with `--trace 0` the end-to-end
-metrics). It exits with another code than 0 and prints no result line when JAX
-finds no TPU or fewer chips than the cell asks for, or when the program under
-test is not beside it. `--rehearsal` walks the same control flow at tiny shapes
-on the CPU, tags every line REHEARSAL and prints no result line: it checks the
-harness, never the system's speed.
+metrics), and last `compared`: each number that decided `correct` beside its
+limit, which are also the last lines of standard error. It exits with another
+code than 0 and prints no result line when JAX finds no TPU or fewer chips
+than the cell asks for, or when the program under test is not beside it.
+`--rehearsal` walks the same control flow at tiny shapes on the CPU, tags every
+line REHEARSAL and prints no result line: it checks the harness, never the
+system's speed.
 """
 
 from __future__ import annotations
@@ -60,7 +62,7 @@ def main(argv=None) -> int:
 
     metrics: dict = {}
     if ctx.trace:
-        for name in ctx.workload["per_layer"]:
+        for name in harness.per_layer_names(ctx.workload):
             reader = harness.load_module("layers", name)
             value = reader.read(run, ctx)
             if value is not None:
@@ -85,6 +87,8 @@ def main(argv=None) -> int:
             "device_ops": run["trace"]["top_ops"],
             "idle_gaps": run["trace"]["idle_gaps"],
         }
+    # Last in the line: what `correct` compared, `{name: [number, limit]}`.
+    result["compared"] = run["compared"]
     for line in run.get("notes", []):
         ctx.say(line)
     if not ctx.trace:
@@ -99,6 +103,9 @@ def main(argv=None) -> int:
         ctx.say(f"would print: {json.dumps(result)}")
         return 0 if run["correct"] else 1
     sys.stdout.flush()
+    for name, (value, limit) in run["compared"].items():
+        print(f"compared {name}: {value!r} (limit {limit!r})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

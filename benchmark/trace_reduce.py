@@ -8,11 +8,24 @@ small recorded trace kept beside it (`trace_fixture.json`) without a chip:
                  "lines": [{"name": "XLA Ops",
                             "events": [[name, start_ns, duration_ns], ...]}]}]}
 
+An event may carry a fourth element, `{stat: value}`: those of the profiler's
+stats the caller asked `load_xplane` for (`stats=`) and the event has. The
+one this file reads itself is `NAME_STACK_STAT`, the operation's name stack
+(where `jax.named_scope` shows); everything here works on events with and
+without it.
+
 `load_xplane` turns the profiler's `.xplane.pb` into that structure with
-nothing but JAX (`jax.profiler.ProfileData`). On a TPU the device planes are
-named `/device:TPU:<n>`; their line `XLA Ops` holds one event per executed HLO
-operation (nested for `while` bodies and fusions) and `XLA Modules` one event
-per executed program. Host threads are lines of the plane `/host:CPU`.
+nothing but JAX (`jax.profiler.ProfileData`) for planes, lines, events and an
+event's own stats. What names an operation is not among those: the v5e's
+profiler keeps the name stack, the category, the operations and bytes of an
+HLO instruction once, as stats of the event's METADATA entry, which
+`ProfileData` does not show. `metadata_stats` reads those from the file's
+protobuf wire format directly (a few dozen lines, no further package) and
+`load_xplane` joins them to the events by plane and name. On a TPU the
+device planes are named `/device:TPU:<n>`; their line `XLA Ops` holds one
+event per executed HLO operation (nested for `while` bodies and fusions) and
+`XLA Modules` one event per executed program. Host threads are lines of the
+plane `/host:CPU`.
 
 Definitions (on-chip-measurement guide, section 4):
 - busy    = union of the intervals in which an operation ran on the device,
@@ -35,10 +48,16 @@ from __future__ import annotations
 import glob
 import os
 import re
+import struct
 from typing import Iterable, Optional
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+# The stat that holds an operation's name stack as the v5e's profiler writes
+# it: `jit(train_step)/while/body/closed_call/.../conv_general_dilated:`, on
+# the METADATA entry of an `XLA Ops` event (PERF.md section 5, PR 25). A
+# Mosaic kernel is a `custom-call` event whose stack ends in `pallas_call:`.
+NAME_STACK_STAT = "tf_op"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 
@@ -52,31 +71,144 @@ def find_xplane(trace_dir: str) -> Optional[str]:
     return found[-1] if found else None
 
 
-def load_xplane(path: str, keep_lines: Optional[Iterable[str]] = None) -> dict:
-    """Read an `.xplane.pb` into the plain structure (module docstring)."""
+def _wire(buf):
+    """(field number, wire type, value) of one protobuf message; a
+    length-delimited value is a view of its bytes, not parsed further."""
+    i, n = 0, len(buf)
+
+    def varint() -> int:
+        nonlocal i
+        value = shift = 0
+        while True:
+            b = buf[i]
+            i += 1
+            value |= (b & 0x7F) << shift
+            shift += 7
+            if b < 0x80:
+                return value
+
+    while i < n:
+        key = varint()
+        number, kind = key >> 3, key & 7
+        if kind == 0:
+            yield number, kind, varint()
+        elif kind == 2:
+            size = varint()
+            yield number, kind, buf[i:i + size]
+            i += size
+        elif kind in (1, 5):
+            size = 8 if kind == 1 else 4
+            yield number, kind, buf[i:i + size]
+            i += size
+        else:
+            raise ValueError(f"protobuf wire type {kind} in an xplane file")
+
+
+def _keeps(stats: Iterable[str]):
+    """The test `name -> bool` for a `stats=` argument; `"*"` keeps all."""
+    wanted = frozenset(stats)
+    return (lambda name: True) if "*" in wanted else wanted.__contains__
+
+
+def _map_entry(buf) -> tuple:
+    entry = {number: value for number, _, value in _wire(buf)}
+    return entry.get(1), entry.get(2)
+
+
+def metadata_stats(path: str, stats: Iterable[str]) -> dict[str, dict[str, dict]]:
+    """{plane name: {event name: {stat: value}}} from the stats of each
+    plane's event METADATA (`tf_op`, `hlo_category`, `flops`,
+    `bytes_accessed`, `source`, ... on a TPU's `XLA Ops`), for the names in
+    `stats` (`"*"`: all but raw bytes). Field numbers are those of
+    `xplane.proto` (tsl/profiler): XSpace.planes = 1; XPlane.name = 2,
+    .event_metadata = 4, .stat_metadata = 5 (maps: key 1, value 2);
+    XEventMetadata.name = 2, .stats = 5; XStatMetadata.name = 2;
+    XStat.metadata_id = 1, .double_value = 2, .uint64_value = 3,
+    .int64_value = 4, .str_value = 5, .ref_value = 7."""
+    keep = _keeps(stats)
+    with open(path, "rb") as fh:
+        space = memoryview(fh.read())
+    out: dict[str, dict[str, dict]] = {}
+    for number, _, plane in _wire(space):
+        if number != 1:
+            continue
+        plane_name, events, stat_names = "", [], {}
+        for field, _, value in _wire(plane):
+            if field == 2:
+                plane_name = bytes(value).decode()
+            elif field == 4:
+                events.append(_map_entry(value)[1])
+            elif field == 5:
+                key, meta = _map_entry(value)
+                names = [bytes(v).decode() for f, _, v in _wire(meta) if f == 2]
+                stat_names[key] = names[0] if names else ""
+        found: dict[str, dict] = {}
+        for meta in events:
+            name, kept = None, {}
+            for field, _, value in _wire(meta):
+                if field == 2:
+                    name = bytes(value).decode()
+                elif field == 5:
+                    stat = {f: v for f, _, v in _wire(value)}
+                    key = stat_names.get(stat.get(1), "")
+                    if not keep(key):
+                        continue
+                    if 5 in stat:
+                        kept[key] = bytes(stat[5]).decode(errors="replace")
+                    elif 7 in stat:
+                        kept[key] = stat_names.get(stat[7], "")
+                    elif 2 in stat:
+                        kept[key] = struct.unpack("<d", stat[2])[0]
+                    elif 3 in stat or 4 in stat:
+                        kept[key] = stat.get(3, stat.get(4))
+            if name is not None and kept:
+                found[name] = kept
+        if found:
+            out[plane_name] = found
+    return out
+
+
+def load_xplane(path: str, keep_lines: Optional[Iterable[str]] = None,
+                stats: Iterable[str] = ()) -> dict:
+    """Read an `.xplane.pb` into the plain structure (module docstring).
+    Where `stats` names some of the profiler's stats, an event that has any
+    of them, of its own or through its metadata entry, is
+    `[name, start_ns, duration_ns, {stat: value}]`; `"*"` keeps every stat
+    (for `describe`, a first look)."""
     import jax
+
+    stats = tuple(stats)
+    keep = _keeps(stats)
+    by_plane = metadata_stats(path, stats) if stats else {}
+
+    def plain(e, of_name: dict) -> list:
+        out = [e.name, float(e.start_ns), float(e.duration_ns)]
+        if stats:
+            found = {**of_name.get(e.name, {}),
+                     **{k: v for k, v in e.stats if keep(k)}}
+            if found:
+                out.append(found)
+        return out
 
     data = jax.profiler.ProfileData.from_file(path)
     planes = []
     for plane in data.planes:
+        of_name = by_plane.get(plane.name, {})
         lines = []
         for line in plane.lines:
             if keep_lines is not None and DEVICE_PLANE.match(plane.name) \
                     and line.name not in keep_lines:
                 continue
-            lines.append({
-                "name": line.name,
-                "events": [
-                    [e.name, float(e.start_ns), float(e.duration_ns)]
-                    for e in line.events
-                ],
-            })
+            lines.append({"name": line.name,
+                          "events": [plain(e, of_name) for e in line.events]})
         planes.append({"name": plane.name, "lines": lines})
     return {"planes": planes}
 
 
 def describe(trace: dict) -> list[str]:
-    """One line per plane and line: what a trace holds, for a first look."""
+    """One line per plane and line: what a trace holds, for a first look,
+    with the names of the stats the line's first event carries (of those the
+    trace was loaded with: `stats=("*",)` shows all)."""
     out = []
     for plane in trace["planes"]:
         out.append(f"PLANE {plane['name']}")
@@ -84,6 +216,8 @@ def describe(trace: dict) -> list[str]:
             evs = line["events"]
             head = ", ".join(str(e[0])[:40] for e in evs[:3])
             out.append(f"  LINE {line['name']!r}: {len(evs)} events ({head})")
+            if evs and len(evs[0]) > 3:
+                out.append(f"    stats of the first: {sorted(evs[0][3])}")
     return out
 
 
@@ -110,7 +244,7 @@ def self_times(events: list[list]) -> dict[str, float]:
             name, _, self_ns = stack.pop()
             totals[name] = totals.get(name, 0.0) + max(self_ns, 0.0)
 
-    for name, start, dur in order:
+    for name, start, dur, *_ in order:
         close(start)
         if stack:
             stack[-1][2] -= dur
@@ -153,17 +287,23 @@ def host_events(trace: dict) -> list[list]:
     return out
 
 
+GAP_PREFIXES = ("bench:", "ac:")
+
+
 def label_gap(gap: tuple[float, float], host: list[list],
-              prefixes: tuple[str, ...] = ("bench:",)) -> str:
-    """What the host was doing in an idle gap. A gap is attributed only to
-    one of the harness's own `TraceAnnotation`s (names starting with one of
-    `prefixes`) and only where that annotation covers at least half of it.
-    Anything else is `unattributed` (the `tracing` issue puts names inside
-    the program); where one of the profiler's own host events covers half
-    the gap, its name follows as a hint (`unattributed;host=<event>`)."""
+              prefixes: tuple[str, ...] = GAP_PREFIXES) -> str:
+    """What the host was doing in an idle gap. A gap is attributed only to a
+    `TraceAnnotation` whose name starts with one of `prefixes` and only where
+    that annotation covers at least half of it: `bench:<what>` is the
+    harness's own, `ac:<span name>` the name under which the program mirrors
+    its `telemetry` spans (`ac:update`, `ac:log`, `ac:checkpoint`, ...) into
+    the profiler's trace. The benchmark fixes that name, the program meets
+    it. Anything else is `unattributed`; where one of the profiler's own
+    host events covers half the gap, its name follows as a hint
+    (`unattributed;host=<event>`)."""
     gs, ge = gap
     best = {True: ("", 0.0), False: ("", 0.0)}
-    for name, start, dur in host:
+    for name, start, dur, *_ in host:
         cover = min(ge, start + dur) - max(gs, start)
         ours = str(name).startswith(prefixes)
         if cover > best[ours][1]:
@@ -174,6 +314,30 @@ def label_gap(gap: tuple[float, float], host: list[list],
     if best[False][1] >= half:
         return f"unattributed;host={best[False][0][:60]}"
     return "unattributed"
+
+
+_WRAPPER = re.compile(r"^p?jit(\(.*\))?$")
+
+
+def scope_of(event: list, limit: int = 48) -> Optional[str]:
+    """The event's name stack (`NAME_STACK_STAT`) cut to what a person
+    reads, or None where the event carries no stack. The `jit(...)` and
+    `pjit` wrappers and the closing colon go; of what is left the FIRST
+    component stays, because it is the phase (`while` is the rollout's scan,
+    `jvp(...)` the update's forward, `transpose(jvp(...))` its backward; a
+    `jax.named_scope` round a phase lands there too), and the LAST two, the
+    layer and the primitive (`conv_0/conv_general_dilated`), with `~` for
+    what was between. At most `limit` characters, cut at the end."""
+    stack = event[3].get(NAME_STACK_STAT) if len(event) > 3 else None
+    if not stack:
+        return None
+    parts = [p for p in str(stack).rstrip(":").split("/")
+             if p and not _WRAPPER.match(p)]
+    if not parts:
+        return None
+    if len(parts) > 3:
+        parts = [parts[0], "~", *parts[-2:]]
+    return "/".join(parts)[:limit]
 
 
 def reduce(trace: dict, top: int = 10, gaps: int = 5) -> Optional[dict]:
@@ -194,6 +358,7 @@ def reduce(trace: dict, top: int = 10, gaps: int = 5) -> Optional[dict]:
     busy = []
     extents = []
     ops: dict[str, float] = {}
+    first: dict[str, list] = {}  # an instruction runs under one name stack
     modules: dict[str, list[float]] = {}
     gap_list: list[tuple[float, float, float]] = []  # (length, start, end)
     for plane, evs in per_plane:
@@ -202,9 +367,11 @@ def reduce(trace: dict, top: int = 10, gaps: int = 5) -> Optional[dict]:
         extents.append(ivs[-1][1] - ivs[0][0])
         for name, ns in self_times(evs).items():
             ops[name] = ops.get(name, 0.0) + ns
+        for e in evs:
+            first.setdefault(e[0], e)
         mod_line = _line(plane, MODULES_LINE)
         if mod_line is not None:
-            for name, _, dur in mod_line["events"]:
+            for name, _, dur, *_ in mod_line["events"]:
                 modules.setdefault(module_key(name), []).append(dur)
         gap_list.extend((s1 - e0, e0, s1) for (_, e0), (s1, _) in zip(ivs, ivs[1:]))
     n = len(per_plane)
@@ -228,17 +395,23 @@ def reduce(trace: dict, top: int = 10, gaps: int = 5) -> Optional[dict]:
             for k, v in modules.items()
         },
         "top_ops": [
-            [short_name(k), v / n / 1e9]
+            [short_name(k, scope_of(first[k])), v / n / 1e9]
             for k, v in sorted(ops.items(), key=lambda kv: -kv[1])[:top]
         ],
         "idle_gaps": [[label, ns / 1e9] for ns, label in labelled],
     }
 
 
-def short_name(hlo: str, limit: int = 140) -> str:
-    """An HLO operation's text without layouts and cut to `limit`: the
-    trace names an operation by its whole instruction."""
-    return re.sub(r"\{[^{}]*\}", "", str(hlo))[:limit]
+def short_name(hlo: str, scope: Optional[str] = None, limit: int = 140) -> str:
+    """An HLO operation's text without layouts and cut to `limit`: the trace
+    names an operation by its whole instruction. With a scope, what tells
+    most comes first (the ledger keeps 64 characters of a name):
+    `<scope>|<op name> <output shape>`, then the rest of the text."""
+    text = re.sub(r"\{[^{}]*\}", "", str(hlo))
+    if scope is not None:
+        m = re.match(r"^%?(\S+) = (.*)$", text)
+        text = f"{scope}|{m.group(1)} {m.group(2)}" if m else f"{scope}|{text}"
+    return text[:limit]
 
 
 def module_key(event_name: str) -> str:

@@ -26,7 +26,7 @@ if ROOT not in sys.path:
 from benchmark import flops, harness, spans, trace_reduce  # noqa: E402
 
 BENCH = os.path.join(ROOT, "benchmark")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def _manifest():
@@ -68,10 +68,25 @@ def test_manifest_cell_agrees_with_its_files(cell):
     harness.load_json("traffic", f"{w['traffic']}.json")
     applies = lambda e: cell in e.get("workloads", [cell])  # noqa: E731
     assert set(w["end_to_end"]) == {e["name"] for e in m["end_to_end"] if applies(e)}
-    assert set(w["per_layer"]) == {e["name"] for e in m["per_layer"] if applies(e)}
     for e in m["end_to_end"]:
         if applies(e):
             assert w["units"][e["name"]] == e["unit"]
+    # Which per-layer metric an accepted cell reads is said once, by the
+    # manifest: every entry lists manifest cells, the cell's file has no list
+    # of its own to keep equal, and each reader named is a file.
+    cells = {x["name"] for x in m["workloads"]}
+    assert "per_layer" not in w
+    for e in m["per_layer"]:
+        assert e["workloads"] and set(e["workloads"]) <= cells
+        assert set(e["workloads"]) <= {
+            x["name"] for x in m["workloads"]
+            if e["moves"] in harness.load_json(
+                "workloads", f"{x['name']}.json")["end_to_end"]}
+    names = harness.per_layer_names(w)
+    assert names == [e["name"] for e in m["per_layer"] if cell in e["workloads"]]
+    assert names, "every cell reports at least one per-layer metric"
+    for name in names:
+        assert os.path.isfile(os.path.join(BENCH, "layers", f"{name}.py"))
 
 
 @pytest.mark.parametrize(
@@ -104,8 +119,11 @@ def test_every_workload_file_names_files_that_exist(cell):
     for kind, name in (("drivers", w["driver"]), ("reference", cfg["reference"]),
                        ("seams", cfg["seam"])):
         assert os.path.isfile(os.path.join(BENCH, kind, f"{name}.py"))
-    for name in w["per_layer"]:
+    for name in harness.per_layer_names(w):
         assert os.path.isfile(os.path.join(BENCH, "layers", f"{name}.py"))
+    # Only a cell that no manifest entry can name lists its readers itself.
+    listed = cell in {x["name"] for x in _manifest()["workloads"]}
+    assert ("per_layer" in w) is (not listed)
     assert set(w["units"]) == set(w["end_to_end"])
 
 
@@ -143,6 +161,7 @@ def test_rehearsal_of_a_cell_and_a_reader_added_as_files_only(tmp_path):
         os.path.join(BENCH, "layers", f"{reader}.py"),
     ]
     w = harness.load_json("workloads", "impala_pong.fleet.json")
+    assert "per_layer" not in w  # an accepted cell's file; the new cell lists its own
     w.update(name=cell, per_layer=["compiles_in_window", reader])
     try:
         with open(paths[0], "w") as fh:
@@ -171,12 +190,107 @@ def test_rehearsal_of_a_cell_and_a_reader_added_as_files_only(tmp_path):
     would = json.loads(lines[-1].split("would print: ", 1)[1])
     assert set(would) == RESULT_KEYS
     assert would["correct"] is True and would["failed"] == 0
+    assert set(would["compared"]) == {"adv_err", "loss_err", "narrow_ops",
+                                      "compiles_in_window", "nonfinite_rows"}
     # `attempted` counts iterations, the reader rows: the first iteration,
     # every tenth, the last.
     rows = would["metrics"][reader]["value"]
     assert rows >= 3 and 10 * (rows - 3) < would["attempted"] <= 10 * (rows - 1)
     assert would["metrics"]["compiles_in_window"] == {"value": 0.0, "unit": "count"}
     assert set(would["device"]) >= {"platform", "kind", "count", "memory_peak_bytes"}
+
+
+READER_FOR_AN_ACCEPTED_CELL = (
+    'LAYER, UNIT, SOURCE, MOVES = "test", "count", "program_counter", '
+    '"fused_steps_per_s"\n\n\n'
+    'def read(run, ctx):\n'
+    '    assert "trace_path" in run\n'
+    '    return len(run["rows"])\n')
+
+
+def _entry_for(reader: str, cells: list) -> dict:
+    return {"name": reader, "unit": "count", "better": "higher",
+            "source": "program_counter", "layer": "test",
+            "moves": "fused_steps_per_s", "workloads": cells}
+
+
+def _files_under(top: str) -> dict:
+    out = {}
+    for folder, _, names in os.walk(top):
+        if "__pycache__" in folder:
+            continue
+        for name in names:
+            path = os.path.join(folder, name)
+            with open(path, "rb") as fh:
+                out[os.path.relpath(path, top)] = fh.read()
+    return out
+
+
+def test_rehearsal_of_a_reader_added_to_an_accepted_cell_as_files_only(tmp_path):
+    """A per-layer metric for a cell the manifest already lists = one new
+    `benchmark/layers/<name>.py` + one new entry in `BENCHMARK.json`, and no
+    edit to any file that is there (PR 24 was refused for two such edits).
+    On a copy of the checkout (the benchmark copied, the program linked):
+    the reader is written, the manifest gets one more entry that lists
+    `impala_pong.fleet`, the cell is rehearsed with `--trace 1`, the metric
+    is in the line it would print, and every file that was under
+    `benchmark/` is byte for byte what it was."""
+    reader = "rows_seen_by_a_later_pr"
+    before = _files_under(BENCH)
+    shutil.copytree(BENCH, tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for name in ("train.py", "scripts", "actor_critic_tpu"):
+        os.symlink(os.path.join(ROOT, name), tmp_path / name)
+    manifest = _manifest()
+    manifest["per_layer"].append(_entry_for(reader, ["impala_pong.fleet"]))
+    with open(tmp_path / "BENCHMARK.json", "w") as fh:
+        json.dump(manifest, fh)
+    (tmp_path / "benchmark" / "layers" / f"{reader}.py").write_text(
+        READER_FOR_AN_ACCEPTED_CELL)
+    r = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", "impala_pong.fleet",
+         "--seed", "7", "--seconds", "1", "--trace", "1", "--rehearsal"],
+        cwd=tmp_path, env=_cpu_env(), capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("REHEARSAL")]
+    would = json.loads(lines[-1].split("would print: ", 1)[1])
+    assert set(would) == RESULT_KEYS and would["correct"] is True
+    assert would["metrics"][reader]["value"] >= 3
+    assert would["metrics"][reader]["unit"] == "count"
+    # The readers the cell had are still read, in the manifest's order, the
+    # new one last (those that find nothing on the CPU are left out).
+    accepted = [e["name"] for e in manifest["per_layer"]]
+    assert [n for n in accepted if n in would["metrics"]] == list(would["metrics"])
+    assert {"compiles_in_window", "cache_miss_count", "adv_kernel_calls",
+            "enqueue_ms"} <= set(would["metrics"])
+    after = _files_under(str(tmp_path / "benchmark"))
+    assert set(after) - set(before) == {os.path.join("layers", f"{reader}.py")}
+    assert {k: after[k] for k in before} == before
+    assert _files_under(BENCH) == before
+
+
+def test_per_layer_names_come_from_the_manifest_for_an_accepted_cell(monkeypatch):
+    manifest = _manifest()
+    names = [e["name"] for e in manifest["per_layer"]]
+    fleet = harness.load_json("workloads", "impala_pong.fleet.json")
+    half = harness.load_json("workloads", "impala_pong.fleet2048.json")
+    assert harness.per_layer_names(fleet) == names == harness.per_layer_names(half)
+    later = json.loads(json.dumps(manifest))
+    later["per_layer"].append(_entry_for("later", ["impala_pong.fleet"]))
+    # An entry without the key is read in every cell that reports what it moves.
+    everywhere = _entry_for("everywhere", [])
+    del everywhere["workloads"]
+    later["per_layer"].append(everywhere)
+    nowhere = {**everywhere, "name": "nowhere", "moves": "act_p99_ms"}
+    later["per_layer"].append(nowhere)
+    monkeypatch.setattr(harness, "load_manifest", lambda: later)
+    assert harness.per_layer_names(fleet) == names + ["later", "everywhere"]
+    assert harness.per_layer_names(half) == names + ["everywhere"]
+    # A cell that is only a file keeps its own list, whatever the manifest says.
+    held_out = harness.load_json("workloads", "impala_pong.preset.json")
+    assert harness.per_layer_names(held_out) == held_out["per_layer"]
+    assert "mfu_pct" in held_out["per_layer"]
+    assert harness.per_layer_names({"name": "t", "per_layer": ["x"]}) == ["x"]
 
 
 # -- arithmetic --------------------------------------------------------------
@@ -278,6 +392,174 @@ def test_trace_reduce_on_a_hand_made_trace():
     assert trace_reduce.reduce({"planes": trace["planes"][1:]}) is None
 
 
+STACK = "jit(train_step)/while/body/closed_call/ActorCriticDiscrete/torso/conv_0/conv_general_dilated:"
+
+
+@pytest.mark.parametrize("stack, scope", [
+    (STACK, "while/~/conv_0/conv_general_dilated"),
+    ("jit(train_step)/jvp(ActorCriticDiscrete)/torso/div:",
+     "jvp(ActorCriticDiscrete)/torso/div"),
+    ("jit(train_step)/transpose(jvp(ActorCriticDiscrete))/torso/conv_0/conv_general_dilated:",
+     "transpose(jvp(ActorCriticDiscrete))/~/conv_0/con"),
+    ("jit(train_step)/jit(main)/pjit/rollout/while/body/add", "rollout/~/body/add"),
+    ("jit(train_step)/jvp()/pallas_call:", "jvp()/pallas_call"),
+    ("jit(train_step)/while:", "while"),
+    ("jit(train_step)", None),
+    ("", None),
+])
+def test_scope_of_a_name_stack(stack, scope):
+    event = ["%fusion.1 = f32[8] fusion(f32[8] %p)", 0.0, 5.0,
+             {trace_reduce.NAME_STACK_STAT: stack}]
+    assert trace_reduce.scope_of(event) == scope
+    assert scope is None or len(scope) <= 48
+
+
+def test_scope_of_an_event_without_stats_and_the_names_of_top_ops():
+    assert trace_reduce.scope_of(["%copy.4 = f32[8] copy(f32[8] %p)", 0.0, 5.0]) is None
+    assert trace_reduce.scope_of(["x", 0.0, 5.0, {"hlo_category": "copy"}]) is None
+    text = "%fusion.389 = bf16[4096,20,20,32]{3,2,1,0:T(8,128)} fusion(bf16[8,8,2,32]{3,2,1,0} %p), kind=kOutput"
+    assert trace_reduce.short_name(text) == \
+        "%fusion.389 = bf16[4096,20,20,32] fusion(bf16[8,8,2,32] %p), kind=kOutput"
+    assert trace_reduce.short_name(text, "while/~/conv_0/conv_general_dilated") == (
+        "while/~/conv_0/conv_general_dilated|fusion.389 bf16[4096,20,20,32] "
+        "fusion(bf16[8,8,2,32] %p), kind=kOutput")
+    assert trace_reduce.short_name("no-equals-sign", "while") == "while|no-equals-sign"
+    # In a reduction: the scope leads where the event has a stack, the name
+    # stays where it has none; self times, order and count do not change.
+    stat = {trace_reduce.NAME_STACK_STAT: STACK}
+    ops = [["%while.1 = () while()", 0, 90], ["%fusion.2 = f32[8] fusion()", 10, 30, stat],
+           ["%conv.3 = f32[8] convolution()", 50, 40], ["%fusion.2 = f32[8] fusion()", 100, 50, stat]]
+    with_stats = {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": ops}]}]}
+    without = {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": [e[:3] for e in ops]}]}]}
+    got, plain = trace_reduce.reduce(with_stats), trace_reduce.reduce(without)
+    assert [n for n, _ in got["top_ops"]] == [
+        "while/~/conv_0/conv_general_dilated|fusion.2 f32[8] fusion()",
+        "%conv.3 = f32[8] convolution()", "%while.1 = () while()"]
+    assert [n for n, _ in plain["top_ops"]] == [
+        "%fusion.2 = f32[8] fusion()", "%conv.3 = f32[8] convolution()",
+        "%while.1 = () while()"]
+    assert [v for _, v in got["top_ops"]] == [v for _, v in plain["top_ops"]]
+    assert {k: v for k, v in got.items() if k != "top_ops"} == \
+        {k: v for k, v in plain.items() if k != "top_ops"}
+
+
+@pytest.mark.parametrize("host, label", [
+    ([["ac:log", 100, 45]], "ac:log"),
+    ([["bench:scrape", 90, 60, {"a": 1}]], "bench:scrape"),
+    ([["np.asarray(jax.Array)", 100, 45]], "unattributed;host=np.asarray(jax.Array)"),
+    ([["np.asarray(jax.Array)", 100, 50], ["ac:update", 120, 25]], "ac:update"),
+    ([["ac:log", 100, 20]], "unattributed"),
+    ([["acme:log", 100, 45]], "unattributed;host=acme:log"),
+    ([], "unattributed"),
+])
+def test_label_gap_attributes_to_the_programs_spans(host, label):
+    """A gap of 50 ns from 100 to 150: the harness's own `bench:` and the
+    program's `ac:<span>` annotations attribute it where one covers half of
+    it; a foreign host event is only ever a hint."""
+    assert trace_reduce.label_gap((100.0, 150.0), host) == label
+
+
+def test_load_xplane_joins_event_metadata_stats(tmp_path):
+    """A profiler trace taken here on the CPU, read back three ways: without
+    stats an event has three elements; with `stats=` it carries those it
+    has; and the wire reader finds the stats of the planes' event metadata
+    (where the v5e keeps `tf_op`; the CPU backend writes none, so here only
+    the reader's walk over a real file is held, not a name stack)."""
+    import jax
+    import jax.numpy as jnp
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("ac:log"):
+            jax.jit(lambda x: jnp.tanh(x @ x).sum())(
+                jnp.ones((64, 64))).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = trace_reduce.find_xplane(str(tmp_path))
+    assert path is not None
+    bare = trace_reduce.load_xplane(path)
+    events = [e for p in bare["planes"] for l in p["lines"] for e in l["events"]]
+    assert events and all(len(e) == 3 for e in events)
+    assert any(e[0] == "ac:log" for e in trace_reduce.host_events(bare))
+    full = trace_reduce.load_xplane(path, stats=("hlo_module", "no_such_stat"))
+    tagged = [e for p in full["planes"] for l in p["lines"] for e in l["events"]
+              if len(e) > 3]
+    assert tagged and all(set(e[3]) == {"hlo_module"} for e in tagged)
+    assert any(e[3]["hlo_module"].startswith("jit_") for e in tagged)
+    assert any("stats of the first" in line for line in trace_reduce.describe(
+        trace_reduce.load_xplane(path, stats=("*",))))
+    by_plane = trace_reduce.metadata_stats(path, ("*",))
+    assert isinstance(by_plane, dict)
+    for of_name in by_plane.values():
+        assert all(isinstance(k, str) and isinstance(v, dict) and v
+                   for k, v in of_name.items())
+    assert trace_reduce.metadata_stats(path, ()) == {}
+
+
+def _pb(*fields) -> bytes:
+    """A protobuf message from (field number, int | bytes | str) pairs."""
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+    msg = b""
+    for number, value in fields:
+        if isinstance(value, int):
+            msg += varint(number << 3) + varint(value)
+        else:
+            raw = value.encode() if isinstance(value, str) else value
+            msg += varint(number << 3 | 2) + varint(len(raw)) + raw
+    return msg
+
+
+def test_name_stack_from_a_hand_made_xplane_file(tmp_path):
+    """An `.xplane.pb` written field by field as the v5e's profiler lays it
+    out: the name stack is a stat (`tf_op`) of the event's METADATA entry,
+    not of the event. `load_xplane(stats=)` joins it to the events, a
+    referenced value (`ref_value`) resolves to its string, and `top_ops`
+    leads with the scope."""
+    stat_names = {1: "tf_op", 2: "hlo_category", 3: "flops", 4: "convolution"}
+    conv = "%fusion.2 = f32[8]{0} fusion(f32[8]{0} %p), kind=kOutput"
+    metadata = {
+        1: _pb((1, 1), (2, conv),
+               (5, _pb((1, 1), (5, STACK))),        # tf_op, a string
+               (5, _pb((1, 2), (7, 4))),            # hlo_category, a reference
+               (5, _pb((1, 3), (3, 1234)))),        # flops, an integer
+        2: _pb((1, 2), (2, "%copy.3 = f32[8]{0} copy(f32[8]{0} %p)")),
+    }
+    line = _pb((2, "XLA Ops"), (3, 0),
+               (4, _pb((1, 1), (2, 0), (3, 30_000))),
+               (4, _pb((1, 2), (2, 40_000), (3, 10_000))))
+    plane = _pb(
+        (1, 0), (2, "/device:TPU:0"), (3, line),
+        *[(4, _pb((1, k), (2, v))) for k, v in metadata.items()],
+        *[(5, _pb((1, k), (2, _pb((1, k), (2, v))))) for k, v in stat_names.items()])
+    path = tmp_path / "hand.xplane.pb"
+    path.write_bytes(_pb((1, plane)))
+    assert trace_reduce.metadata_stats(str(path), ("*",)) == {"/device:TPU:0": {
+        conv: {"tf_op": STACK, "hlo_category": "convolution", "flops": 1234}}}
+    trace = trace_reduce.load_xplane(
+        str(path), keep_lines=(trace_reduce.OPS_LINE,),
+        stats=(trace_reduce.NAME_STACK_STAT,))
+    events = trace["planes"][0]["lines"][0]["events"]
+    assert events == [[conv, 0.0, 30.0, {"tf_op": STACK}],
+                      ["%copy.3 = f32[8]{0} copy(f32[8]{0} %p)", 40.0, 10.0]]
+    got = trace_reduce.reduce(trace)
+    assert got["top_ops"] == [
+        ["while/~/conv_0/conv_general_dilated|fusion.2 f32[8] fusion(f32[8] %p), "
+         "kind=kOutput", pytest.approx(30e-9)],
+        ["%copy.3 = f32[8] copy(f32[8] %p)", pytest.approx(10e-9)]]
+    assert got["busy_s"] == pytest.approx(40e-9)
+    assert got["window_s"] == pytest.approx(50e-9)
+
+
 def test_trace_reduce_on_the_recorded_chip_trace():
     """The small trace recorded on the v5e (benchmark/trace_fixture.json.gz,
     cut from impala_pong.fleet's traced run): the reduction gives the numbers
@@ -294,7 +576,16 @@ def test_trace_reduce_on_the_recorded_chip_trace():
     for name, module in want["modules"].items():
         assert got["modules"][name]["count"] == module["count"]
         assert got["modules"][name]["total_s"] == pytest.approx(module["total_s"])
-    assert [n for n, _ in got["top_ops"][:3]] == want["top_ops_names"]
+    # Names: the scope first where the chip wrote a name stack for the
+    # operation, the HLO text as it was where it wrote none (%while.10).
+    assert [n[:100] for n, _ in got["top_ops"][:3]] == want["top_ops_names"]
+    assert got["top_ops"][0][0].startswith("%while.10 = ")
+    assert sum("|" in n.split(" ")[0] for n, _ in got["top_ops"]) == 9
+    # The name stacks and the `ac:log` annotation moved no number.
+    assert [v for _, v in got["top_ops"]] == pytest.approx(
+        want["top_ops_self_s"], rel=1e-9)
+    assert [label for label, _ in got["idle_gaps"]] == want["idle_gap_labels"]
+    assert got["idle_gaps"][0] == ["ac:log", pytest.approx(1.1651e-05)]
     # Busy is a union: never more than the window, never more than the sum.
     ops = next(l for p in trace["planes"] if trace_reduce.DEVICE_PLANE.match(p["name"])
                for l in p["lines"] if l["name"] == trace_reduce.OPS_LINE)

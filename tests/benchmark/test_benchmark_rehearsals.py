@@ -18,7 +18,7 @@ if ROOT not in sys.path:
 
 from benchmark import harness  # noqa: E402
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def _rehearse(cell: str, trace: int) -> dict:
@@ -37,6 +37,9 @@ def _rehearse(cell: str, trace: int) -> dict:
     assert set(would) == RESULT_KEYS
     assert would["correct"] is True and would["failed"] == 0
     assert would["attempted"] > 0
+    # Each number that decided `correct` beside its limit, last in the line.
+    assert list(would)[-1] == "compared" and len(would["compared"]) >= 4
+    assert all(0 <= number <= limit for number, limit in would["compared"].values())
     return would["metrics"]
 
 
