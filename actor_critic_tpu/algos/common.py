@@ -94,8 +94,12 @@ def rollout_scan(
         )
         return RolloutState(env_state=out.state, obs=out.obs), trans
 
-    step_keys = jax.random.split(key, num_steps)
-    return jax.lax.scan(step_fn, rstate, step_keys)
+    # One phase of the fused step's timeline (the scan and all of its
+    # body): the scope is the first component of every operation's name
+    # stack in a profiler trace, where benchmark/phases.py reads it.
+    with jax.named_scope("rollout"):
+        step_keys = jax.random.split(key, num_steps)
+        return jax.lax.scan(step_fn, rstate, step_keys)
 
 
 class OffPolicyTransition(NamedTuple):
@@ -171,17 +175,21 @@ def gae_targets(
     picks the fused in-VMEM reverse scan on TPU backends and the lax.scan
     reference everywhere else, keeping the whole update ONE program under
     jit on both planes. `time_axis_name` selects the sequence-parallel
-    variant inside shard_map. Returns (advantages, returns)."""
-    if time_axis_name is not None:
-        from actor_critic_tpu.parallel.seqpar import seqpar_gae
+    variant inside shard_map. Returns (advantages, returns).
 
-        return seqpar_gae(
-            rewards, values, dones, bootstrap_value, gamma, lam,
-            axis_name=time_axis_name,
-        )
-    from actor_critic_tpu.ops.pallas_scan import gae_auto as _gae
+    Runs under the `advantage` phase scope (kernel, padding and slicing),
+    as `corrected_advantages`' V-trace branch does."""
+    with jax.named_scope("advantage"):
+        if time_axis_name is not None:
+            from actor_critic_tpu.parallel.seqpar import seqpar_gae
 
-    return _gae(rewards, values, dones, bootstrap_value, gamma, lam)
+            return seqpar_gae(
+                rewards, values, dones, bootstrap_value, gamma, lam,
+                axis_name=time_axis_name,
+            )
+        from actor_critic_tpu.ops.pallas_scan import gae_auto as _gae
+
+        return _gae(rewards, values, dones, bootstrap_value, gamma, lam)
 
 
 def corrected_advantages(
@@ -223,21 +231,25 @@ def corrected_advantages(
     from actor_critic_tpu.ops.pallas_scan import vtrace_auto as _vtrace
 
     if correction == "vtrace":
-        if time_axis_name is not None:
-            from actor_critic_tpu.parallel.seqpar import seqpar_vtrace
+        # The `advantage` phase of the step's timeline: the Pallas seam
+        # with its padding and slicing (`gae_targets` carries the same
+        # scope for the "none" branch and the GAE trainers).
+        with jax.named_scope("advantage"):
+            if time_axis_name is not None:
+                from actor_critic_tpu.parallel.seqpar import seqpar_vtrace
 
-            vt = seqpar_vtrace(
-                target_log_probs, behavior_log_probs, rewards, values,
-                dones, bootstrap_value, gamma, rho_bar=rho_bar, c_bar=c_bar,
-                lam=lam, axis_name=time_axis_name,
-            )
-        else:
-            vt = _vtrace(
-                target_log_probs, behavior_log_probs, rewards, values,
-                dones, bootstrap_value, gamma, rho_bar=rho_bar, c_bar=c_bar,
-                lam=lam,
-            )
-        return vt.pg_advantages, vt.vs, jnp.mean(vt.clipped_rhos)
+                vt = seqpar_vtrace(
+                    target_log_probs, behavior_log_probs, rewards, values,
+                    dones, bootstrap_value, gamma, rho_bar=rho_bar,
+                    c_bar=c_bar, lam=lam, axis_name=time_axis_name,
+                )
+            else:
+                vt = _vtrace(
+                    target_log_probs, behavior_log_probs, rewards, values,
+                    dones, bootstrap_value, gamma, rho_bar=rho_bar,
+                    c_bar=c_bar, lam=lam,
+                )
+            return vt.pg_advantages, vt.vs, jnp.mean(vt.clipped_rhos)
     if correction == "none":
         pg_advantages, value_targets = gae_targets(
             rewards, values, dones, bootstrap_value, gamma, lam,

@@ -163,25 +163,34 @@ def impala_loss(
     the pmean of local-mean grads exactly the global-mean grad).
     """
     T, E = traj.reward.shape
-    obs = traj.obs.reshape(T * E, *traj.obs.shape[2:])
-    actions = traj.action.reshape(T * E, *traj.action.shape[2:])
-
-    dist, values = apply_fn(params, obs)
-    target_log_probs = dist.log_prob(actions).reshape(T, E)
-    values = values.reshape(T, E)
-    # Explicit fp32 accumulators on every reduction: bit-identical in
-    # fp32 mode (the heads cast up), precision-discipline-required under
-    # --update-dtype bf16 (bf16 compute, fp32 accumulation).
-    entropy = jnp.mean(dist.entropy(), dtype=jnp.float32)
-    _, bootstrap_value = apply_fn(params, bootstrap_obs)
+    # Each phase below runs under a `jax.named_scope`: metadata only (the
+    # HLO is the same), and the first component of every operation's name
+    # stack in a profiler trace, which is what tells the pass over `obs`
+    # from the pass over `final_obs` (benchmark/phases.py reads them; the
+    # backward pass shows as `transpose(jvp(<scope>))` by itself).
+    with jax.named_scope("forward"):
+        obs = traj.obs.reshape(T * E, *traj.obs.shape[2:])
+        actions = traj.action.reshape(T * E, *traj.action.shape[2:])
+        dist, values = apply_fn(params, obs)
+        target_log_probs = dist.log_prob(actions).reshape(T, E)
+        values = values.reshape(T, E)
+        # Explicit fp32 accumulators on every reduction: bit-identical in
+        # fp32 mode (the heads cast up), precision-discipline-required
+        # under --update-dtype bf16 (bf16 compute, fp32 accumulation).
+        entropy = jnp.mean(dist.entropy(), dtype=jnp.float32)
+    with jax.named_scope("bootstrap"):
+        _, bootstrap_value = apply_fn(params, bootstrap_obs)
 
     if can_truncate:
         # Truncation bootstrap under the LEARNER's critic.
-        flat_final = traj.final_obs.reshape(T * E, *traj.final_obs.shape[2:])
-        _, final_values = apply_fn(params, flat_final)
-        rewards = truncation_bootstrap_rewards(
-            traj, final_values.reshape(T, E), cfg.gamma
-        )
+        with jax.named_scope("final_obs"):
+            flat_final = traj.final_obs.reshape(
+                T * E, *traj.final_obs.shape[2:]
+            )
+            _, final_values = apply_fn(params, flat_final)
+            rewards = truncation_bootstrap_rewards(
+                traj, final_values.reshape(T, E), cfg.gamma
+            )
     else:
         rewards = traj.reward
 
@@ -203,15 +212,16 @@ def impala_loss(
         time_axis_name=time_axis_name,
     )
 
-    pg_loss = -jnp.mean(
-        jax.lax.stop_gradient(pg_advantages) * target_log_probs,
-        dtype=jnp.float32,
-    )
-    v_loss = 0.5 * jnp.mean(
-        (values - jax.lax.stop_gradient(value_targets)) ** 2,
-        dtype=jnp.float32,
-    )
-    loss = pg_loss + cfg.value_coef * v_loss - cfg.entropy_coef * entropy
+    with jax.named_scope("loss"):
+        pg_loss = -jnp.mean(
+            jax.lax.stop_gradient(pg_advantages) * target_log_probs,
+            dtype=jnp.float32,
+        )
+        v_loss = 0.5 * jnp.mean(
+            (values - jax.lax.stop_gradient(value_targets)) ** 2,
+            dtype=jnp.float32,
+        )
+        loss = pg_loss + cfg.value_coef * v_loss - cfg.entropy_coef * entropy
     return loss, {
         "loss": loss,
         "pg_loss": pg_loss,
@@ -245,18 +255,22 @@ def make_train_step(
             state.params, apply_fn, traj, new_rollout.obs, cfg,
             env.spec.can_truncate,
         )
-        grads = pmesh.pmean_tree(grads, axis_name)
-        updates, new_opt_state = opt.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            grads = pmesh.pmean_tree(grads, axis_name)
+            updates, new_opt_state = opt.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
 
-        # k-step policy lag: actors pick up the learner params only at
-        # refresh boundaries (k=1 degrades gracefully to on-policy, where
-        # every ρ is exactly 1 — tested in tests/test_impala.py).
-        new_step = state.update_step + 1
-        refresh = (new_step % cfg.actor_refresh_every) == 0
-        new_actor_params = jax.tree.map(
-            lambda n, o: jnp.where(refresh, n, o), new_params, state.actor_params
-        )
+            # k-step policy lag: actors pick up the learner params only at
+            # refresh boundaries (k=1 degrades gracefully to on-policy,
+            # where every ρ is exactly 1 — tested in tests/test_impala.py).
+            new_step = state.update_step + 1
+            refresh = (new_step % cfg.actor_refresh_every) == 0
+            new_actor_params = jax.tree.map(
+                lambda n, o: jnp.where(refresh, n, o),
+                new_params, state.actor_params,
+            )
 
         ep_ret, ep_len, avg_ret, ep_metrics = episode_metrics_update(
             state.ep_return, state.ep_length, state.avg_return, traj

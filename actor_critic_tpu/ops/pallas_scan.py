@@ -137,9 +137,12 @@ def gae(
     lam: float,
     *,
     block_envs: int = _DEFAULT_BLOCK_E,
+    name: str = "gae",
 ) -> tuple[jax.Array, jax.Array]:
     """Drop-in for `ops.returns.gae` on [T, E] f32 batches via one Pallas
-    kernel; any other shape/dtype falls back to the lax.scan version."""
+    kernel; any other shape/dtype falls back to the lax.scan version.
+    `name` is the kernel's name in the HLO text and in a profiler trace
+    (`lambda_returns` passes its own)."""
     if rewards.ndim != 2 or rewards.dtype != jnp.float32:
         return _returns.gae(rewards, values, dones, bootstrap_value, gamma, lam)
     T, E = rewards.shape
@@ -175,6 +178,7 @@ def gae(
             jax.ShapeDtypeStruct((T, Ep), jnp.float32),
         ],
         interpret=not on_tpu(),
+        name=name,
     )(rewards, values, dones, boot)
     return (adv[:, :E], ret[:, :E]) if Ep != E else (adv, ret)
 
@@ -198,7 +202,7 @@ def lambda_returns(
         )
     return gae(
         rewards, values, dones, bootstrap_value, gamma, lam,
-        block_envs=block_envs,
+        block_envs=block_envs, name="lambda_returns",
     )[1]
 
 
@@ -326,6 +330,7 @@ def vtrace(
         out_specs=[spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((T, Ep), jnp.float32)] * 3,
         interpret=not on_tpu(),
+        name="vtrace",
     )(tlp, blp, rewards, values, dones, boot)
     if Ep != E:
         vs, pg, rho = vs[:, :E], pg[:, :E], rho[:, :E]

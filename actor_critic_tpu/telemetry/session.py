@@ -36,6 +36,9 @@ from actor_critic_tpu.telemetry.spans import SpanTracer
 from actor_critic_tpu.utils.numguard import safe_json_row
 
 _SESSION: Optional["TelemetrySession"] = None
+# `jax.profiler.TraceAnnotation` while a session is installed, else None:
+# what `_Span` mirrors itself with (set_current).
+_ANNOTATION = None
 
 # Event kinds that are a run's last words: after writing one, all three
 # sinks are flushed AND fsynced so a SIGKILL'd run (or a machine losing
@@ -67,25 +70,47 @@ def _thread_stack() -> list[tuple[str, float]]:
     return stack
 
 
+# The prefix under which a span appears in a `jax.profiler` capture; the
+# benchmark's `trace_reduce.label_gap` attributes idle gaps to these names.
+ANNOTATION_PREFIX = "ac:"
+
+
 class _Span:
     """Context manager for one phase span. Always tracks the open-span
     stack; emits a Chrome-trace complete event only while a session is
     installed at EXIT time (so a session installed mid-span still
-    records it)."""
+    records it).
 
-    __slots__ = ("_name", "_args", "_t0")
+    While a session is installed at ENTRY the span is also mirrored into
+    the profiler's own timeline as a `jax.profiler.TraceAnnotation` named
+    `ac:<span name>`: in any capture that is running (the benchmark's,
+    `/profile?iters=N`, SIGUSR2) it lands in the `/host:CPU` plane, on the
+    clock of the device's `XLA Ops` line, so an idle gap of the device can
+    be laid over what the host was doing. `spans.jsonl` keeps its own
+    `perf_counter` clock. Without a session no annotation object is made:
+    the cost is the one `is not None` test. Spans measured elsewhere and
+    emitted afterwards (`complete_span`, the tracer's `complete_foreign`)
+    are not mirrored."""
+
+    __slots__ = ("_name", "_args", "_t0", "_annotation")
 
     def __init__(self, name: str, args: Optional[dict]):
         self._name = name
         self._args = args
 
     def __enter__(self) -> "_Span":
+        self._annotation = None
+        if _ANNOTATION is not None:
+            self._annotation = _ANNOTATION(ANNOTATION_PREFIX + self._name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         _thread_stack().append((self._name, self._t0))
         return self
 
     def __exit__(self, *exc) -> None:
         dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         stack = _thread_stack()
         if stack and stack[-1][0] == self._name:
             stack.pop()
@@ -117,7 +142,11 @@ def complete_span(name: str, start_pc: float, dur_s: float, **args) -> None:
     (e.g. a sharded-pool worker's busy time within a collection block,
     aggregated host-side). `start_pc` is a `perf_counter()` reading.
     Unlike `span()`, it does not touch the open-span stack — the
-    measured work happened in another process."""
+    measured work happened in another process. Not mirrored into the
+    profiler's trace either (nor is the tracer's `complete_foreign`): a
+    duration emitted afterwards cannot be put into the past of the
+    profiler's clock, so `serve_queue_wait` and `env_step_worker` exist
+    in `spans.jsonl` only."""
     s = _SESSION
     if s is not None:
         s.tracer.complete(name, start_pc, dur_s, args or None)
@@ -143,8 +172,16 @@ def current() -> Optional["TelemetrySession"]:
 
 
 def set_current(session: Optional["TelemetrySession"]) -> None:
-    global _SESSION
+    global _SESSION, _ANNOTATION
     _SESSION = session
+    _ANNOTATION = None
+    if session is not None:
+        # Resolved here and not at import: this package imports without
+        # jax, and a span must not pay an import lookup.
+        try:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        except ImportError:
+            pass
 
 
 def open_spans() -> list[str]:

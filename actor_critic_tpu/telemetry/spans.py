@@ -35,6 +35,7 @@ CANONICAL_PHASES = frozenset({
     "host_to_device",   # block transfer onto the device
     "queue_wait",       # async learner waiting on the trajectory queue
     "update",           # jitted learner update (async dispatch)
+    "device_wait",      # fused loop blocked on a dispatched step (before a log row)
     "eval",             # greedy eval sweep
     "log",              # metrics materialization + sinks
     "checkpoint",       # orbax save boundary

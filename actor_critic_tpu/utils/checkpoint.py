@@ -335,6 +335,7 @@ def checkpointed_train(
     log_fn: Optional[Callable[[int, dict], None]] = None,
     resume: bool = True,
     stride: int = 1,
+    log_due: Optional[Callable[[int], bool]] = None,
 ) -> tuple[Any, dict]:
     """Restart-idempotent train loop (SURVEY.md §5.3).
 
@@ -358,6 +359,19 @@ def checkpointed_train(
     compile). Save/log callbacks fire only at chunk boundaries — the
     caller is responsible for choosing cadences that are multiples of
     `stride` (train.py snaps them up and says so).
+
+    `log_due(it)` says whether `log_fn` will materialize this dispatch's
+    metrics (the loop's one device sync). Where it does AND a telemetry
+    session is installed, the loop takes that sync itself under
+    `device_wait` spans (the dispatch before, then this one), so that
+    `log` starts when the device has finished and holds the host's own
+    work only: a span that began before a profiler capture, or ends
+    after it, is not in the capture, and the idle gap after a log row
+    would have no `ac:` annotation to carry it. Without a session (or
+    without `log_due`) the wait stays inside `log_fn`'s first `float()`,
+    which enqueues its transfer behind the running step: waiting first
+    costs a row 0.3-0.5 ms of device idle (PERF.md, PR 26), so an
+    untraced run does not pay it.
     """
     if ckpt is not None and resume:
         state, done = resume_or_init(ckpt, init_state)
@@ -387,6 +401,7 @@ def checkpointed_train(
             watchdog.ensure_timeout_at_least(3.0 * learned)
 
     it = done
+    previous = None  # the dispatch before this one's metrics (device_wait)
     timed_k = None  # heuristic fallback: stride of the last compile-paid dispatch
     while it < num_iterations:
         # First chunk after a misaligned resume realigns to stride
@@ -463,8 +478,23 @@ def checkpointed_train(
                     jax.block_until_ready(state)
                     ckpt.save(it, state, metrics=metrics, force=True)
         if log_fn is not None:
+            if (
+                log_due is not None
+                and telemetry.current() is not None
+                and log_due(it)
+            ):
+                # In two steps, so that the wait that ends with the device
+                # is at most one dispatch long: it then lies inside a
+                # capture that the whole wait since the last row would
+                # cross the edge of.
+                if previous is not None:
+                    with telemetry.span("device_wait", it=it - k):
+                        jax.block_until_ready(previous)
+                with telemetry.span("device_wait", it=it):
+                    jax.block_until_ready(metrics)
             with telemetry.span("log", it=it):
                 log_fn(it, metrics)
+        previous = metrics
     if ckpt is not None:
         ckpt.wait()
     return state, metrics

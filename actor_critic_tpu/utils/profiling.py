@@ -7,8 +7,6 @@ tools, in one place:
 - `trace(logdir)`: profiler context producing TensorBoard/Perfetto
   traces of the XLA programs inside (view with `tensorboard --logdir` or
   ui.perfetto.dev).
-- `named_scope`: re-export of `jax.named_scope` — trainers annotate loss
-  terms so traces/HLO carry readable op names.
 - `time_fn(fn, *args)`: dispatch-overhead-aware timing: warmup (compile)
   + `block_until_ready` fencing, returns seconds/call.
 - `nan_guard(tree, name)`: jittable non-finite detector for dev runs —
@@ -26,8 +24,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-
-named_scope = jax.named_scope
 
 _log = logging.getLogger(__name__)
 

@@ -13,7 +13,10 @@ that set (telemetry/spans.py) BEFORE instrumenting with them.
 import re
 from pathlib import Path
 
+import pytest
+
 from actor_critic_tpu import telemetry
+from actor_critic_tpu.telemetry import session as session_mod
 
 REPO = Path(__file__).parent.parent
 
@@ -32,6 +35,12 @@ _FOREIGN = re.compile(
     r"""\.\s*complete_foreign\s*\(\s*(['"])(?P<name>[^'"]+)\1""",
     re.VERBOSE,
 )
+# The serving hops are timed first and emitted afterwards, straight on the
+# session's tracer (`tracer.complete("serve_dispatch", ...)`).
+_TRACER = re.compile(
+    r"""\.\s*complete\s*\(\s*(['"])(?P<name>[^'"]+)\1""",
+    re.VERBOSE,
+)
 # Phase names bound to a constant before use (e.g. the shard-pool
 # relay's batched emission) declare themselves with a *_PHASE suffix.
 _CONST = re.compile(
@@ -48,7 +57,7 @@ def _span_names() -> dict[str, set[str]]:
         files = [path] if path.is_file() else sorted(path.rglob("*.py"))
         for f in files:
             text = f.read_text()
-            for pat in (_CALL, _FOREIGN, _CONST):
+            for pat in (_CALL, _FOREIGN, _TRACER, _CONST):
                 for m in pat.finditer(text):
                     uses.setdefault(m.group("name"), set()).add(
                         str(f.relative_to(REPO))
@@ -78,3 +87,34 @@ def test_core_phases_are_instrumented():
     for phase in ("iteration", "env_step", "update", "log", "checkpoint",
                   "eval", "host_to_device", "env_step_worker"):
         assert phase in uses, f"phase {phase!r} no longer instrumented"
+
+
+def test_every_canonical_phase_has_a_call_site():
+    """The other direction: a name in the vocabulary that nothing emits is
+    dead weight in every report's legend (and in `PERF.md`'s table of who
+    reads which span). Retire the name with its last call site."""
+    unused = telemetry.CANONICAL_PHASES - set(_span_names())
+    assert not unused, f"canonical phase(s) nothing emits: {sorted(unused)}"
+
+
+@pytest.mark.parametrize("name", sorted(telemetry.CANONICAL_PHASES))
+def test_a_span_is_mirrored_into_the_profiler_as_ac_name(name, monkeypatch):
+    """While a session is installed a span opens a profiler annotation
+    named exactly `"ac:" + name`: the contract the benchmark's
+    `trace_reduce.label_gap` attributes idle gaps by."""
+    opened = []
+
+    class Annotation:
+        def __init__(self, label):
+            self.label = label
+
+        def __enter__(self):
+            opened.append(self.label)
+
+        def __exit__(self, *exc):
+            opened.append("/" + self.label)
+
+    monkeypatch.setattr(session_mod, "_ANNOTATION", Annotation)
+    with telemetry.span(name):
+        assert opened == ["ac:" + name]
+    assert opened == ["ac:" + name, "/ac:" + name]

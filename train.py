@@ -349,14 +349,19 @@ def run_fused(env, preset, args, logger) -> dict:
             mixture.parse_curriculum(args.curriculum, env.member_names)
         )
 
-    def log_fn(it, metrics):
+    def eval_due(it):
+        return eval_fn is not None and (
+            it % args.eval_every == 0 or it == args.iterations
+        )
+
+    def log_due(it):
         # Eval cadence is INDEPENDENT of the logging cadence; an eval
         # iteration always emits a log row so the number is never lost.
-        do_log = should_log(it, args.log_every, args.iterations)
+        return should_log(it, args.log_every, args.iterations) or eval_due(it)
+
+    def log_fn(it, metrics):
         extra = {}
-        if eval_fn is not None and (
-            it % args.eval_every == 0 or it == args.iterations
-        ):
+        if eval_due(it):
             with telemetry.span("eval", it=it):
                 extra["eval_return"] = float(eval_fn(state_box[0], eval_key))
                 if typed_eval is not None:
@@ -383,15 +388,15 @@ def run_fused(env, preset, args, logger) -> dict:
                         flush=True,
                     )
                 extra["curriculum_stage"] = curriculum_ctl.stage
-            do_log = True
-        if do_log:
+        if log_due(it):
             # Health monitors see the materialized row — AFTER the eval
             # merge (so eval_return reaches the divergence detector) and
             # only on the log cadence: the float() coercions are the
-            # loop's first device sync, and syncing every dispatch would
-            # serialize host on device, the pipelining this loop exists
-            # to preserve. Non-floatable values stringify, same tolerance
-            # as JsonlLogger.log.
+            # loop's one device sync (a traced run has just waited for it
+            # under `device_wait`, utils/checkpoint.py), and syncing every
+            # dispatch would serialize host on device, the pipelining
+            # this loop exists to preserve. Non-floatable values
+            # stringify, same tolerance as JsonlLogger.log.
             row = {}
             for k, v in metrics.items():
                 try:
@@ -440,7 +445,7 @@ def run_fused(env, preset, args, logger) -> dict:
         state, metrics = checkpointed_train(
             step_tracking, state, args.iterations,
             ckpt=ckpt, save_every=args.save_every, log_fn=log_fn,
-            resume=args.resume, stride=chunk,
+            resume=args.resume, stride=chunk, log_due=log_due,
         )
     finally:
         if gauge_key is not None:
