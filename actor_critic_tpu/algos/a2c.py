@@ -32,7 +32,7 @@ from actor_critic_tpu.algos.common import (
     init_rollout,
     linear_anneal,
     rollout_scan,
-    truncation_bootstrap_rewards,
+    truncation_bootstrap,
 )
 from actor_critic_tpu.algos.metrics import aggregate_metrics
 from actor_critic_tpu.envs.jax_env import JaxEnv
@@ -219,14 +219,9 @@ def make_train_step(
         # --- targets ---
         _, bootstrap_value = apply_fn(state.params, new_rollout.obs)
         if env.spec.can_truncate:
-            # Value of pre-reset final obs for truncation bootstrap.
-            T, E = traj.reward.shape
-            _, final_values = apply_fn(
-                state.params,
-                traj.final_obs.reshape(T * E, *traj.final_obs.shape[2:]),
-            )
-            rewards = truncation_bootstrap_rewards(
-                traj, final_values.reshape(T, E), cfg.gamma
+            # Value of pre-reset final obs, at the truncated rows only.
+            rewards = truncation_bootstrap(
+                apply_fn, state.params, traj, cfg.gamma
             )
         else:
             rewards = traj.reward

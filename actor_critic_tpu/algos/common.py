@@ -295,6 +295,55 @@ def truncation_bootstrap_rewards(
     return traj.reward + gamma * final_values * truncated
 
 
+# Rows a trip of `truncation_bootstrap`'s loop evaluates: small enough that
+# a learnt policy's handful of truncated rows costs one short pass, large
+# enough that a synchronized burst of E rows takes few trips (PERF.md, PR 27:
+# 256, 512 and 1024 measured on a v5e at pixel-IMPALA shapes).
+TRUNCATION_CHUNK = 512
+
+
+def truncation_bootstrap(
+    apply_fn: Callable[[Any, jax.Array], tuple[Any, jax.Array]],
+    params: Any,
+    traj: Transition,
+    gamma: float,
+) -> jax.Array:
+    """`truncation_bootstrap_rewards` with the critic run on the truncated
+    rows only: the patched rewards [T, E].
+
+    The value of `final_obs` is used only where the time limit cut an
+    episode (the formula multiplies every other row by zero), so the rows
+    of `traj.final_obs` that need it are gathered `C` at a time under a
+    loop whose trip count, ceil(n / C), is read from the data: no truncated
+    row, no trip; every row truncated, the full pass in T·E / C pieces.
+    Primal only (the loop has no reverse rule): pass `stop_gradient`-ed
+    params from inside a differentiated function. No gradient reaches the
+    patched rewards in any trainer.
+    """
+    T, E = traj.reward.shape
+    N = T * E
+    C = min(N, TRUNCATION_CHUNK)
+    truncated = (traj.done * (1.0 - traj.terminated)).reshape(N) > 0
+    # Indices of the truncated rows, ascending, compacted to the front; the
+    # fill is out of range, so a chunk's rows past n are dropped by the write.
+    rows = jnp.sort(jnp.where(truncated, jnp.arange(N, dtype=jnp.int32), N))
+    rows = jnp.pad(rows, (0, -N % C), constant_values=N)
+    trips = (jnp.sum(truncated, dtype=jnp.int32) + C - 1) // C
+    flat_final = traj.final_obs.reshape(N, *traj.final_obs.shape[2:])
+
+    def chunk(i, final_values):
+        idx = jax.lax.dynamic_slice(rows, (i * C,), (C,))
+        _, v = apply_fn(params, jnp.take(flat_final, idx, axis=0, mode="clip"))
+        return final_values.at[idx].set(v, mode="drop")
+
+    final_values = jax.lax.fori_loop(
+        0, trips, chunk, jnp.zeros((N,), jnp.float32)
+    )
+    return truncation_bootstrap_rewards(
+        traj, final_values.reshape(T, E), gamma
+    )
+
+
 def evaluate(
     env: JaxEnv,
     act_fn: Callable[[Any, jax.Array], jax.Array],

@@ -41,7 +41,7 @@ from actor_critic_tpu.algos.common import (
     init_rollout,
     rollout_scan,
     episode_metrics_update,
-    truncation_bootstrap_rewards,
+    truncation_bootstrap,
 )
 from actor_critic_tpu.algos.metrics import aggregate_metrics
 from actor_critic_tpu.envs.jax_env import JaxEnv
@@ -182,17 +182,18 @@ def impala_loss(
         _, bootstrap_value = apply_fn(params, bootstrap_obs)
 
     if can_truncate:
-        # Truncation bootstrap under the LEARNER's critic.
+        # Truncation bootstrap under the LEARNER's critic, on the truncated
+        # rows only; primal only (no gradient reaches the rewards below).
         with jax.named_scope("final_obs"):
-            flat_final = traj.final_obs.reshape(
-                T * E, *traj.final_obs.shape[2:]
+            rewards = truncation_bootstrap(
+                apply_fn, jax.lax.stop_gradient(params), traj, cfg.gamma
             )
-            _, final_values = apply_fn(params, flat_final)
-            rewards = truncation_bootstrap_rewards(
-                traj, final_values.reshape(T, E), cfg.gamma
+            truncated_frac = jnp.mean(
+                traj.done * (1.0 - traj.terminated), dtype=jnp.float32
             )
     else:
         rewards = traj.reward
+        truncated_frac = jnp.zeros((), jnp.float32)
 
     # Correction machinery shared with the async actor–learner PPO
     # update (ISSUE 6): V-trace or plain λ-return, sequence-parallel
@@ -228,6 +229,7 @@ def impala_loss(
         "v_loss": v_loss,
         "entropy": entropy,
         "mean_rho": mean_rho,
+        "truncated_frac": truncated_frac,
     }
 
 

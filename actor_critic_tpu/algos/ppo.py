@@ -34,7 +34,7 @@ from actor_critic_tpu.algos.common import (
     init_rollout,
     linear_anneal,
     rollout_scan,
-    truncation_bootstrap_rewards,
+    truncation_bootstrap,
 )
 from actor_critic_tpu.algos.metrics import aggregate_metrics
 from actor_critic_tpu.envs.jax_env import JaxEnv
@@ -1374,12 +1374,8 @@ def make_train_step(
         _, bootstrap_value = apply_fn(state.params, new_rollout.obs)
         T, E = traj.reward.shape
         if env.spec.can_truncate:
-            _, final_values = apply_fn(
-                state.params,
-                traj.final_obs.reshape(T * E, *traj.final_obs.shape[2:]),
-            )
-            rewards = truncation_bootstrap_rewards(
-                traj, final_values.reshape(T, E), cfg.gamma
+            rewards = truncation_bootstrap(
+                apply_fn, state.params, traj, cfg.gamma
             )
         else:
             rewards = traj.reward

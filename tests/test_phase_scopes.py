@@ -73,6 +73,10 @@ def test_the_step_carries_every_scope_of_the_table(step_hlo):
                for s in _op_names(step_hlo))
     assert any(s.startswith("jit(train_step)/rollout/while/body/")
                for s in _op_names(step_hlo))
+    # The truncation bootstrap is a loop over the truncated rows (ISSUE
+    # 27): the scope is on its body, where the critic's operations are.
+    assert any(s.startswith("jit(train_step)/jvp(final_obs)/while/body/")
+               for s in _op_names(step_hlo))
 
 
 def test_obs_and_final_obs_convolutions_carry_different_scopes(step_hlo):
@@ -83,6 +87,10 @@ def test_obs_and_final_obs_convolutions_carry_different_scopes(step_hlo):
         assert convs[scope] == 3, convs
     assert convs[phases.BACKWARD] >= 2
     assert convs[phases.UNSCOPED] == 0 and convs[None] == 0
+    # final_obs's three run inside the loop over the truncated rows.
+    in_loop = [s for s in _op_names(step_hlo, "convolution")
+               if phases.phase_of(s) == "final_obs"]
+    assert all("/jvp(final_obs)/while/body/" in s for s in in_loop), in_loop
 
 
 def test_scopes_are_metadata_the_compiled_step_is_the_same(step_hlo, monkeypatch):
