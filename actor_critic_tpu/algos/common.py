@@ -54,6 +54,75 @@ class TrainState(NamedTuple):
     avg_return: jax.Array
 
 
+class Unrolled(NamedTuple):
+    """What a loss needs of a policy on a `[T, E]` trajectory."""
+
+    log_prob: jax.Array  # [T, E], of `traj.action` under the given params
+    entropy: jax.Array   # per decision, any shape (the loss takes its mean)
+    value: jax.Array     # [T, E]
+    # [T, E] of 0 / 1, or None where every step is a decision: the steps a
+    # loss counts (a token env feeds prompt tokens and ignores the action).
+    mask: Optional[jax.Array]
+    metrics: dict        # the policy's own scalar counters, into the rows
+
+
+class Policy(NamedTuple):
+    """How the fused trainers act and learn, whatever the network keeps
+    between steps.
+
+    `init_carry(num_envs)` is what the policy carries through ONE rollout
+    (a sequence model's cache; `()` for a feed-forward network): it lives
+    inside `rollout_scan` / `evaluate` and starts fresh with each, so it is
+    no part of `RolloutState` or of a checkpoint. `step(params, obs, carry)
+    -> (dist, value, carry)` acts on one observation a row; `unroll(params,
+    traj) -> Unrolled` re-evaluates a whole trajectory for the update;
+    `bootstrap(params, obs) -> value [E]` values the observation after the
+    last step."""
+
+    init_carry: Callable[[int], Any]
+    step: Callable[[Any, jax.Array, Any], tuple[Any, jax.Array, Any]]
+    unroll: Callable[[Any, "Transition"], Unrolled]
+    bootstrap: Callable[[Any, jax.Array], jax.Array]
+
+
+def feedforward_policy(
+    apply_fn: Callable[[Any, jax.Array], tuple[Any, jax.Array]],
+) -> Policy:
+    """The trivial adapter for `apply_fn(params, obs) -> (dist, value)`:
+    nothing carried, and `unroll` is one application to the `T * E`
+    independent rows."""
+
+    def unroll(params, traj):
+        T, E = traj.reward.shape
+        obs = traj.obs.reshape(T * E, *traj.obs.shape[2:])
+        actions = traj.action.reshape(T * E, *traj.action.shape[2:])
+        dist, values = apply_fn(params, obs)
+        log_prob = dist.log_prob(actions).reshape(T, E)
+        values = values.reshape(T, E)
+        return Unrolled(log_prob, dist.entropy(), values, None, {})
+
+    return Policy(
+        init_carry=lambda num_envs: (),
+        step=lambda params, obs, carry: (*apply_fn(params, obs), carry),
+        unroll=unroll,
+        bootstrap=lambda params, obs: apply_fn(params, obs)[1],
+    )
+
+
+def as_policy(policy_or_apply) -> Policy:
+    """A `Policy` as it is; a bare `apply_fn` through `feedforward_policy`."""
+    if isinstance(policy_or_apply, Policy):
+        return policy_or_apply
+    return feedforward_policy(policy_or_apply)
+
+
+def masked_mean(x: jax.Array, mask: Optional[jax.Array]) -> jax.Array:
+    """float32 mean of `x` over the steps `mask` counts (all where None)."""
+    if mask is None:
+        return jnp.mean(x, dtype=jnp.float32)
+    return jnp.sum(x * mask, dtype=jnp.float32) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
 def init_rollout(env: JaxEnv, key: jax.Array, num_envs: int) -> RolloutState:
     keys = jax.random.split(key, num_envs)
     env_state, obs = jax.vmap(env.reset)(keys)
@@ -62,7 +131,7 @@ def init_rollout(env: JaxEnv, key: jax.Array, num_envs: int) -> RolloutState:
 
 def rollout_scan(
     env: JaxEnv,
-    apply_fn: Callable[[Any, jax.Array], tuple[Any, jax.Array]],
+    policy: Any,
     params: Any,
     rstate: RolloutState,
     key: jax.Array,
@@ -70,13 +139,17 @@ def rollout_scan(
 ) -> tuple[RolloutState, Transition]:
     """Collect `num_steps` of experience from the vmapped env batch.
 
-    `apply_fn(params, obs) -> (dist, value)`; actions are sampled per env
-    with per-step keys. Returns time-major Transition with arrays
+    `policy` is a `Policy`, or a bare `apply_fn(params, obs) -> (dist,
+    value)` (`as_policy`); actions are sampled per env with per-step keys.
+    What the policy carries from step to step starts fresh here and is
+    dropped at the end. Returns time-major Transition with arrays
     [T, E, ...].
     """
+    policy = as_policy(policy)
 
-    def step_fn(carry: RolloutState, step_key: jax.Array):
-        dist, value = apply_fn(params, carry.obs)
+    def step_fn(scan_carry, step_key: jax.Array):
+        carry, policy_carry = scan_carry
+        dist, value, policy_carry = policy.step(params, carry.obs, policy_carry)
         n_envs = carry.obs.shape[0]
         akeys = jax.random.split(step_key, n_envs)
         action = jax.vmap(lambda d, k: d.sample(k), in_axes=(0, 0))(dist, akeys)
@@ -92,14 +165,16 @@ def rollout_scan(
             terminated=out.info["terminated"],
             final_obs=out.info["final_obs"],
         )
-        return RolloutState(env_state=out.state, obs=out.obs), trans
+        return (RolloutState(env_state=out.state, obs=out.obs), policy_carry), trans
 
     # One phase of the fused step's timeline (the scan and all of its
     # body): the scope is the first component of every operation's name
     # stack in a profiler trace, where benchmark/phases.py reads it.
     with jax.named_scope("rollout"):
         step_keys = jax.random.split(key, num_steps)
-        return jax.lax.scan(step_fn, rstate, step_keys)
+        init = (rstate, policy.init_carry(rstate.obs.shape[0]))
+        (rstate, _), traj = jax.lax.scan(step_fn, init, step_keys)
+        return rstate, traj
 
 
 class OffPolicyTransition(NamedTuple):
@@ -352,11 +427,14 @@ def evaluate(
     num_envs: int = 32,
     num_steps: int = 256,
     reset_fn: Optional[Callable] = None,
+    init_carry: Optional[Callable[[int], Any]] = None,
 ) -> jax.Array:
     """Greedy eval: mean return of each env's FIRST episode (SURVEY §3.4).
 
     `act_fn(params, obs) -> action` is the deterministic policy (mode /
-    mean action). Rewards stop accumulating at the first `done`. Envs
+    mean action); with `init_carry` (a `Policy`'s) it is `act_fn(params,
+    obs, carry) -> (action, carry)` and the carry is threaded through the
+    episode loop. Rewards stop accumulating at the first `done`. Envs
     whose episode outlives `num_steps` are EXCLUDED from the mean (a
     partial return would understate exactly when the policy is good);
     if no env finishes within the horizon, the mean of the partial
@@ -368,17 +446,22 @@ def evaluate(
     """
     keys = jax.random.split(key, num_envs)
     env_state, obs = jax.vmap(reset_fn or env.reset)(keys)
-    init = (env_state, obs, jnp.zeros(num_envs), jnp.ones(num_envs))
+    if init_carry is None:
+        act = lambda params, obs, carry: (act_fn(params, obs), carry)  # noqa: E731
+        policy_carry = ()
+    else:
+        act, policy_carry = act_fn, init_carry(num_envs)
+    init = (env_state, obs, jnp.zeros(num_envs), jnp.ones(num_envs), policy_carry)
 
     def step(carry, _):
-        env_state, obs, ret, alive = carry
-        action = act_fn(params, obs)
+        env_state, obs, ret, alive, policy_carry = carry
+        action, policy_carry = act(params, obs, policy_carry)
         out = jax.vmap(env.step)(env_state, action)
         ret = ret + out.reward * alive
         alive = alive * (1.0 - out.done)
-        return (out.state, out.obs, ret, alive), None
+        return (out.state, out.obs, ret, alive, policy_carry), None
 
-    (_, _, returns, alive), _ = jax.lax.scan(step, init, None, length=num_steps)
+    (_, _, returns, alive, _), _ = jax.lax.scan(step, init, None, length=num_steps)
     finished = 1.0 - alive
     n_finished = jnp.sum(finished)
     finished_mean = jnp.sum(returns * finished) / jnp.maximum(n_finished, 1.0)
@@ -397,30 +480,34 @@ def make_greedy_eval(
     env: JaxEnv,
     act: Callable[[Any, jax.Array], jax.Array],
     params_of: Callable[[Any], Any],
+    init_carry: Optional[Callable[[int], Any]] = None,
 ):
     """THE eval-program factory shared by every algo's `make_eval_fn`:
     `act(params, obs) → action` is the algo's greedy policy, `params_of`
     extracts the acting params from its train state. Returns
     `eval_fn(state, key, num_envs=32, num_steps=default_eval_steps(env))`
-    (jit with static_argnums=(2, 3))."""
+    (jit with static_argnums=(2, 3)). `init_carry` as in `evaluate`."""
     default_steps = default_eval_steps(env)
 
     def eval_fn(state, key, num_envs: int = 32, num_steps: int = default_steps):
-        return evaluate(env, act, params_of(state), key, num_envs, num_steps)
+        return evaluate(env, act, params_of(state), key, num_envs, num_steps,
+                        init_carry=init_carry)
 
     return eval_fn
 
 
 def make_mode_eval(env: JaxEnv, net):
-    """`make_greedy_eval` specialization for actor-critic nets whose
-    `apply(params, obs) → (dist, value)`: greedy action = dist.mode(),
+    """`make_greedy_eval` specialization for actor-critic policies: a
+    `Policy`, or a net whose `apply(params, obs) → (dist, value)`. Greedy
+    action = dist.mode(), the policy's carry threaded through the episode;
     params live at `state.params` (a2c/ppo/impala)."""
+    policy = as_policy(getattr(net, "apply", net))
 
-    def act(params, obs):
-        dist, _ = net.apply(params, obs)
-        return dist.mode()
+    def act(params, obs, carry):
+        dist, _, carry = policy.step(params, obs, carry)
+        return dist.mode(), carry
 
-    return make_greedy_eval(env, act, lambda s: s.params)
+    return make_greedy_eval(env, act, lambda s: s.params, policy.init_carry)
 
 
 def episode_metrics_update(

@@ -36,15 +36,20 @@ import jax.numpy as jnp
 import optax
 
 from actor_critic_tpu.algos.common import (
+    Policy,
     RolloutState,
+    as_policy,
     corrected_advantages,
+    feedforward_policy,
     init_rollout,
+    masked_mean,
     rollout_scan,
     episode_metrics_update,
     truncation_bootstrap,
 )
 from actor_critic_tpu.algos.metrics import aggregate_metrics
 from actor_critic_tpu.envs.jax_env import JaxEnv
+from actor_critic_tpu.models import seq_policy
 from actor_critic_tpu.models.networks import ActorCriticDiscrete, ActorCriticGaussian
 from actor_critic_tpu.parallel import mesh as pmesh
 
@@ -68,6 +73,9 @@ class ImpalaConfig:
     rms_decay: float = 0.99
     rms_eps: float = 0.1
     bf16_compute: bool = False
+    # A sequence-model policy over a token env (`models/seq_policy.py`),
+    # reached as `--set seq.<field>=...`; None: the MLP / CNN torsos.
+    seq: Optional[seq_policy.SeqPolicyConfig] = None
 
     def __post_init__(self):
         if self.correction not in ("vtrace", "none"):
@@ -102,11 +110,27 @@ def make_network(env: JaxEnv, cfg: ImpalaConfig):
     )
 
 
+def make_policy(env: JaxEnv, cfg: ImpalaConfig) -> Policy:
+    """How this configuration acts and learns: the torso's `apply` through
+    the feed-forward adapter, or the sequence model with its cache (which
+    refuses an env whose episode is not exactly one unroll)."""
+    if cfg.seq is not None:
+        return seq_policy.make_policy(env.spec, cfg.seq, cfg.rollout_steps)
+    return feedforward_policy(make_network(env, cfg).apply)
+
+
+def init_params(env: JaxEnv, cfg: ImpalaConfig, key: jax.Array):
+    if cfg.seq is not None:
+        return seq_policy.init_params(key, cfg.seq, env.spec.action_dim)
+    dummy = jnp.zeros((1, *env.spec.obs_shape), env.spec.obs_dtype)
+    return make_network(env, cfg).init(key, dummy)
+
+
 def make_eval_fn(env: JaxEnv, cfg: "ImpalaConfig"):
     """Greedy (mode-action) eval program (SURVEY.md §3.4)."""
     from actor_critic_tpu.algos.common import make_mode_eval
 
-    return make_mode_eval(env, make_network(env, cfg))
+    return make_mode_eval(env, make_policy(env, cfg))
 
 
 def make_optimizer(cfg: ImpalaConfig) -> optax.GradientTransformation:
@@ -117,11 +141,9 @@ def make_optimizer(cfg: ImpalaConfig) -> optax.GradientTransformation:
 
 
 def init_state(env: JaxEnv, cfg: ImpalaConfig, key: jax.Array) -> ImpalaTrainState:
-    net = make_network(env, cfg)
     opt = make_optimizer(cfg)
     key, pkey, rkey = jax.random.split(key, 3)
-    dummy = jnp.zeros((1, *env.spec.obs_shape), env.spec.obs_dtype)
-    params = net.init(pkey, dummy)
+    params = init_params(env, cfg, pkey)
     E = cfg.num_envs
     return ImpalaTrainState(
         params=params,
@@ -141,7 +163,7 @@ def init_state(env: JaxEnv, cfg: ImpalaConfig, key: jax.Array) -> ImpalaTrainSta
 
 def impala_loss(
     params: Any,
-    apply_fn: Callable,
+    policy: Any,
     traj,
     bootstrap_obs: jax.Array,
     cfg: ImpalaConfig,
@@ -150,9 +172,13 @@ def impala_loss(
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """V-trace (or A3C λ-return) actor-critic loss on a [T, E] trajectory.
 
-    The learner re-evaluates π/V at the stored observations; `traj.log_prob`
+    The learner re-evaluates π/V at the stored observations (`policy.unroll`;
+    `policy` is a `common.Policy` or a bare `apply_fn`); `traj.log_prob`
     holds the BEHAVIOUR policy's log-probs from rollout time, so the
     ρ = π/μ importance ratios are exact even under parameter staleness.
+    Where the policy masks steps (`Unrolled.mask`: the env ignored the
+    action there), they leave every mean of the loss and their importance
+    ratio is 1, so V-trace passes through them as through on-policy steps.
 
     With `time_axis_name` the function runs INSIDE shard_map with the
     trajectory's TIME axis sharded over that mesh axis (sequence
@@ -162,31 +188,29 @@ def impala_loss(
     gradients the caller must pmean over the axis (equal time shards make
     the pmean of local-mean grads exactly the global-mean grad).
     """
-    T, E = traj.reward.shape
+    policy = as_policy(policy)
     # Each phase below runs under a `jax.named_scope`: metadata only (the
     # HLO is the same), and the first component of every operation's name
     # stack in a profiler trace, which is what tells the pass over `obs`
     # from the pass over `final_obs` (benchmark/phases.py reads them; the
     # backward pass shows as `transpose(jvp(<scope>))` by itself).
     with jax.named_scope("forward"):
-        obs = traj.obs.reshape(T * E, *traj.obs.shape[2:])
-        actions = traj.action.reshape(T * E, *traj.action.shape[2:])
-        dist, values = apply_fn(params, obs)
-        target_log_probs = dist.log_prob(actions).reshape(T, E)
-        values = values.reshape(T, E)
+        out = policy.unroll(params, traj)
+        target_log_probs, values, mask = out.log_prob, out.value, out.mask
         # Explicit fp32 accumulators on every reduction: bit-identical in
         # fp32 mode (the heads cast up), precision-discipline-required
         # under --update-dtype bf16 (bf16 compute, fp32 accumulation).
-        entropy = jnp.mean(dist.entropy(), dtype=jnp.float32)
+        entropy = masked_mean(out.entropy, mask)
     with jax.named_scope("bootstrap"):
-        _, bootstrap_value = apply_fn(params, bootstrap_obs)
+        bootstrap_value = policy.bootstrap(params, bootstrap_obs)
 
     if can_truncate:
         # Truncation bootstrap under the LEARNER's critic, on the truncated
         # rows only; primal only (no gradient reaches the rewards below).
         with jax.named_scope("final_obs"):
             rewards = truncation_bootstrap(
-                apply_fn, jax.lax.stop_gradient(params), traj, cfg.gamma
+                lambda p, obs: (None, policy.bootstrap(p, obs)),
+                jax.lax.stop_gradient(params), traj, cfg.gamma,
             )
             truncated_frac = jnp.mean(
                 traj.done * (1.0 - traj.terminated), dtype=jnp.float32
@@ -198,8 +222,11 @@ def impala_loss(
     # Correction machinery shared with the async actor–learner PPO
     # update (ISSUE 6): V-trace or plain λ-return, sequence-parallel
     # when a time axis name is given.
+    vtrace_target_lp = jax.lax.stop_gradient(target_log_probs)
+    if mask is not None:
+        vtrace_target_lp = jnp.where(mask > 0, vtrace_target_lp, traj.log_prob)
     pg_advantages, value_targets, mean_rho = corrected_advantages(
-        jax.lax.stop_gradient(target_log_probs),
+        vtrace_target_lp,
         traj.log_prob,
         rewards,
         jax.lax.stop_gradient(values),
@@ -214,13 +241,11 @@ def impala_loss(
     )
 
     with jax.named_scope("loss"):
-        pg_loss = -jnp.mean(
-            jax.lax.stop_gradient(pg_advantages) * target_log_probs,
-            dtype=jnp.float32,
+        pg_loss = -masked_mean(
+            jax.lax.stop_gradient(pg_advantages) * target_log_probs, mask
         )
-        v_loss = 0.5 * jnp.mean(
-            (values - jax.lax.stop_gradient(value_targets)) ** 2,
-            dtype=jnp.float32,
+        v_loss = 0.5 * masked_mean(
+            (values - jax.lax.stop_gradient(value_targets)) ** 2, mask
         )
         loss = pg_loss + cfg.value_coef * v_loss - cfg.entropy_coef * entropy
     return loss, {
@@ -230,6 +255,7 @@ def impala_loss(
         "entropy": entropy,
         "mean_rho": mean_rho,
         "truncated_frac": truncated_frac,
+        **out.metrics,
     }
 
 
@@ -239,22 +265,21 @@ def make_train_step(
     axis_name: Optional[str] = None,
 ) -> Callable[[ImpalaTrainState], tuple[ImpalaTrainState, dict[str, jax.Array]]]:
     """Fused rollout(stale actor) → V-trace → update → k-step actor refresh."""
-    net = make_network(env, cfg)
+    policy = make_policy(env, cfg)
     opt = make_optimizer(cfg)
-    apply_fn = net.apply
 
     def train_step(state: ImpalaTrainState):
         key, rkey = jax.random.split(state.key)
 
         # Actors run the STALE params; behaviour log-probs are recorded.
         new_rollout, traj = rollout_scan(
-            env, apply_fn, state.actor_params, state.rollout, rkey,
+            env, policy, state.actor_params, state.rollout, rkey,
             cfg.rollout_steps,
         )
 
         grad_fn = jax.value_and_grad(impala_loss, has_aux=True)
         (_, metrics), grads = grad_fn(
-            state.params, apply_fn, traj, new_rollout.obs, cfg,
+            state.params, policy, traj, new_rollout.obs, cfg,
             env.spec.can_truncate,
         )
         with jax.named_scope("optimizer"):

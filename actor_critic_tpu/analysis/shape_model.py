@@ -441,12 +441,13 @@ _SHARED: dict = {}
 def module_flows(mod: ModuleInfo) -> list[ScopeFlow]:
     """[ScopeFlow] for every scope in `mod`, cached per module so the
     three shapes passes build the model once."""
-    key = id(mod)
     entry = _SHARED.get("entry")
-    if entry is not None and entry[0] == key:
+    # The entry holds the module itself, as the other passes' entries do: an
+    # `id()` alone is handed to the next module once this one is collected.
+    if entry is not None and entry[0] is mod:
         return entry[1]
     flows = [build_scope_flow(mod, scope) for scope in iter_scopes(mod)]
-    _SHARED["entry"] = (key, flows)
+    _SHARED["entry"] = (mod, flows)
     return flows
 
 

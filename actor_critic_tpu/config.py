@@ -15,6 +15,7 @@ import typing
 from typing import Any, Optional, Union
 
 from actor_critic_tpu.algos import a2c, ddpg, impala, ppo, sac
+from actor_critic_tpu.models.seq_policy import SeqPolicyConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,6 +176,53 @@ PRESETS: dict[str, Preset] = {
         "(ISSUE 11 scenario universe)",
         env_kwargs={"randomize": 0.2},
     ),
+    # Token-level IMPALA over one chip's share of JoyAI-LLM-Flash
+    # (https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/config.json;
+    # SeqPolicyConfig's defaults are its published widths): 16 chips share
+    # each layer, so this one holds 16 of the 256 routed experts and an
+    # eighth of the vocabulary (16,160 rows, the env's ids), and 1 dense +
+    # 4 expert layers of the 40 (further layers lie on further stages). 565M
+    # parameters, 9.0 GB with the actor copy, gradients and RMSProp's moment.
+    # An episode is one unroll: `horizon` must equal `rollout_steps`.
+    "impala_joyai_flash": Preset(
+        algo="impala",
+        env="jax:token_task",
+        config=impala.ImpalaConfig(
+            num_envs=64, rollout_steps=512, actor_refresh_every=4,
+            gamma=1.0, lr=1e-4, entropy_coef=0.001,
+            seq=SeqPolicyConfig(
+                num_hidden_layers=5, experts_held=16, expert_offset=0,
+            ),
+        ),
+        iterations=200,
+        description="Token-level IMPALA over a chip's share of "
+        "JoyAI-LLM-Flash (MLA, 16 of 256 sigmoid-routed experts, 5 layers) "
+        "on the prompt-copy task",
+        env_kwargs={"vocab_size": 16160, "horizon": 512,
+                    "prompt_min": 16, "prompt_max": 128},
+    ),
+    # The same program at widths a CPU runs in seconds (tests, a first
+    # drive): every mechanism of the block, none of its sizes.
+    "impala_joyai_flash_tiny": Preset(
+        algo="impala",
+        env="jax:token_task",
+        config=impala.ImpalaConfig(
+            num_envs=32, rollout_steps=16, actor_refresh_every=2,
+            gamma=1.0, lr=3e-3, rms_eps=1e-5, entropy_coef=0.003,
+            seq=SeqPolicyConfig(
+                hidden_size=64, num_attention_heads=4, q_lora_rank=32,
+                kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+                v_head_dim=8, intermediate_size=128,
+                moe_intermediate_size=32, n_routed_experts=16,
+                num_experts_per_tok=2, num_hidden_layers=2, experts_held=4,
+                compute_dtype="float32",
+            ),
+        ),
+        iterations=300,
+        description="impala_joyai_flash at toy widths (CPU tests and drives)",
+        env_kwargs={"vocab_size": 8, "horizon": 16,
+                    "prompt_min": 1, "prompt_max": 2},
+    ),
     "a3c_pong": Preset(
         algo="a3c",
         env="jax:pong",
@@ -230,20 +278,34 @@ def _coerce(raw: str, typ: Any) -> Any:
 def apply_overrides(config: Any, overrides: dict[str, str]) -> Any:
     """`dataclasses.replace` with string values coerced to field types.
 
-    Unknown keys raise with the list of valid fields (typo safety).
+    Unknown keys raise with the list of valid fields (typo safety). A
+    dotted key reaches into a field that is itself a config
+    (`seq.hidden_size=64`); the group must be set in the preset.
     """
     if not overrides:
         return config
     hints = typing.get_type_hints(type(config))
     fields = {f.name for f in dataclasses.fields(config)}
     updates = {}
+    nested: dict[str, dict[str, str]] = {}
     for key, raw in overrides.items():
+        group, dot, rest = key.partition(".")
+        if dot and group in fields:
+            if not dataclasses.is_dataclass(getattr(config, group)):
+                raise KeyError(
+                    f"{type(config).__name__}.{group} is not set in this "
+                    f"preset, so {key!r} has nothing to override"
+                )
+            nested.setdefault(group, {})[rest] = raw
+            continue
         if key not in fields:
             raise KeyError(
                 f"{type(config).__name__} has no field {key!r}; "
                 f"valid: {sorted(fields)}"
             )
         updates[key] = _coerce(raw, hints[key])
+    for group, sub in nested.items():
+        updates[group] = apply_overrides(getattr(config, group), sub)
     return dataclasses.replace(config, **updates)
 
 

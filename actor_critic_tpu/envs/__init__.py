@@ -5,6 +5,7 @@ from actor_critic_tpu.envs.maze import make_maze
 from actor_critic_tpu.envs.mixture import MixtureEnv, make_mixture, parse_mixture_spec
 from actor_critic_tpu.envs.pendulum import make_pendulum
 from actor_critic_tpu.envs.pong import make_pong
+from actor_critic_tpu.envs.token_task import make_token_task
 from actor_critic_tpu.envs.testbeds import (
     make_bandit,
     make_point_mass,
@@ -25,6 +26,7 @@ __all__ = [
     "make_pendulum",
     "make_point_mass",
     "make_pong",
+    "make_token_task",
     "make_two_state_mdp",
     "parse_mixture_spec",
 ]

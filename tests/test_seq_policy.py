@@ -1,0 +1,402 @@
+"""The sequence policy (`models/seq_policy.py`), the token env and the policy
+seam of the fused trainers, at toy widths on the CPU: step through the cache
+= one causal pass = the plain reference; the chip's share of the experts adds
+up; no routing drops a token; masked steps carry no gradient."""
+
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import train  # noqa: E402
+from actor_critic_tpu import config as config_mod  # noqa: E402
+from actor_critic_tpu.algos import common, impala  # noqa: E402
+from actor_critic_tpu.envs import make_token_task  # noqa: E402
+from actor_critic_tpu.models import seq_policy as sp  # noqa: E402
+from benchmark import harness  # noqa: E402
+
+TINY = "impala_joyai_flash_tiny"
+HP = dict(gamma=1.0, rho_bar=1.0, c_bar=1.0, lam=1.0, value_coef=0.5,
+          entropy_coef=0.003)
+
+
+@pytest.fixture(autouse=True)
+def toy_blocking(monkeypatch):
+    """The blocking constants of `seq_policy` at the toy widths' scale, so
+    that a decode step takes the batched matmuls and a causal pass of a few
+    rows the grouped ones in several trips, as at the shipped sizes."""
+    monkeypatch.setattr(sp, "MOE_ROWS", 256)
+    monkeypatch.setattr(sp, "MOE_DENSE_TOKENS", 32)
+
+
+def _network(seq: sp.SeqPolicyConfig) -> dict:
+    """What the benchmark's configuration file would say of `seq`."""
+    return dataclasses.asdict(seq)
+
+
+def _reference():
+    return harness.load_module("reference", "impala_joyai_flash")
+
+
+def _setup(sets=None, env_sets=None, seed=0):
+    """The tiny preset; with `env_sets`, over a vocabulary of 64."""
+    if env_sets is not None:
+        env_sets = {"vocab_size": 64, **env_sets}
+    preset = config_mod.resolve(TINY, None, None, sets or {}, env_overrides=env_sets)
+    cfg = preset.config
+    env, _ = train.build_env(preset.env, preset.algo, cfg, seed,
+                             env_kwargs=preset.env_kwargs)
+    return env, cfg, impala.make_policy(env, cfg)
+
+
+def _rollout(env, cfg, policy, behaviour, seed=3):
+    rstate = common.init_rollout(env, jax.random.key(seed), cfg.num_envs)
+    return jax.jit(lambda p, r, k: common.rollout_scan(
+        env, policy, p, r, k, cfg.rollout_steps))(
+            behaviour, rstate, jax.random.key(seed + 1))
+
+
+# -- the env ---------------------------------------------------------------
+
+def test_token_task_feeds_the_prompt_then_the_action_and_pays_at_the_end():
+    env = make_token_task(vocab_size=32, horizon=8, prompt_min=3, prompt_max=3)
+    state, obs = env.reset(jax.random.key(0))
+    prompt = [int(t) for t in state.prompt]
+    assert 0 not in prompt and int(state.prompt_len) == 3
+    seen, rewards, dones = [np.asarray(obs)], [], []
+    # A perfect copier: the response repeats the prompt cyclically.
+    for t in range(8):
+        out = env.step(state, jnp.asarray(prompt[(t + 1 - 3) % 3]))
+        state = out.state
+        seen.append(np.asarray(out.obs)); rewards.append(float(out.reward))
+        dones.append(float(out.done))
+    tokens = [int(o[0]) for o in seen[:8]]
+    assert tokens == prompt + [prompt[i % 3] for i in range(5)]
+    assert [int(o[1]) for o in seen[:8]] == list(range(8))
+    assert [int(o[2]) for o in seen[:8]] == [1, 1, 0, 0, 0, 0, 0, 0]
+    assert rewards == [0.0] * 7 + [1.0] and dones == [0.0] * 7 + [1.0]
+    assert int(seen[8][1]) == 0 and float(out.info["terminated"]) == 1.0
+
+
+def test_token_task_eos_ends_the_episode_and_pads_the_row():
+    env = make_token_task(vocab_size=32, horizon=8, prompt_min=2, prompt_max=2)
+    state, _ = env.reset(jax.random.key(1))
+    prompt = [int(t) for t in state.prompt]
+    actions = [5, prompt[0], prompt[1], 0, 7, 7, 7, 7]  # EOS at position 3
+    outs = []
+    for a in actions:
+        out = env.step(state, jnp.asarray(a)); state = out.state; outs.append(out)
+    assert [float(o.done) for o in outs] == [0, 0, 0, 1, 0, 0, 0, 0]
+    # Three response tokens, two of them right (the EOS is not the prompt's).
+    assert outs[3].reward == pytest.approx(2 / 3)
+    assert all(float(o.reward) == 0 for i, o in enumerate(outs) if i != 3)
+    # Padding: the EOS id under is_prompt = 1 until the row resets.
+    pads = [np.asarray(o.info["final_obs"]) for o in outs[3:]]
+    assert all(int(p[0]) == 0 and int(p[2]) == 1 for p in pads)
+    assert int(outs[-1].obs[1]) == 0 and not bool(outs[-1].state.finished)
+
+
+def test_token_task_refuses_a_prompt_that_fills_the_row():
+    with pytest.raises(ValueError, match="prompt_max < horizon"):
+        make_token_task(vocab_size=32, horizon=8, prompt_max=8)
+
+
+# -- step = unroll = reference ---------------------------------------------
+
+def _with_an_eos(env, cfg, policy, behaviour):
+    """A rollout in which some row holds an EOS before its end."""
+    for seed in range(3, 40):
+        _, traj = _rollout(env, cfg, policy, behaviour, seed)
+        early = np.asarray(traj.done)[:-1].sum()
+        if early > 0:
+            return traj
+    raise AssertionError("no seed gave an EOS mid-row")
+
+
+@pytest.mark.parametrize("compute_dtype, tol", [("float32", 2e-5), ("bfloat16", 6e-2)])
+def test_step_through_the_cache_equals_unroll_equals_reference(compute_dtype, tol):
+    env, cfg, policy = _setup({"num_envs": "6", "seq.compute_dtype": compute_dtype},
+                              {"prompt_min": 1, "prompt_max": 6})
+    behaviour = impala.init_params(env, cfg, jax.random.key(1))
+    traj = _with_an_eos(env, cfg, policy, behaviour)
+    prompts = np.asarray(traj.obs[..., 2]).sum(axis=0)
+    assert len(set(prompts.tolist())) > 1, "uneven prompts"
+    # The rollout decoded token by token through the latent cache; the causal
+    # pass over the same rows gives the same log-probabilities and values.
+    out = policy.unroll(behaviour, traj)
+    live = np.asarray(out.mask) > 0
+    assert np.abs(np.asarray(out.log_prob - traj.log_prob))[live].max() < tol
+    assert np.abs(np.asarray(out.value - traj.value)).max() < tol
+    if compute_dtype != "float32":
+        # At toy widths one rounded router score moves a token to another
+        # expert and its logits with it; the reference is held in float32.
+        return
+    # And the plain reference's full forward pass gives the same logits.
+    logits, values = sp.logits_and_values(
+        behaviour, jnp.swapaxes(traj.obs, 0, 1), cfg.seq)
+    want_logits, want_values = _reference().forward(
+        behaviour, traj.obs, _network(cfg.seq))
+    scale = float(jnp.max(jnp.abs(want_logits)))
+    assert float(jnp.max(jnp.abs(jnp.swapaxes(logits, 0, 1) - want_logits))) < tol * scale
+    assert float(jnp.max(jnp.abs(values.T - want_values))) < tol
+    assert np.abs(np.asarray(out.value - want_values)).max() < tol
+
+
+def test_loss_and_gradients_agree_with_the_reference(monkeypatch):
+    monkeypatch.setattr(sp, "MOE_ROWS", 16)
+    env, cfg, policy = _setup({"num_envs": "6"}, {"prompt_min": 1, "prompt_max": 6})
+    params = impala.init_params(env, cfg, jax.random.key(0))
+    behaviour = impala.init_params(env, cfg, jax.random.key(1))
+    rstate, traj = _rollout(env, cfg, policy, behaviour)
+    hp = {**HP, "entropy_coef": cfg.entropy_coef, "value_coef": cfg.value_coef}
+
+    def program(p):
+        return impala.impala_loss(p, policy, traj, rstate.obs, cfg, False)
+
+    (loss, metrics), grads = jax.value_and_grad(program, has_aux=True)(params)
+    ref = _reference()
+    net = _network(cfg.seq)
+    want_all = ref.loss_and_targets(params, traj._asdict(), rstate.obs, hp, net)
+    want = jnp.sum(want_all["loss"])   # the reference gives the loss as its terms
+
+    def surrogate(p):
+        """The reference's loss with its targets held fixed, as the program
+        holds them (`stop_gradient`): from the reference's own forward pass."""
+        logits, values = ref.forward(p, traj.obs, net)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        lp = jnp.take_along_axis(logp, traj.action[..., None], axis=-1)[..., 0]
+        mask = 1.0 - traj.obs[..., 2].astype(jnp.float32)
+        mean = lambda x: jnp.sum(x * mask) / jnp.sum(mask)  # noqa: E731
+        entropy = mean(-jnp.sum(jnp.exp(logp) * logp, axis=-1))
+        return (-mean(want_all["pg_advantages"] * lp)
+                + hp["value_coef"] * 0.5 * mean((values - want_all["value_targets"]) ** 2)
+                - hp["entropy_coef"] * entropy)
+
+    again, want_grads = jax.value_and_grad(surrogate)(params)
+    assert float(again) == pytest.approx(float(want), rel=1e-5, abs=1e-6)
+    assert float(loss) == pytest.approx(float(want), rel=1e-5, abs=1e-6)
+    assert float(metrics["moe_dropped"]) == 0.0
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
+        scale = max(float(jnp.max(jnp.abs(w))), 1e-6)
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-4 * scale + 1e-7, \
+            jax.tree_util.keystr(path)
+    # The frozen bias gets no gradient, the router does.
+    moe = grads["params"]["layer_1"]["moe"]
+    assert float(jnp.max(jnp.abs(moe["bias"]))) == 0.0
+    assert float(jnp.max(jnp.abs(moe["router"]))) > 0.0
+
+
+def test_masked_prompt_steps_give_no_gradient():
+    """Where the env ignored the action (prompt, padding), the action taken
+    reaches neither the loss nor any gradient."""
+    env, cfg, policy = _setup({"num_envs": "6"}, {"prompt_min": 2, "prompt_max": 6})
+    params = impala.init_params(env, cfg, jax.random.key(0))
+    behaviour = impala.init_params(env, cfg, jax.random.key(1))
+    rstate, traj = _rollout(env, cfg, policy, behaviour)
+    ignored = traj.obs[..., 2] > 0
+    assert 0 < int(ignored.sum()) < ignored.size
+    other = traj._replace(
+        action=jnp.where(ignored, (traj.action + 7) % env.spec.action_dim, traj.action),
+        log_prob=jnp.where(ignored, traj.log_prob - 1.0, traj.log_prob))
+    grad = jax.grad(lambda p, t: impala.impala_loss(
+        p, policy, t, rstate.obs, cfg, False)[0])
+    a, b = grad(params, traj), grad(params, other)
+    assert all(bool(jnp.array_equal(x, y))
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+    # A live step's action does reach the gradient.
+    moved = traj._replace(action=jnp.where(ignored, traj.action,
+                                           (traj.action + 7) % env.spec.action_dim))
+    c = grad(params, moved)
+    assert not all(bool(jnp.array_equal(x, y))
+                   for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(c)))
+
+
+# -- the expert layer and the chip's share ----------------------------------
+
+def _expert_layer(seq, key=0):
+    params = sp.init_params(jax.random.key(key), seq, 32)
+    return params["params"]["layer_1"]["moe"]
+
+
+def _whole_layer_reference(full, h, seq):
+    """The uncut layer by the plain reference: every expert held."""
+    net = {**_network(seq), "expert_offset": 0}
+    return _reference()._moe(full, h, net)
+
+
+@pytest.mark.parametrize("dense_tokens", [0, 4096])
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_reference(
+        dense_tokens, monkeypatch):
+    """Through the grouped matmuls (0) and through every held expert on every
+    token (4096: a decode step's path)."""
+    monkeypatch.setattr(sp, "MOE_DENSE_TOKENS", dense_tokens)
+    seq = config_mod.PRESETS[TINY].config.seq
+    whole = dataclasses.replace(seq, experts_held=seq.n_routed_experts)
+    full = _expert_layer(whole)
+    h = jax.random.normal(jax.random.key(5), (48, seq.hidden_size))
+    want = _whole_layer_reference(full, h, whole)
+    shared = sp._swiglu(full["shared"], h, jnp.float32)
+    shares = seq.n_routed_experts // seq.experts_held
+    total, landed = shared, 0.0
+    for i in range(shares):
+        cut = dataclasses.replace(seq, expert_offset=i * seq.experts_held)
+        lo, hi = cut.expert_offset, cut.expert_offset + cut.experts_held
+        part = {**full, "experts": jax.tree.map(lambda a: a[lo:hi], full["experts"])}
+        y, stats = sp.moe(part, h, cut)
+        # Each share computes the shared expert whole; count it once.
+        total = total + (y - shared)
+        landed += float(stats["routed_here_frac"])
+        # The share agrees with the reference given the same share.
+        ref_part = _reference()._moe(part, h, _network(cut))
+        assert float(jnp.max(jnp.abs(y - ref_part))) < 1e-5
+    assert landed == pytest.approx(1.0)
+    assert float(jnp.max(jnp.abs(total - want))) < 2e-5
+
+
+@pytest.mark.parametrize("moe_rows, dense_tokens", [
+    (4, 0), (16, 0), (4096, 0), (4096, 4096)])
+def test_every_token_to_one_held_expert_drops_nothing(
+        moe_rows, dense_tokens, monkeypatch):
+    """The worst routing for a grouped matmul: every token's top choices
+    include the same held expert. Trips of `moe_rows` assignments take them
+    all, whatever the trip size."""
+    monkeypatch.setattr(sp, "MOE_ROWS", moe_rows)
+    monkeypatch.setattr(sp, "MOE_DENSE_TOKENS", dense_tokens)
+    seq = config_mod.PRESETS[TINY].config.seq
+    layer = _expert_layer(seq)
+    # Expert 2 (held) and expert 9 (absent) win every token.
+    bias = jnp.zeros_like(layer["bias"]).at[jnp.array([2, 9])].set(10.0)
+    layer = {**layer, "bias": bias}
+    h = jax.random.normal(jax.random.key(6), (40, seq.hidden_size))
+    y, stats = jax.jit(lambda p, h: sp.moe(p, h, seq))(layer, h)
+    assert float(stats["moe_dropped"]) == 0.0
+    assert float(stats["routed_here_frac"]) == pytest.approx(0.5)
+    assert float(stats["expert_load_max_over_mean"]) == pytest.approx(seq.experts_held)
+    want = _reference()._moe(layer, h, _network(seq))
+    assert float(jnp.max(jnp.abs(y - want))) < 1e-5
+    # Gradients through the trips too (the loop's reverse rule is written out).
+    g = jax.grad(lambda p, h: jnp.sum(sp.moe(p, h, seq)[0] ** 2), argnums=(0, 1))(layer, h)
+    w = jax.grad(lambda p, h: jnp.sum(_reference()._moe(p, h, _network(seq)) ** 2),
+                 argnums=(0, 1))(layer, h)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4 * max(float(jnp.max(jnp.abs(b))), 1.0)
+
+
+def test_the_bias_changes_which_experts_are_chosen_and_not_their_weights():
+    seq = config_mod.PRESETS[TINY].config.seq
+    layer = _expert_layer(seq)
+    h = jax.random.normal(jax.random.key(7), (64, seq.hidden_size))
+    idx0, w0 = sp.route({**layer, "bias": jnp.zeros_like(layer["bias"])}, h, seq)
+    tilt = jnp.zeros_like(layer["bias"]).at[3].set(10.0)
+    idx1, w1 = sp.route({**layer, "bias": tilt}, h, seq)
+    assert bool(jnp.all(jnp.any(idx1 == 3, axis=-1))) and not bool(
+        jnp.all(jnp.any(idx0 == 3, axis=-1)))
+    # Weights are the sigmoid scores of the chosen, normalised over them and
+    # scaled: the bias enters nowhere.
+    s = jax.nn.sigmoid(h @ layer["router"])
+    chosen = jnp.take_along_axis(s, idx1, axis=-1)
+    want = chosen / chosen.sum(-1, keepdims=True) * seq.routed_scaling_factor
+    assert float(jnp.max(jnp.abs(w1 - want))) < 1e-5
+    assert float(jnp.max(jnp.abs(w1.sum(-1) - seq.routed_scaling_factor))) < 1e-5
+
+
+# -- the preset, the seam, the eval ------------------------------------------
+
+def test_the_preset_refuses_an_episode_that_is_not_one_unroll():
+    preset = config_mod.resolve(TINY, None, None, {"rollout_steps": "8"})
+    env, _ = train.build_env(preset.env, preset.algo, preset.config, 0,
+                             env_kwargs=preset.env_kwargs)
+    with pytest.raises(ValueError, match="exactly one unroll.*--env-set horizon=8"):
+        impala.make_train_step(env, preset.config)
+    with pytest.raises(ValueError, match="reads .token id, position, is_prompt."):
+        from actor_critic_tpu.envs import make_cartpole
+        impala.make_policy(make_cartpole(), preset.config)
+
+
+def test_dotted_overrides_reach_the_policys_group():
+    cfg = config_mod.resolve(TINY, None, None, {
+        "seq.hidden_size": "48", "lr": "1e-3", "seq.compute_dtype": "bfloat16"}).config
+    assert (cfg.seq.hidden_size, cfg.lr, cfg.seq.compute_dtype) == (48, 1e-3, "bfloat16")
+    with pytest.raises(KeyError, match="no field 'nope'"):
+        config_mod.resolve(TINY, None, None, {"seq.nope": "1"})
+    with pytest.raises(KeyError, match="is not set in this preset"):
+        config_mod.resolve("impala_pong", None, None, {"seq.hidden_size": "48"})
+
+
+def test_the_shipped_preset_is_the_configuration_the_benchmark_states():
+    """Every width of `benchmark/configs/impala_joyai_flash.json` is the
+    preset's, and the catalog's keys at the file's top level agree with its
+    `network` group."""
+    with open(os.path.join(ROOT, "benchmark/configs/impala_joyai_flash.json")) as fh:
+        cfg = json.load(fh)
+    preset = config_mod.PRESETS["impala_joyai_flash"]
+    seq, net = preset.config.seq, cfg["network"]
+    for key, value in net.items():
+        if hasattr(seq, key):
+            assert getattr(seq, key) == value, key
+    assert net["vocab_size"] == preset.env_kwargs["vocab_size"] == cfg["vocab_size"]
+    assert cfg["n_routed_experts"] == seq.experts_held == 16
+    assert cfg["published"]["n_routed_experts"] == seq.n_routed_experts == 256
+    for key in ("hidden_size", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
+                "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+                "intermediate_size", "moe_intermediate_size", "num_experts_per_tok",
+                "routed_scaling_factor", "first_k_dense_replace",
+                "num_hidden_layers", "rms_norm_eps"):
+        assert cfg[key] == net[key] == getattr(seq, key), key
+    assert cfg["rope_theta"] == seq.rope_theta
+    algo = cfg["algorithm"]
+    for key in ("num_envs", "rollout_steps", "gamma", "lam", "rho_bar", "c_bar",
+                "value_coef", "entropy_coef", "lr", "actor_refresh_every"):
+        assert algo[key] == getattr(preset.config, key), key
+    assert preset.env_kwargs["horizon"] == preset.config.rollout_steps == 512
+    # 565M parameters, as the file says.
+    shapes = jax.eval_shape(
+        lambda: sp.init_params(jax.random.key(0), seq, net["vocab_size"]))
+    count = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    assert 564.5e6 < count < 565.5e6
+
+
+def test_greedy_eval_threads_the_cache():
+    """`--eval-every`'s program: greedy decode through the policy's carry,
+    equal to decoding by hand."""
+    env, cfg, policy = _setup({"num_envs": "4"})
+    state = impala.init_state(env, cfg, jax.random.key(0))
+    # Make the greedy policy a copier of the current token, so the return is known.
+    eval_fn = jax.jit(impala.make_eval_fn(env, cfg), static_argnums=(2, 3))
+    got = float(eval_fn(state, jax.random.key(9), 8, cfg.rollout_steps + 8))
+
+    keys = jax.random.split(jax.random.key(9), 8)
+    env_state, obs = jax.vmap(env.reset)(keys)
+    carry, ret = policy.init_carry(8), np.zeros(8)
+    alive = np.ones(8)
+    for _ in range(cfg.rollout_steps):
+        dist, _, carry = policy.step(state.params, obs, carry)
+        out = jax.vmap(env.step)(env_state, dist.mode())
+        env_state, obs = out.state, out.obs
+        ret += np.asarray(out.reward) * alive
+        alive *= 1.0 - np.asarray(out.done)
+    assert got == pytest.approx(float(ret.mean()), abs=1e-6)
+
+
+def test_the_tiny_presets_return_rises_on_the_copy_task():
+    env, cfg, _ = _setup()
+    state = impala.init_state(env, cfg, jax.random.key(0))
+    step = jax.jit(impala.make_train_step(env, cfg), donate_argnums=0)
+    returns = []
+    for _ in range(250):
+        state, m = step(state)
+        returns.append(m["mean_finished_return"])
+    first, last = float(np.mean(returns[:10])), float(np.mean(returns[-10:]))
+    assert float(m["moe_dropped"]) == 0.0 and np.isfinite(float(m["loss"]))
+    # Chance is 1 / 7 a token; a policy that learnt to repeat does far better.
+    assert last > 0.35 and last > 2 * first, (first, last)
