@@ -44,6 +44,17 @@ The model is plain functions over a parameter tree shaped like a flax one
 `lax.map` / `jax.checkpoint`, which linen's lifted transforms would only wrap.
 `make_policy` gives the trainers' `common.Policy`.
 
+What the causal pass keeps for its backward pass is two arrays a layer, the
+layer's input and the residual stream after attention (`[E, T, H]` float32:
+2 x 268 MB at the shipped shape): each half of a layer is one
+`jax.checkpoint` (`trunk`), and inside the halves every `lax.map` trip
+(`_map_rows`: `ATTN_ROWS` episodes of attention, `MLP_ROWS` / `HEAD_ROWS`
+token rows) is one more, so the `[heads, T, T]` scores and the wide halves of
+MLA are alive a trip at a time, in the backward pass too. The update therefore
+runs a trip of attention forward twice (the forward pass, the trip's own
+rematerialization) and the FFN likewise; the cut between the halves is there
+so that rebuilding the FFN's input does not run attention a third time.
+
 The cache is a carry of the rollout scan and starts fresh with it, so an
 episode must be exactly one unroll and every row at the same position: the
 token env guarantees both (`envs/token_task.py`) and `make_policy` refuses an
@@ -109,7 +120,7 @@ _EPS = 1e-20
 # [-BIAS_SCALE, BIAS_SCALE].
 BIAS_SCALE = 0.05
 # Blocking of the passes (memory, never results), sized so that the shipped
-# preset's step program fits one v5e (13.9 GB by `memory_analysis()`).
+# preset's step program fits one v5e (15.2 GB by `memory_analysis()`).
 ATTN_ROWS = 8       # episodes a trip of the causal pass's attention
 MLP_ROWS = 8192     # token rows a trip of the dense MLP and the shared expert
 HEAD_ROWS = 4096    # token rows a trip of the lm_head
@@ -322,23 +333,31 @@ def _held_experts(cfg: SeqPolicyConfig, R: int):
     def trip(j, experts, h, w_flat, route):
         """Assignments `[j R, (j + 1) R)`: (weighted outputs [R, H], (their
         tokens [R], assignments done)). Rows past the last landed assignment
-        belong to no group: masked to zero on the way in and out."""
+        belong to no group, and what a grouped matmul leaves in such rows of
+        its result is whatever the memory held (on the chip; zeros on the
+        CPU): NaN there would reach the router's gradient as `0 * NaN`. So
+        every grouped matmul has those rows selected to zero on its way in
+        and on its way out, which holds its two cotangents to zero there
+        too."""
         order, ends, sizes, landed = route
         lo = j * R
         with jax.named_scope("moe_route"):
             rows = jax.lax.dynamic_slice(order, (lo,), (R,))
             valid = (lo + jnp.arange(R) < landed)[:, None]
             token = rows // k
-            x = jnp.where(valid, jnp.take(h, token, axis=0), 0.0)
+            x = jnp.take(h, token, axis=0)
             sizes_j = jnp.clip(ends - lo, 0, R) - jnp.clip(ends - sizes - lo, 0, R)
         with jax.named_scope("moe_experts"):
-            dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
-                a.astype(cd), w.astype(cd), sizes_j,
-                preferred_element_type=jnp.float32)
+            def dot(a, w):
+                out = jax.lax.ragged_dot(
+                    jnp.where(valid, a, 0.0).astype(cd), w.astype(cd), sizes_j,
+                    preferred_element_type=jnp.float32)
+                return jnp.where(valid, out, 0.0)
+
             act = jax.nn.silu(dot(x, experts["w_gate"])) * dot(x, experts["w_up"])
-            out = dot(jnp.where(valid, act, 0.0), experts["w_down"])
+            out = dot(act, experts["w_down"])
         with jax.named_scope("moe_route"):
-            out = jnp.where(valid, out * jnp.take(w_flat, rows)[:, None], 0.0)
+            out = out * jnp.take(w_flat, rows)[:, None]
         return out, (token, jnp.sum(sizes_j))
 
     def trips_of(route):
@@ -483,24 +502,36 @@ def _value(p, h):
 
 
 def trunk(params, obs, cfg: SeqPolicyConfig):
-    """The causal pass over `obs [E, T, 3]`, a layer rematerialized at a time:
-    (final-normed hidden [E, T, H], the expert layers' stats, layers-mean)."""
+    """The causal pass over `obs [E, T, 3]`: (final-normed hidden [E, T, H],
+    the expert layers' stats, layers-mean).
+
+    Each half of a layer is rematerialized on its own, so the backward pass
+    keeps the layer's input and the residual stream after attention. With
+    one checkpoint round the whole layer only the input is kept, and the
+    FFN's backward, which starts from that residual stream, has every trip
+    of `attend` run forward again just to rebuild it, before the trips'
+    own rematerialization runs them a third time. Cut here, the attention
+    half's rematerialization recomputes the latents (the trips' inputs) and
+    nothing of `attend`, whose output feeds only the linear `x + .`."""
     p, layers = _layers(params)
     tokens, positions = obs[..., 0], obs[..., 1]
     E, T = tokens.shape
     x = jnp.take(p["embed"], tokens, axis=0)
 
-    def block(layer, x):
+    def attn(layer, x):
         with jax.named_scope("mla"):
             h = _rms(x, layer["attn_norm"], cfg.rms_norm_eps)
-            x = x + mla_unroll(layer["mla"], h, positions, cfg)
+            return x + mla_unroll(layer["mla"], h, positions, cfg)
+
+    def ffn(layer, x):
         h = _rms(x, layer["ffn_norm"], cfg.rms_norm_eps).reshape(E * T, -1)
         y, stats = _ffn(layer, h, cfg)
         return x + y.reshape(x.shape), stats
 
     stats = []
     for layer in layers:
-        x, s = jax.checkpoint(block)(layer, x)
+        x = jax.checkpoint(attn)(layer, x)
+        x, s = jax.checkpoint(ffn)(layer, x)
         if s is not None:
             stats.append(s)
     mean = {k: jnp.mean(jnp.stack([s[k] for s in stats])) for k in stats[0]} \

@@ -1,12 +1,16 @@
 """The sequence policy (`models/seq_policy.py`), the token env and the policy
 seam of the fused trainers, at toy widths on the CPU: step through the cache
 = one causal pass = the plain reference; the chip's share of the experts adds
-up; no routing drops a token; masked steps carry no gradient."""
+up; no routing drops a token; masked steps carry no gradient; the causal
+pass's checkpoints change no gradient and run attention forward twice."""
 
 import dataclasses
+import functools
 import json
 import os
+import re
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -221,6 +225,74 @@ def test_masked_prompt_steps_give_no_gradient():
                    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(c)))
 
 
+# -- what the causal pass rematerializes ------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _unroll_gradient(compute_dtype: str, checkpointed: bool):
+    """The gradient of a loss over `unroll`'s log-probability, entropy and
+    value at three layers (one dense, two expert) and `ATTN_ROWS` < E, so
+    that the attention's trips exist: (gradient, products with a `[.., T, T]`
+    result in the compiled program). `checkpointed=False` builds the same
+    program with `jax.checkpoint` the identity: nothing rematerialized. T is
+    no other size of the toy model, so a product whose result ends in
+    `[T, T]` is one over the scores. (The expert layer's blocking is the
+    file's `toy_blocking`, the same under every test, so the cache is sound.)"""
+    E, T, V = 6, 12, 64
+    seq = dataclasses.replace(config_mod.PRESETS[TINY].config.seq,
+                              num_hidden_layers=3, compute_dtype=compute_dtype)
+    params = sp.init_params(jax.random.key(0), seq, V)
+    keys = jax.random.split(jax.random.key(1), 5)
+    obs = jnp.stack([jax.random.randint(keys[0], (E, T), 0, V),
+                     jnp.broadcast_to(jnp.arange(T), (E, T)),
+                     jnp.zeros((E, T), jnp.int32)], axis=-1)
+    actions = jax.random.randint(keys[1], (E, T), 0, V)
+    w_logp, w_entropy, w_value = (jax.random.normal(k, (E, T)) for k in keys[2:])
+
+    def loss(p):
+        log_prob, entropy, value, _ = sp.unroll(p, obs, actions, seq)
+        return jnp.sum(log_prob * w_logp + entropy * w_entropy + value * w_value)
+
+    identity = lambda fn, *args, **kwargs: fn  # noqa: E731
+    with mock.patch.object(sp, "ATTN_ROWS", 2), mock.patch.object(
+            jax, "checkpoint", jax.checkpoint if checkpointed else identity):
+        compiled = jax.jit(jax.grad(loss)).lower(params).compile()
+    products = re.findall(rf"= \w+\[[\d,]*{T},{T}\]\S* dot\(", compiled.as_text())
+    return compiled(params), len(products)
+
+
+@pytest.mark.parametrize("compute_dtype", ["bfloat16", "float32"])
+def test_the_rematerialized_gradient_is_the_plain_one(compute_dtype):
+    """Cutting the pass into checkpoints changes when a value is computed,
+    never which: at float32 the gradient equals, leaf for leaf and bit for
+    bit, that of the program with no `jax.checkpoint` at all; at bfloat16
+    (where the plain program may fuse, and so round, differently) within the
+    rounding."""
+    got, _ = _unroll_gradient(compute_dtype, True)
+    want, _ = _unroll_gradient(compute_dtype, False)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    norm = lambda x: float(jnp.linalg.norm(x))  # noqa: E731
+    assert sum(norm(g) ** 2 for _, g in flat) ** 0.5 > 1.0
+    for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        name = jax.tree_util.keystr(path)
+        if compute_dtype == "float32":
+            assert bool(jnp.array_equal(g, w)), name
+        else:
+            assert norm(g - w) <= 1e-2 * norm(w), name
+
+
+@pytest.mark.parametrize("compute_dtype", ["bfloat16", "float32"])
+def test_the_update_computes_the_scores_twice_a_layer(compute_dtype):
+    """The compiled gradient holds three products with a `[.., T, T]` result
+    a layer: the forward pass's scores, the scores of a trip's
+    rematerialization, and the softmax's cotangent. A fourth is a layer's
+    rematerialization running attention forward again only to rebuild the
+    FFN's input (one checkpoint round a whole layer does that); with nothing
+    rematerialized there are two."""
+    _, products = _unroll_gradient(compute_dtype, True)
+    _, plain = _unroll_gradient(compute_dtype, False)
+    assert (products, plain) == (3 * 3, 2 * 3)
+
+
 # -- the expert layer and the chip's share ----------------------------------
 
 def _expert_layer(seq, key=0):
@@ -290,6 +362,58 @@ def test_every_token_to_one_held_expert_drops_nothing(
                  argnums=(0, 1))(layer, h)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4 * max(float(jnp.max(jnp.abs(b))), 1.0)
+
+
+def _ragged_dot_that_leaves_garbage(real):
+    """`lax.ragged_dot` as a grouped kernel on the chip may behave: the rows
+    of no group hold whatever the memory held, here NaN, in the product and
+    in the product of its reverse rule (the CPU's writes zeros there)."""
+    def outside(sizes, n):
+        return (jnp.arange(n) >= jnp.sum(sizes))[:, None]
+
+    @jax.custom_vjp
+    def dot(lhs, rhs, sizes):
+        out = real(lhs, rhs, sizes, preferred_element_type=jnp.float32)
+        return jnp.where(outside(sizes, lhs.shape[0]), jnp.nan, out)
+
+    def forward(lhs, rhs, sizes):
+        return dot(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def backward(kept, g):
+        lhs, rhs, sizes = kept
+        _, pull = jax.vjp(lambda a, b: real(
+            a, b, sizes, preferred_element_type=jnp.float32), lhs, rhs)
+        d_lhs, d_rhs = pull(g)
+        return jnp.where(outside(sizes, lhs.shape[0]), jnp.nan, d_lhs), d_rhs, None
+
+    dot.defvjp(forward, backward)
+    return lambda lhs, rhs, sizes, **_: dot(lhs, rhs, sizes)
+
+
+@pytest.mark.parametrize("moe_rows", [16, 4096])
+def test_rows_of_no_group_reach_no_gradient(moe_rows, monkeypatch):
+    """A trip's rows past the last landed assignment belong to no group.
+    Whatever a grouped matmul leaves there, NaN included, the layer's output
+    and every gradient are what they are with zeros there."""
+    monkeypatch.setattr(sp, "MOE_ROWS", moe_rows)
+    monkeypatch.setattr(sp, "MOE_DENSE_TOKENS", 0)
+    seq = config_mod.PRESETS[TINY].config.seq
+    layer = _expert_layer(seq)
+    h = jax.random.normal(jax.random.key(8), (40, seq.hidden_size))
+
+    def grads():
+        return jax.value_and_grad(
+            lambda p, h: jnp.sum(sp.moe(p, h, seq)[0] ** 2), argnums=(0, 1))(layer, h)
+
+    want_value, want = grads()
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        _ragged_dot_that_leaves_garbage(jax.lax.ragged_dot))
+    value, got = grads()
+    assert float(sp.moe(layer, h, seq)[1]["routed_here_frac"]) < 1.0  # such rows exist
+    assert float(value) == float(want_value)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        assert bool(jnp.array_equal(g, w)), jax.tree_util.keystr(path)
 
 
 def test_the_bias_changes_which_experts_are_chosen_and_not_their_weights():
