@@ -10,7 +10,6 @@ don't each carry a diverging copy.
 
 from __future__ import annotations
 
-import time
 import warnings
 from functools import partial
 from typing import Callable, Optional
@@ -774,7 +773,6 @@ def off_policy_train_host_async(
     max_staleness: Optional[int] = None,
     data_plane: str = "host",
     plane_codec: str = "fp32",
-    transfer_pad_s: float = 0.0,
     make_device_ingest_update: Optional[Callable] = None,
     publish_hook: Optional[Callable[[int, object], None]] = None,
 ):
@@ -807,8 +805,6 @@ def off_policy_train_host_async(
     per-algo factory ddpg/sac pass — builds the jitted program that
     gathers + decodes the slot, scatters it into the replay ring, and
     updates, with zero host→device transfers per consumed block.
-    `transfer_pad_s` is the transfer-wall testbed pad (ppo.train_host_async
-    docstring).
 
     Returns (learner, history).
     """
@@ -880,7 +876,6 @@ def off_policy_train_host_async(
             codec=plane_codec,
             max_staleness=max_staleness,
             policy="drop_oldest",
-            transfer_pad_s=transfer_pad_s,
         )
         ingest_update = make_device_ingest_update(
             spec.action_dim, cfg, queue.codecs
@@ -963,8 +958,6 @@ def off_policy_train_host_async(
                     queue.release(block)
                 else:
                     with telemetry.span("host_to_device"):
-                        if transfer_pad_s > 0:
-                            time.sleep(transfer_pad_s)  # testbed pad
                         # jnp.array, NOT asarray: the transfer must
                         # snapshot the slot before release (the PR 6
                         # contract).

@@ -821,7 +821,6 @@ def train_host_async(
     resume: bool = False,
     data_plane: str = "host",
     plane_codec: str = "fp32",
-    transfer_pad_s: float = 0.0,
     publish_hook: Optional[Callable[[int, Any], None]] = None,
 ):
     """Async actor–learner PPO on host env pools (ISSUE 6 tentpole).
@@ -858,14 +857,10 @@ def train_host_async(
     learner's jitted program gathers + decodes the slot in-jit — zero
     host→device transfers per consumed block. The fp32 codec at depth 1
     with `correction="none"` stays bitwise-equal to the host plane.
-    `transfer_pad_s` is the transfer-wall testbed knob (bench A/B): it
-    pads every block transfer — the learner-side `jnp.array` on the
-    host plane, the actor-side enqueue put on the device plane.
 
     Returns (params, opt_state, history).
     """
     import threading
-    import time as _time
 
     import numpy as np
 
@@ -917,7 +912,6 @@ def train_host_async(
             codec=plane_codec,
             max_staleness=None if strict_lockstep else max_staleness,
             policy="block" if strict_lockstep else "drop_oldest",
-            transfer_pad_s=transfer_pad_s,
         )
         update = make_device_update_step(
             spec, cfg, queue.codecs, can_truncate=True,
@@ -1085,8 +1079,6 @@ def train_host_async(
                     queue.release(block)
                 else:
                     with telemetry.span("host_to_device"):
-                        if transfer_pad_s > 0:
-                            _time.sleep(transfer_pad_s)  # testbed pad
                         # jnp.array, NOT asarray: the CPU backend may
                         # alias numpy buffers zero-copy, and releasing
                         # the slot below lets the next put() rewrite

@@ -488,7 +488,6 @@ def train_host_async(
     max_staleness: Optional[int] = None,
     data_plane: str = "host",
     plane_codec: str = "fp32",
-    transfer_pad_s: float = 0.0,
     publish_hook: Optional[Callable[[int, object], None]] = None,
 ):
     """SAC with decoupled actor services (ISSUE 9 satellite; mirrors
@@ -512,7 +511,6 @@ def train_host_async(
         eval_every=eval_every, eval_envs=eval_envs, eval_steps=eval_steps,
         queue_depth=queue_depth, max_staleness=max_staleness,
         data_plane=data_plane, plane_codec=plane_codec,
-        transfer_pad_s=transfer_pad_s,
         make_device_ingest_update=make_device_ingest_update,
         publish_hook=publish_hook,
     )

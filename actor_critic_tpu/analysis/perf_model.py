@@ -205,8 +205,7 @@ def program_bindings(
     # Named jit wraps resolve scope-aware: a site bound INSIDE this
     # scope wins over a module-level one of the same name, and a site
     # local to a DIFFERENT function never leaks in (two functions may
-    # each bind `run = jax.jit(...)` with different donation configs —
-    # bench/suite.py does).
+    # each bind `run = jax.jit(...)` with different donation configs).
     top_defs = [
         n for n in mod.tree.body
         if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))

@@ -218,13 +218,7 @@ class DeviceTrajRing:
     learner drives its jitted gather+update through `run()`.
 
     `codec` is a `codecs.traj_codecs` mode string ("fp32"/"f16"/"int8")
-    or an explicit per-key kind dict. `transfer_pad_s` is a testbed
-    knob (the `serving.PolicyEngine(dispatch_pad_s=...)` discipline):
-    pads every host→device block transfer with a wall sleep standing in
-    for a slow host↔device link, so the data-plane A/B bench can show on
-    CPU where that transfer wall lands — in the device plane
-    that wall lands on ACTOR threads at collection time, never on the
-    learner.
+    or an explicit per-key kind dict.
     """
 
     def __init__(
@@ -236,7 +230,6 @@ class DeviceTrajRing:
         policy: str = "drop_oldest",
         gauge_name: str = "device_ring",
         register_gauge: bool = True,
-        transfer_pad_s: float = 0.0,
     ):
         if depth < 1:
             raise ValueError(f"depth must be >= 1, got {depth}")
@@ -247,7 +240,6 @@ class DeviceTrajRing:
         self.depth = int(depth)
         self.max_staleness = max_staleness
         self.policy = policy
-        self.transfer_pad_s = float(transfer_pad_s)
         self._spec = dict(block_spec)
         self.codecs = (
             np_codecs.traj_codecs(codec, block_spec)
@@ -387,8 +379,6 @@ class DeviceTrajRing:
             )
             for name in self._spec
         }
-        if self.transfer_pad_s > 0:
-            time.sleep(self.transfer_pad_s)  # transfer-wall testbed pad
         encoded_dev = jax.device_put(encoded)
         nbytes = sum(v.nbytes for v in encoded.values())
         deadline = None if timeout is None else time.monotonic() + timeout

@@ -118,18 +118,12 @@ class HostEnvPool:
         scale_actions: bool = False,
         env_kwargs: dict | None = None,
         workers: int = 1,
-        worker_env_kwargs: list[dict | None] | None = None,
     ):
         self.env_id = env_id
         self.num_envs = num_envs
         env_kwargs = dict(env_kwargs or {})
         if pixel_preprocess and backend != "gym":
             raise ValueError("pixel_preprocess applies to the gym backend only")
-        if worker_env_kwargs is not None and workers <= 1:
-            raise ValueError(
-                "worker_env_kwargs needs the sharded gym backend "
-                "(workers > 1); with one process pass env_kwargs"
-            )
         if env_kwargs and backend != "gym":
             raise ValueError(
                 "env_kwargs go to gym.make; the native engine takes none"
@@ -160,7 +154,6 @@ class HostEnvPool:
                     env_id, num_envs, workers=self._workers,
                     env_kwargs=env_kwargs,
                     pixel_preprocess=pixel_preprocess,
-                    worker_env_kwargs=worker_env_kwargs,
                 )
             else:
                 from gymnasium.vector import AutoresetMode, SyncVectorEnv
@@ -249,10 +242,7 @@ class HostEnvPool:
         """A companion pool for greedy evaluation: same env/backend and the
         SAME obs-normalization statistics (shared by reference, read-only —
         eval must see the training policy's input distribution), raw
-        rewards (no reward normalization), fresh episodes. Per-worker
-        constructor overrides (`worker_env_kwargs`) do NOT carry over:
-        eval pools are uniform — a sleep-padded straggler shard is a
-        collection testbed, not an eval condition."""
+        rewards (no reward normalization), fresh episodes."""
         pool = HostEnvPool(
             self.env_id, num_envs, seed=seed,
             normalize_obs=self._normalize_obs, normalize_reward=False,

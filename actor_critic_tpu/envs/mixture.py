@@ -29,9 +29,8 @@ heterogeneous batching) applied to training:
   re-drawing `step`) and passes the other slots through untouched.
   Under `vmap` the switch lowers to a select over all branches — each
   instance pays the summed member step cost, the known price of SIMD
-  heterogeneity (measured by `bench/suite.py scenario_fleet`'s
-  mixture_overhead_x row); the win is that the WHOLE fleet stays inside
-  one compiled program with zero host round-trips.
+  heterogeneity; the win is that the WHOLE fleet stays inside one
+  compiled program with zero host round-trips.
 - **Type-preserving auto-reset**: an episode end re-rolls the member's
   scenario params from the instance's own PRNG stream (the member's
   `auto_reset` does this already) while the type id is preserved. With

@@ -205,7 +205,6 @@ class ShardedVecEnv:
         env_kwargs: Optional[dict] = None,
         pixel_preprocess: bool = False,
         step_timeout_s: float = 300.0,
-        worker_env_kwargs: Optional[list[Optional[dict]]] = None,
     ):
         if workers < 1:
             raise ValueError(f"workers must be >= 1, got {workers}")
@@ -217,22 +216,6 @@ class ShardedVecEnv:
         self.num_envs = E = int(num_envs)
         self.num_workers = W = int(workers)
         env_kwargs = dict(env_kwargs or {})
-        # Per-worker constructor overrides, merged over env_kwargs —
-        # heterogeneous shards for straggler testbeds (one sleep-padded
-        # worker among fast ones; bench async_decoupling, ISSUE 6) and
-        # future per-shard scenario randomization. Overrides must not
-        # change observation/action SPACES: the parent probes one env
-        # with the BASE kwargs and sizes every shm block from it.
-        if worker_env_kwargs is not None and len(worker_env_kwargs) != W:
-            raise ValueError(
-                f"worker_env_kwargs has {len(worker_env_kwargs)} entries "
-                f"for workers={W}; need exactly one (or None) per worker"
-            )
-        self._worker_env_kwargs = [
-            {**env_kwargs, **(worker_env_kwargs[w] or {})}
-            if worker_env_kwargs is not None else env_kwargs
-            for w in range(W)
-        ]
         self._step_timeout_s = float(step_timeout_s)
 
         # Probe one env in-process for the spaces (wrappers included).
@@ -273,7 +256,7 @@ class ShardedVecEnv:
                 proc = ctx.Process(
                     target=_worker_main,
                     args=(
-                        child_conn, w, env_id, self._worker_env_kwargs[w],
+                        child_conn, w, env_id, env_kwargs,
                         pixel_preprocess, lo, hi, raw, specs,
                     ),
                     daemon=True,

@@ -1,13 +1,12 @@
-"""Sleep-padded gym testbed env for host-pool scaling benchmarks/tests.
+"""Sleep-padded gym testbed env for the host-pool and actor tests.
 
 The sharded pool's win is overlapping per-env simulator WALL time, but
 CI has no MuJoCo-scale simulator and the container may be single-core —
 a CPU-bound env would show no multi-process speedup there. `SleepPadEnv`
 pads every step with a `time.sleep(sleep_s)` (wall-bound, zero CPU), so
-`bench/suite.py host_pool_scaling` measures real worker overlap on any
-host. Dynamics are a deterministic drift on a 4-dim state, seeded
-through gymnasium's `np_random`, so it also serves the sharded-vs-sync
-trajectory-equivalence tests.
+worker overlap is real on any host. Dynamics are a deterministic drift
+on a 4-dim state, seeded through gymnasium's `np_random`, so it also
+serves the sharded-vs-sync trajectory-equivalence tests.
 
 `crash_at_step > 0` raises inside `step()` once that many steps have run
 in the env instance — the injection point for the worker-crash-surfaces-
@@ -83,12 +82,12 @@ QUALIFIED_CARTPOLE_ID = f"{__name__}:{CARTPOLE_ENV_ID}"
 class SleepPadCartPoleEnv(gym.Env):
     """CartPole-v1 with a per-step wall-time pad: REAL dynamics (so a
     learner can be judged on eval return) under a simulator-shaped wall
-    cost. The async-decoupling bench (`bench/suite.py
-    async_decoupling`, ISSUE 6) pads one worker/actor to make a
-    straggler while the rest run unpadded — lockstep collection slows
-    to the straggler's pace at its sync barrier; the async queue does
-    not. A plain delegating Env (not gym.Wrapper): registered entry
-    points need a class-level `metadata` dict."""
+    cost. `scripts/launch_multihost.py --straggler-rank` pads one
+    process's envs further to make a straggler while the rest run at
+    the base pad — a sync fleet slows to the straggler's pace at its
+    barrier; a gossip fleet does not. A plain delegating Env (not
+    gym.Wrapper): registered entry points need a class-level `metadata`
+    dict."""
 
     metadata: dict = {"render_modes": []}
 

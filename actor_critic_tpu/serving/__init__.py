@@ -1,8 +1,7 @@
 """Policy-serving gateway (ISSUE 10): the acting path as a production
 inference service — GA3C-style micro-batching (arxiv 1611.06256) over
 stdlib HTTP, AOT-warm bucket programs, multi-policy hot-swap, serving
-metrics on /metrics. `scripts/serve.py` is the CLI; `bench/suite.py
-serving_latency` is the SLO bench.
+metrics on /metrics. `scripts/serve.py` is the CLI.
 
 Importing this package registers the serving warmup planner
 (`engine.make_act_program`) — `analysis/warmup.py`'s registry lint

@@ -149,7 +149,6 @@ class PolicyEngine:
         buckets: tuple[int, ...] = DEFAULT_BUCKETS,
         sample: bool = False,
         seed: int = 0,
-        dispatch_pad_s: float = 0.0,
         backend: str = "xla",
     ):
         buckets = tuple(sorted({int(b) for b in buckets}))
@@ -190,15 +189,6 @@ class PolicyEngine:
             self._program = make_act_program(
                 spec, cfg, algo, sample=self.sample
             )
-        # Testbed knob (sleep_pad.py's discipline, pointed at serving):
-        # a fixed wall pad per DISPATCH stands in for the
-        # host<->accelerator round trip of a serving deployment behind
-        # a slow link, which a CPU-local jit dispatch (~0.3 ms) cannot
-        # exhibit. The
-        # pad is per-dispatch, not per-row: exactly the fixed cost
-        # GA3C-style micro-batching amortizes. Default 0 — real serving
-        # never pads; `bench/suite.py serving_latency` sets it.
-        self.dispatch_pad_s = float(dispatch_pad_s)
         self._seed = int(seed)
         self._base_key = None  # lazy: jax.random.key allocates on-device
         # jaxlint: thread-owned=dispatcher (itertools.count — next() is
@@ -257,8 +247,7 @@ class PolicyEngine:
         to XLA without measuring. The bucket-1 compile happens OUTSIDE
         the timed region, so the choice compares steady-state
         dispatch, not compilation. Idempotent no-op on an already
-        concrete backend; the testbed `dispatch_pad_s` is excluded
-        (it pads both paths identically in act())."""
+        concrete backend."""
         if self.backend != "auto":
             return self.backend
         import time as _time
@@ -348,10 +337,6 @@ class PolicyEngine:
             else:
                 out = self._program(params, staged)
             out = jax.device_get(out)
-        if self.dispatch_pad_s > 0.0:
-            import time
-
-            time.sleep(self.dispatch_pad_s)  # modeled device round trip
         return np.asarray(out)[:n]
 
     def warm(self, params) -> int:

@@ -35,11 +35,7 @@ import json
 import threading
 import time
 import uuid
-from http.server import (
-    BaseHTTPRequestHandler,
-    HTTPServer,
-    ThreadingHTTPServer,
-)
+from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 from urllib.parse import urlparse
 
@@ -230,35 +226,12 @@ class _ThreadedServer(ThreadingHTTPServer):
     daemon_threads = True
 
 
-class _SequentialServer(HTTPServer):
-    # Without keep-alive every request is a fresh connect, so the
-    # backlog sees the WHOLE client fleet every cycle; the stall above
-    # would otherwise dominate the baseline's measured latency.
-    request_queue_size = 128
-
-
-class _SequentialHandler(_Handler):
-    """Handler for the single-threaded baseline server (`ServeGateway
-    (threaded=False)` — the pre-GA3C architecture the SLO bench
-    compares against): HTTP/1.0, no keep-alive, because with ONE server
-    thread a kept-alive connection would starve every other client.
-    Each request pays connect + parse + dispatch + respond end-to-end,
-    sequentially — exactly 'sequential batch=1 request handling'."""
-
-    protocol_version = "HTTP/1.0"
-
-
 class ServeGateway:
     """Owns the HTTP server thread, the micro-batcher, and the serving
     gauge registration for one serving process. `port=0` binds an
     OS-assigned ephemeral port; the ACTUAL port is on `self.port` (and
     in `self.url`) so callers — the load generator, CI — never race for
-    a fixed one.
-
-    `threaded=False` swaps the concurrent server + micro-batcher for a
-    single-threaded HTTP/1.0 server with a batch=1, zero-wait batcher:
-    the sequential baseline the `serving_latency` bench measures the
-    micro-batched gateway against."""
+    a fixed one."""
 
     def __init__(
         self,
@@ -272,7 +245,6 @@ class ServeGateway:
         request_timeout_s: float = 30.0,
         stall_after_s: float = 5.0,
         batcher: Optional[MicroBatcher] = None,
-        threaded: bool = True,
         fleet=None,
         aggregator=None,
         max_inflight: int = 1,
@@ -291,18 +263,9 @@ class ServeGateway:
         # 503 when a peer's last gossip exchange is older than the
         # monitor's bound — the ROADMAP elastic-ops observability half.
         self.fleet = fleet
-        self.threaded = bool(threaded)
         self.request_timeout_s = float(request_timeout_s)
         self.stall_after_s = float(stall_after_s)
         owns_batcher = batcher is None
-        if not threaded and batcher is None:
-            # Sequential baseline: one request per flush, no batching
-            # window (waiting could only add latency — there is never a
-            # second in-flight request to batch with).
-            batcher = MicroBatcher(
-                store, max_wait_us=0.0, max_batch_rows=1,
-                queue_limit=queue_limit,
-            )
         self.batcher = batcher or MicroBatcher(
             store,
             max_wait_us=max_wait_us,
@@ -321,12 +284,7 @@ class ServeGateway:
             "serving", self.batcher.gauge
         )
         try:
-            if threaded:
-                self._server = _ThreadedServer((host, int(port)), _Handler)
-            else:
-                self._server = _SequentialServer(
-                    (host, int(port)), _SequentialHandler
-                )
+            self._server = _ThreadedServer((host, int(port)), _Handler)
         except Exception:
             # Bind failure (e.g. EADDRINUSE): close() is unreachable
             # when __init__ raises, so the gauge registration and the

@@ -2,7 +2,7 @@
 """jaxlint: repo-wide JAX correctness analyzer (ISSUE 5).
 
     python scripts/jaxlint.py                         # default scan set
-    python scripts/jaxlint.py actor_critic_tpu train.py bench
+    python scripts/jaxlint.py actor_critic_tpu train.py
     python scripts/jaxlint.py --list-checks
     python scripts/jaxlint.py --select lock-discipline,check-then-act
     python scripts/jaxlint.py --diff HEAD             # changed files only
@@ -38,7 +38,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-DEFAULT_PATHS = ("actor_critic_tpu", "train.py", "bench")
+DEFAULT_PATHS = ("actor_critic_tpu", "train.py")
 
 
 def main(argv=None) -> int:

@@ -12,19 +12,17 @@ ring) mode, and aggregates fleet throughput. One JSON line on stdout.
     python scripts/launch_multihost.py --processes 2 --straggler-rank 0 \
         --straggler-extra-s 0.006                # inject a slow host
     python scripts/launch_multihost.py --smoke   # tier-1 2-process check
-    python scripts/launch_multihost.py --bench   # the multihost_scaling
-                                                 # grid (results/ record)
 
 Envs are the sleep-padded CartPole testbed (`envs/sleep_pad.py`): real
-dynamics under a simulator-shaped wall cost, so fleet scaling is
-measurable on any host (the same rationale as `host_pool_scaling`).
+dynamics under a simulator-shaped wall cost, so process-level overlap
+shows on any host.
 `--straggler-rank R` pads rank R's envs further: sync mode stalls the
 fleet at the all-reduce barrier; gossip mode degrades only R's own
 contribution — the straggler-does-not-stall acceptance row.
 
 On a real pod, run one `train.py --distributed --coordinator ...`
-process per host instead; this launcher exists so tier-1 and the bench
-cover the stack with no TPU present.
+process per host instead; this launcher exists so tier-1 covers the
+stack with no TPU present.
 
 Exit codes: 0 ok; 1 a worker failed or a consistency check tripped.
 """
@@ -208,7 +206,6 @@ def run_cluster(
     eval_steps: int = 0,
     telemetry_dir: str = "",
     timeout_s: float = 600.0,
-    extra_args: tuple = (),
 ) -> dict:
     """One N-process local-cluster run; returns the aggregated fleet
     record (raises on worker failure)."""
@@ -230,7 +227,6 @@ def run_cluster(
             "--gossip-weight", str(gossip_weight),
             "--seed", str(seed), "--eval-steps", str(eval_steps),
             "--telemetry-dir", telemetry_dir,
-            *extra_args,
         ]
         t0 = time.perf_counter()
         procs = [
@@ -370,7 +366,7 @@ def merge_host_traces(telemetry_dir: str, processes: int) -> str:
 
 
 # ---------------------------------------------------------------------------
-# smoke + bench drivers
+# smoke driver
 # ---------------------------------------------------------------------------
 
 
@@ -386,90 +382,6 @@ def run_smoke(args) -> int:
     ok = rec["version_consistent"] and rec["fingerprint_consistent"]
     print(json.dumps({"smoke": "multihost_sync_2proc", "ok": ok, **rec}))
     return 0 if ok else 1
-
-
-def run_bench(args) -> dict:
-    """The `multihost_scaling` grid (ROADMAP multi-host item): sync
-    aggregate consumed env-steps/s at 1/2/4 processes, the gossip
-    variant at 4, and the straggler A/B (sync stalls at the barrier,
-    gossip degrades) at 2 processes. Every run is WALL-bounded
-    (`--duration-s`): fleets consume whatever blocks fit in the same
-    window, so a straggler's cost is measured as missing consumption
-    rather than stretched wall. Headline value = sync aggregate
-    speedup at 4 processes over 1 (target >= 1.5x). Env steps are
-    sleep-padded (wall-bound, CPU-idle), so process-level overlap is
-    measurable even on a 1-2 core CI host — the same testbed rationale
-    as `host_pool_scaling`."""
-    duration = args.duration_s if args.duration_s > 0 else 12.0
-    # Bench pad default (8 ms) is larger than the generic-run default:
-    # at 4 sync processes on a small CI host the gloo collectives spin
-    # against oversubscribed cores, and the pad must keep collection —
-    # the thing being scaled — the pipeline's bottleneck stage.
-    sleep_s = args.sleep_s if args.sleep_s is not None else 0.008
-    base = dict(
-        duration_s=duration, iterations=0,
-        rollout_steps=16, num_envs=4, actors=1,
-        sleep_s=sleep_s, seed=args.seed,
-        timeout_s=args.run_timeout,
-        # One minibatch per update: the collective count per consumed
-        # block stays O(param leaves), not O(epochs × minibatches).
-        extra_args=("--epochs", "1", "--minibatches", "1"),
-    )
-    sync = {}
-    for p in (1, 2, 4):
-        sync[str(p)] = run_cluster(p, "sync", **base)
-    gossip = {"4": run_cluster(4, "gossip", **base)}
-    straggle = dict(base, straggler_rank=0, straggler_extra_s=sleep_s * 3)
-    straggler = {
-        "sync": run_cluster(2, "sync", **straggle),
-        "gossip": run_cluster(2, "gossip", **straggle),
-    }
-    # Fault injection (ISSUE 12 satellite): SIGKILL a REAL gossip
-    # worker mid-run, restart it, and measure wall time-to-recover —
-    # fleetsan's process injector reused as the bench driver. Malformed
-    # or failed runs degrade to an error entry (bench_trend renders
-    # `?`), never take the whole grid down.
-    from actor_critic_tpu.analysis import fleetsan
-
-    try:
-        fault = fleetsan.run_process_chaos(
-            world=2, duration_s=max(duration * 2, 12.0),
-            kill_after_s=max(duration / 3, 3.0),
-            timeout_s=args.run_timeout, seed=args.seed,
-        )
-    except Exception as e:
-        fault = {"error": f"{type(e).__name__}: {e}"}
-    agg = lambda r: r["aggregate_steps_per_s"]  # noqa: E731
-    record = {
-        "metric": "multihost_scaling",
-        "value": round(agg(sync["4"]) / agg(sync["1"]), 2),
-        "fault_injection": fault,
-        "unit": "x aggregate consumed env-steps/s, 4 processes vs 1 "
-                "(sync all-reduce, sleep-padded CartPole, CPU local "
-                "cluster)",
-        "sync": sync,
-        "gossip": gossip,
-        "straggler": {
-            **straggler,
-            "gossip_over_sync": round(
-                agg(straggler["gossip"]) / agg(straggler["sync"]), 2
-            ),
-        },
-        "gossip_over_sync_4proc": round(
-            agg(gossip["4"]) / agg(sync["4"]), 2
-        ),
-        "version_consistent": all(
-            sync[p]["version_consistent"] for p in sync
-        ),
-        "config": {
-            "duration_s": duration,
-            "rollout_steps": base["rollout_steps"],
-            "num_envs_per_process": base["num_envs"],
-            "sleep_s": sleep_s,
-            "straggler_extra_s": straggle["straggler_extra_s"],
-        },
-    }
-    return record
 
 
 def main(argv=None) -> int:
@@ -492,8 +404,8 @@ def main(argv=None) -> int:
     p.add_argument(
         "--duration-s", type=float, default=0.0,
         help="wall-bounded run: consume as many blocks as fit in this "
-        "window instead of a fixed count (the bench's measurement mode "
-        "— a straggler shows up as blocks NOT consumed). Sync fleets "
+        "window instead of a fixed count (a straggler shows up as "
+        "blocks NOT consumed). Sync fleets "
         "all-reduce the stop vote so every host exits together.",
     )
     p.add_argument("--rollout-steps", type=int, default=16)
@@ -505,10 +417,9 @@ def main(argv=None) -> int:
     p.add_argument("--minibatches", type=int, default=2)
     p.add_argument("--lr", type=float, default=3e-3)
     p.add_argument(
-        "--sleep-s", type=float, default=None,
+        "--sleep-s", type=float, default=0.002,
         help="per-env-step wall pad (simulator-shaped cost; see "
-        "envs/sleep_pad.py). Default 0.002 for generic runs, 0.008 "
-        "under --bench",
+        "envs/sleep_pad.py)",
     )
     p.add_argument(
         "--straggler-rank", type=int, default=-1,
@@ -534,34 +445,21 @@ def main(argv=None) -> int:
                    help="per-cluster-run kill budget (seconds)")
     p.add_argument("--smoke", action="store_true",
                    help="tier-1 2-process sync smoke (exit 1 on failure)")
-    p.add_argument("--bench", action="store_true",
-                   help="run the multihost_scaling grid; one JSON record")
-    p.add_argument("--out", default="",
-                   help="with --bench: also write the record to this path")
     args = p.parse_args(argv)
 
     if args.worker:
         if args.max_staleness < 0:
             args.max_staleness = None
-        if args.sleep_s is None:
-            args.sleep_s = 0.002
         return run_worker(args)
     if args.smoke:
         return run_smoke(args)
-    if args.bench:
-        record = run_bench(args)
-        print(json.dumps(record))
-        if args.out:
-            with open(args.out, "w") as f:
-                json.dump(record, f, indent=1)
-        return 0
     rec = run_cluster(
         args.processes, args.mode,
         iterations=args.iterations or 30,
         duration_s=args.duration_s,
         rollout_steps=args.rollout_steps, num_envs=args.num_envs,
         actors=args.actors,
-        sleep_s=args.sleep_s if args.sleep_s is not None else 0.002,
+        sleep_s=args.sleep_s,
         straggler_rank=args.straggler_rank,
         straggler_extra_s=(
             args.straggler_extra_s if args.straggler_rank >= 0 else 0.0

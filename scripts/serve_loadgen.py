@@ -22,8 +22,7 @@ silently slowed generator: `late` counts arrivals that fired behind
 schedule (every connection busy past its slot — the open-loop
 saturation signal), and 503s are split into `shed` (the gateway's
 admission-control answer, body `shed: true`) vs plain `rejected_503`
-(queue-full). `run_load` is the library entry `bench/suite.py`
-drives."""
+(queue-full). `run_load` is the library entry."""
 
 from __future__ import annotations
 

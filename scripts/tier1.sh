@@ -31,7 +31,7 @@ t0=$(date +%s)
 # Static analysis first (ISSUE 5): an un-baselined jaxlint finding fails
 # tier-1 before any test runs (exit 1 = findings, 2 = analyzer crash —
 # distinct so CI logs tell them apart).
-env JAX_PLATFORMS=cpu python scripts/jaxlint.py actor_critic_tpu train.py bench --error-on-new || exit $?
+env JAX_PLATFORMS=cpu python scripts/jaxlint.py actor_critic_tpu train.py --error-on-new || exit $?
 echo "tier1: jaxlint wall $(( $(date +%s) - t0 ))s"; cum
 t0=$(date +%s)
 # Race sanitizer quick profile (ISSUE 7): 100 fixed-seed cooperative

@@ -9,7 +9,7 @@ Three layers of guarantees:
 2. **Mechanics** — inline suppression comments (same line and
    standalone line), baseline round-trip (save → load → zero new,
    stale detection when the flagged line changes).
-3. **The gate** — the real tree (`actor_critic_tpu train.py bench`)
+3. **The gate** — the real tree (`actor_critic_tpu train.py`)
    analyzes clean against the repo baseline, and the CLI's exit codes
    stay distinct: 0 clean / 1 findings / 2 crash-or-parse-error.
 
@@ -535,11 +535,11 @@ def test_cli_json_mode(capsys):
 
 
 def test_repo_tree_is_clean(capsys):
-    """`python scripts/jaxlint.py actor_critic_tpu train.py bench` must
+    """`python scripts/jaxlint.py actor_critic_tpu train.py` must
     exit 0: zero un-baselined findings (the ISSUE 5 acceptance
     criterion, enforced in-process so tier-1 fails with the report)."""
     cli = _load_cli()
-    rc = cli.main(["actor_critic_tpu", "train.py", "bench", "--error-on-new"])
+    rc = cli.main(["actor_critic_tpu", "train.py", "--error-on-new"])
     out = capsys.readouterr()
     assert rc == 0, f"jaxlint found new findings:\n{out.out}\n{out.err}"
 
@@ -1287,10 +1287,10 @@ def test_python_reduction_trips_dispatch_granularity(tmp_path):
         "        state = step(state, b)\n",
     )
     assert _run_snippet(tmp_path, fixed) == []
-    # the real fused drivers (host_loop/mixture benches) stay clean
+    # the real fused driver stays clean
     assert (
         analysis.analyze_paths(
-            ["actor_critic_tpu/algos/host_loop.py", "bench"],
+            ["actor_critic_tpu/algos/host_loop.py"],
             str(REPO),
             checks=["dispatch-granularity"],
         )

@@ -1,6 +1,6 @@
 """Pallas scan kernels vs. the lax.scan golden implementations
 (interpret mode on the CPU test backend; compiled path exercised on TPU
-by bench/ and the fused trainers)."""
+by the fused trainers)."""
 
 import jax.numpy as jnp
 import numpy as np

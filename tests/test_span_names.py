@@ -22,7 +22,7 @@ REPO = Path(__file__).parent.parent
 
 # Source that emits phase spans; tests are excluded on purpose — they
 # exercise the tracer with synthetic names.
-SCAN = ["actor_critic_tpu", "scripts", "train.py", "bench.py", "bench"]
+SCAN = ["actor_critic_tpu", "scripts", "train.py"]
 
 _CALL = re.compile(
     r"""(?:telemetry|_session)\s*\.\s*

@@ -820,8 +820,7 @@ def main(argv=None) -> int:
         help="host pools: worker processes the env batch shards across "
         "(envs/shard_pool.py; shared-memory step exchange, per-shard "
         "seeding identical to the in-process pool). 1 = in-process "
-        "SyncVectorEnv, today's exact semantics; scaling measured by "
-        "`bench/suite.py host_pool_scaling`",
+        "SyncVectorEnv, today's exact semantics",
     )
     p.add_argument(
         "--async-actors", type=int, default=0, metavar="A",
