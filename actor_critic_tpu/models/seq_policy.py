@@ -59,7 +59,9 @@ The cache is a carry of the rollout scan and starts fresh with it, so an
 episode must be exactly one unroll and every row at the same position: the
 token env guarantees both (`envs/token_task.py`) and `make_policy` refuses an
 env whose `episode_horizon` is not the unroll length. The cache slot of a step
-is row 0's position.
+is row 0's position. It is one stacked pair for the whole model, which stays
+in HBM through the scan; a decode step writes one slot a layer in place and
+reads each layer's filled prefix once (`ops/mla_decode.py`).
 """
 
 from __future__ import annotations
@@ -71,6 +73,7 @@ import jax
 import jax.numpy as jnp
 
 from actor_critic_tpu.models.distributions import Categorical
+from actor_critic_tpu.ops.mla_decode import mla_decode_auto
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,29 +283,27 @@ def mla_unroll(p, h, positions, cfg: SeqPolicyConfig):
     return _map_rows(attend, ATTN_ROWS, c_q, c_kv, k_r, positions)
 
 
-def mla_step(p, h, positions, cache, slot, cfg: SeqPolicyConfig):
-    """One token a row through a layer's latent cache `(c_kv [E, T, rank],
-    k_r [E, T, rope])`: the token's latent goes into `slot`, the queries are
-    absorbed into the latent space (`q_nope W_kvb^k`), and attention reads
-    the cached latents only. Returns (out [E, H], cache)."""
+def mla_step(p, h, positions, cache, layer: int, slot, cfg: SeqPolicyConfig):
+    """One token a row through layer `layer` of the latent cache `(c_kv
+    [layers, E, T, rank], k_r [layers, E, T, rope])`: the token's latent goes
+    into `slot`, the queries are absorbed into the latent space (`q_nope
+    W_kvb^k`), and attention reads the cached latents only, as far as they
+    are filled (`ops/mla_decode.py`: one kernel on a TPU where the cache
+    tiles, two einsums elsewhere). Returns (out [E, H], cache)."""
     cd = jnp.dtype(cfg.compute_dtype)
     nh, dn, dv = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
     rank = cfg.kv_lora_rank
     c_q, c_kv, k_r = _mla_latents(p, h, positions, cfg, cd)
     q_nope, q_rope = _mla_queries(p, c_q, positions, cfg, cd)
     put = lambda buf, new: jax.lax.dynamic_update_slice(  # noqa: E731
-        buf, new.astype(buf.dtype)[:, None, :], (0, slot, 0))
+        buf, new.astype(buf.dtype)[None, :, None, :], (layer, 0, slot, 0))
     c_all, r_all = put(cache[0], c_kv), put(cache[1], k_r)
-    E, T, _ = c_all.shape
     w_kvb = p["w_kvb"].reshape(rank, nh, dn + dv)
     q_lat = _einsum("ehd,chd->ehc", q_nope, w_kvb[..., :dn], cd)
-    s = _einsum("ehc,etc->eht", q_lat, c_all, cd)
-    s = s + _einsum("ehr,etr->eht", q_rope, r_all, cd)
-    s = jnp.where(jnp.arange(T) <= slot, s * (dn + cfg.qk_rope_head_dim) ** -0.5,
-                  -jnp.inf)
-    o_lat = _einsum("eht,etc->ehc", jax.nn.softmax(s, axis=-1), c_all, cd)
+    o_lat = mla_decode_auto(q_lat, q_rope, c_all, r_all, layer, slot,
+                            (dn + cfg.qk_rope_head_dim) ** -0.5)
     o = _einsum("ehc,chv->ehv", o_lat, w_kvb[..., dn:], cd)
-    return _mm(o.reshape(E, nh * dv), p["w_o"], cd), (c_all, r_all)
+    return _mm(o.reshape(h.shape[0], nh * dv), p["w_o"], cd), (c_all, r_all)
 
 
 def route(p, h, cfg: SeqPolicyConfig):
@@ -462,15 +463,16 @@ def _layers(params):
 # -- the two passes -------------------------------------------------------
 
 def init_cache(cfg: SeqPolicyConfig, num_envs: int, horizon: int):
-    """The latent cache, `[layers, E, T, latent_dim]` kept as a pair a layer:
-    `(c_kv [E, T, kv_lora_rank], k_r [E, T, qk_rope_head_dim])` in
-    `compute_dtype` (its values are matmul operands only)."""
+    """The latent cache, one pair for the whole model: `(c_kv [layers, E, T,
+    kv_lora_rank], k_r [layers, E, T, qk_rope_head_dim])` in `compute_dtype`
+    (its values are matmul operands only). Stacked, a rollout's carry is too
+    large for the compiler to keep in VMEM between decode steps (a pair a
+    layer it kept there, and evicted to HBM and fetched back every step):
+    its home is HBM, where a step writes one slot in place."""
     cd = jnp.dtype(cfg.compute_dtype)
-    return tuple(
-        (jnp.zeros((num_envs, horizon, cfg.kv_lora_rank), cd),
-         jnp.zeros((num_envs, horizon, cfg.qk_rope_head_dim), cd))
-        for _ in range(cfg.num_hidden_layers)
-    )
+    lead = (cfg.num_hidden_layers, num_envs, horizon)
+    return (jnp.zeros((*lead, cfg.kv_lora_rank), cd),
+            jnp.zeros((*lead, cfg.qk_rope_head_dim), cd))
 
 
 def step(params, obs, cache, cfg: SeqPolicyConfig):
@@ -481,19 +483,17 @@ def step(params, obs, cache, cfg: SeqPolicyConfig):
     tokens, positions = obs[:, 0], obs[:, 1]
     slot = positions[0]
     x = jnp.take(p["embed"], tokens, axis=0)
-    new_cache = []
-    for layer, layer_cache in zip(layers, cache):
+    for i, layer in enumerate(layers):
         with jax.named_scope("mla"):
             h = _rms(x, layer["attn_norm"], cfg.rms_norm_eps)
-            a, layer_cache = mla_step(layer["mla"], h, positions, layer_cache, slot, cfg)
+            a, cache = mla_step(layer["mla"], h, positions, cache, i, slot, cfg)
             x = x + a
-        new_cache.append(layer_cache)
         y, _ = _ffn(layer, _rms(x, layer["ffn_norm"], cfg.rms_norm_eps), cfg)
         x = x + y
     h = _rms(x, p["final_norm"], cfg.rms_norm_eps)
     with jax.named_scope("lm_head"):
         logits = _mm(h, p["lm_head"], cd)
-    return logits, _value(p, h), tuple(new_cache)
+    return logits, _value(p, h), cache
 
 
 def _value(p, h):

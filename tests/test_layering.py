@@ -22,7 +22,8 @@ PACKAGE = REPO / "actor_critic_tpu"
 RUNGS = (
     ("utils",),                              # checkpoint, compile cache, guards
     ("telemetry",),                          # spans, sampler, profiler, exporter
-    ("native", "ops", "models", "replay"),   # kernels, networks, buffers
+    ("native", "ops"),                       # kernels
+    ("models", "replay"),                    # networks, buffers
     ("envs",),                               # on-device envs and host pools
     ("data_plane", "parallel"),              # device ring; dp, seqpar, multihost
     ("algos",),                              # the trainers and their drivers

@@ -2,7 +2,10 @@
 seam of the fused trainers, at toy widths on the CPU: step through the cache
 = one causal pass = the plain reference; the chip's share of the experts adds
 up; no routing drops a token; masked steps carry no gradient; the causal
-pass's checkpoints change no gradient and run attention forward twice."""
+pass's checkpoints change no gradient and run attention forward twice; the
+decode's attention kernel (`ops/mla_decode.py`) through the Pallas interpreter
+equals the einsums, reads nothing past the slot, engages by shape, and in a
+rollout compiled for a described v5e leaves the cache where it is."""
 
 import dataclasses
 import functools
@@ -26,6 +29,7 @@ from actor_critic_tpu import config as config_mod  # noqa: E402
 from actor_critic_tpu.algos import common, impala  # noqa: E402
 from actor_critic_tpu.envs import make_token_task  # noqa: E402
 from actor_critic_tpu.models import seq_policy as sp  # noqa: E402
+from actor_critic_tpu.ops import mla_decode, pallas_scan  # noqa: E402
 from benchmark import harness  # noqa: E402
 
 TINY = "impala_joyai_flash_tiny"
@@ -291,6 +295,144 @@ def test_the_update_computes_the_scores_twice_a_layer(compute_dtype):
     _, products = _unroll_gradient(compute_dtype, True)
     _, plain = _unroll_gradient(compute_dtype, False)
     assert (products, plain) == (3 * 3, 2 * 3)
+
+
+# -- the decode step's attention kernel ---------------------------------------
+
+BLOCK = mla_decode.BLOCK_POSITIONS
+KERNEL_T = 2 * BLOCK
+
+
+def _decode_inputs(E, dtype, rank=128, layers=2, heads=4, rope=16):
+    """Queries and a full stacked cache at the smallest shapes that tile."""
+    keys = jax.random.split(jax.random.key(0), 4)
+    normal = lambda k, *shape: jax.random.normal(k, shape)  # noqa: E731
+    return (normal(keys[0], E, heads, rank), normal(keys[1], E, heads, rope),
+            normal(keys[2], layers, E, KERNEL_T, rank).astype(dtype),
+            normal(keys[3], layers, E, KERNEL_T, rope).astype(dtype))
+
+
+@pytest.mark.parametrize("slot", [0, BLOCK - 1, BLOCK, KERNEL_T - 1])
+@pytest.mark.parametrize("dtype, tol", [("float32", 5e-6), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("E", [mla_decode.BLOCK_ROWS, 3 * mla_decode.BLOCK_ROWS])
+def test_the_decode_kernel_equals_the_einsums(E, dtype, tol, slot):
+    """The kernel through the Pallas interpreter against the einsum path, one
+    row block and several, at a slot in the first block, at its last position,
+    at the first of the next and at the row's end. At bfloat16 the two round
+    the probabilities at different scales (before and after the division)."""
+    q_lat, q_rope, c_kv, k_r = _decode_inputs(E, jnp.dtype(dtype))
+    run = jax.jit(lambda fn, s: fn(q_lat, q_rope, c_kv, k_r, 1, s, 0.125),
+                  static_argnums=0)
+    got = run(mla_decode.mla_decode, jnp.int32(slot))
+    want = run(mla_decode.reference, jnp.int32(slot))
+    assert got.shape == want.shape and got.dtype == want.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) < tol * float(jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("slot", [0, BLOCK - 1, BLOCK, KERNEL_T - 2])
+def test_the_decode_kernel_reads_nothing_past_the_slot(slot):
+    """NaN in every cache position past `slot`, in the slot's own block and
+    in the blocks behind it, reaches no output: the kernel's result is, bit
+    for bit, its result on a cache that holds zeros there. (The einsums
+    give those positions weight zero and still multiply: 0 x NaN.)"""
+    q_lat, q_rope, c_kv, k_r = _decode_inputs(mla_decode.BLOCK_ROWS, jnp.bfloat16)
+    past = (jnp.arange(KERNEL_T) > slot)[None, None, :, None]
+    run = jax.jit(lambda c, r: mla_decode.mla_decode(
+        q_lat, q_rope, c, r, 0, jnp.int32(slot), 0.125))
+    want = run(jnp.where(past, 0, c_kv), jnp.where(past, 0, k_r))
+    got = run(jnp.where(past, jnp.nan, c_kv), jnp.where(past, jnp.nan, k_r))
+    assert bool(jnp.all(jnp.isfinite(got))) and bool(jnp.array_equal(got, want))
+    poisoned = mla_decode.reference(
+        q_lat, q_rope, jnp.where(past, jnp.nan, c_kv), k_r, 0, slot, 0.125)
+    assert not bool(jnp.all(jnp.isfinite(poisoned)))
+
+
+@pytest.mark.parametrize("on_tpu, rank, kernel", [
+    (True, 128, True), (True, 16, False), (False, 128, False)])
+def test_the_decode_takes_the_kernel_where_the_cache_tiles_on_a_tpu(
+        on_tpu, rank, kernel, monkeypatch):
+    """Which path runs is read from the input: the kernel on a TPU where the
+    shapes tile, the einsums elsewhere (the tiny preset's rank of 16, every
+    CPU run), and no error either way."""
+    monkeypatch.setattr(pallas_scan, "on_tpu", lambda: on_tpu)
+    args = _decode_inputs(mla_decode.BLOCK_ROWS, jnp.float32, rank=rank)
+    jaxpr = jax.make_jaxpr(lambda s: mla_decode.mla_decode_auto(
+        *args, 0, s, 0.125))(jnp.int32(3))
+    assert ("pallas_call" in str(jaxpr)) is kernel
+    assert not mla_decode.tiles(mla_decode.BLOCK_ROWS + 1, KERNEL_T, 128)
+    assert not mla_decode.tiles(mla_decode.BLOCK_ROWS, KERNEL_T + 1, 128)
+    with pytest.raises(ValueError, match="not whole blocks"):
+        mla_decode.mla_decode(*_decode_inputs(4, jnp.float32), 0, 3, 0.125)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One chip of a described (not attached) v5e host, for the TPU's own
+    compiler; described here, inside a test, and nowhere at import time."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_rollout_compiled_for_a_v5e_leaves_the_cache_in_hbm(v5e, monkeypatch):
+    """`rollout_scan` at the shipped preset's widths and two layers, compiled
+    for the described chip. In the scan's body the carried cache pair is at
+    home in HBM (no `S(1)`, the compiler's mark for VMEM, on it), nothing
+    cache-sized is copied or sliced asynchronously (a pair a layer lived in
+    VMEM, and every step evicted each to HBM and fetched it back: PERF.md,
+    PR 32), and attention is one kernel a layer."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(pallas_scan, "on_tpu", lambda: True)
+    monkeypatch.setattr(sp, "MOE_DENSE_TOKENS", 128)   # as shipped
+    layers = 2
+    preset = config_mod.resolve(
+        "impala_joyai_flash", None, None, {"seq.num_hidden_layers": str(layers)})
+    cfg = preset.config
+    env, _ = train.build_env(preset.env, preset.algo, cfg, 0,
+                             env_kwargs=preset.env_kwargs)
+    policy = impala.make_policy(env, cfg)
+    key = jax.random.key(0)
+    on_chip = lambda make: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+        jax.eval_shape(make))
+    rollout = jax.jit(lambda p, r, k: common.rollout_scan(
+        env, policy, p, r, k, cfg.rollout_steps))
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = rollout.lower(
+            on_chip(lambda: impala.init_params(env, cfg, key)),
+            on_chip(lambda: common.init_rollout(env, key, cfg.num_envs)),
+            on_chip(lambda: key)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    kernel = 'custom_call_target="tpu_custom_call"'
+    bodies = [c for c in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+              if kernel in c]
+    assert len(bodies) == 1, "the decode kernels are in one computation: the scan's body"
+    body = bodies[0].splitlines()
+    assert sum(kernel in line for line in body) == layers
+    assert all("mla_decode" in line for line in body if kernel in line)
+    dtype = {"bfloat16": "bf16", "float32": "f32"}[cfg.seq.compute_dtype]
+    T, widths = cfg.rollout_steps, f"{cfg.seq.kv_lora_rank}|{cfg.seq.qk_rope_head_dim}"
+    cache_sized = re.compile(rf"{dtype}\[(?:\d+,)*{T},(?:{widths})\]\{{[^}}]*\}}")
+    moved = [line.strip()[:200] for line in body
+             if re.search(r" (?:copy|slice)-start\(", line)
+             and cache_sized.search(re.split(r" (?:copy|slice)-start\(", line)[0])]
+    assert moved == []
+    root, = (line for line in body if line.lstrip().startswith("ROOT"))
+    carried = [shape for shape in cache_sized.findall(root)
+               if shape.startswith(f"{dtype}[{layers},{cfg.num_envs},")]
+    assert len(carried) == 2 and not any("S(1)" in shape for shape in carried), carried
 
 
 # -- the expert layer and the chip's share ----------------------------------
