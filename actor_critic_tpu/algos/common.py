@@ -77,12 +77,16 @@ class Policy(NamedTuple):
     -> (dist, value, carry)` acts on one observation a row; `unroll(params,
     traj) -> Unrolled` re-evaluates a whole trajectory for the update;
     `bootstrap(params, obs) -> value [E]` values the observation after the
-    last step."""
+    last step. A policy that can take the part of an episode no action
+    decides (`EnvSpec.prefill_len` observations: a prompt) in one pass
+    offers `prefill(params, obs [P, E, ...], carry) -> (dist of the last of
+    them, value [P, E], carry)`; one without it is stepped through them."""
 
     init_carry: Callable[[int], Any]
     step: Callable[[Any, jax.Array, Any], tuple[Any, jax.Array, Any]]
     unroll: Callable[[Any, "Transition"], Unrolled]
     bootstrap: Callable[[Any, jax.Array], jax.Array]
+    prefill: Optional[Callable[[Any, jax.Array, Any], tuple[Any, jax.Array, Any]]] = None
 
 
 def feedforward_policy(
@@ -142,14 +146,17 @@ def rollout_scan(
     `policy` is a `Policy`, or a bare `apply_fn(params, obs) -> (dist,
     value)` (`as_policy`); actions are sampled per env with per-step keys.
     What the policy carries from step to step starts fresh here and is
-    dropped at the end. Returns time-major Transition with arrays
-    [T, E, ...].
+    dropped at the end. Where the env hands out the first
+    `spec.prefill_len` observations of an episode at once (`JaxEnv.prefill`)
+    and the policy can take them in one pass (`Policy.prefill`), the rollout
+    starts with that pass: `rstate` must then stand at a reset, as it does
+    where an episode is exactly one rollout. Returns time-major Transition
+    with arrays [T, E, ...].
     """
     policy = as_policy(policy)
 
-    def step_fn(scan_carry, step_key: jax.Array):
-        carry, policy_carry = scan_carry
-        dist, value, policy_carry = policy.step(params, carry.obs, policy_carry)
+    def act(carry: RolloutState, dist, value, step_key: jax.Array):
+        """Sample a row's action, step its env: (next state, the transition)."""
         n_envs = carry.obs.shape[0]
         akeys = jax.random.split(step_key, n_envs)
         action = jax.vmap(lambda d, k: d.sample(k), in_axes=(0, 0))(dist, akeys)
@@ -165,15 +172,50 @@ def rollout_scan(
             terminated=out.info["terminated"],
             final_obs=out.info["final_obs"],
         )
-        return (RolloutState(env_state=out.state, obs=out.obs), policy_carry), trans
+        return RolloutState(env_state=out.state, obs=out.obs), trans
+
+    def step_fn(scan_carry, step_key: jax.Array):
+        carry, policy_carry = scan_carry
+        dist, value, policy_carry = policy.step(params, carry.obs, policy_carry)
+        carry, trans = act(carry, dist, value, step_key)
+        return (carry, policy_carry), trans
 
     # One phase of the fused step's timeline (the scan and all of its
     # body): the scope is the first component of every operation's name
     # stack in a profiler trace, where benchmark/phases.py reads it.
     with jax.named_scope("rollout"):
         step_keys = jax.random.split(key, num_steps)
-        init = (rstate, policy.init_carry(rstate.obs.shape[0]))
-        (rstate, _), traj = jax.lax.scan(step_fn, init, step_keys)
+        policy_carry = policy.init_carry(rstate.obs.shape[0])
+        can_prefill = policy.prefill is not None and env.prefill is not None
+        P = env.spec.prefill_len if can_prefill else 0
+        if not P:
+            (rstate, _), traj = jax.lax.scan(
+                step_fn, (rstate, policy_carry), step_keys)
+            return rstate, traj
+        # The env gives the first P observations of every row at once (no
+        # action decides them), one pass of the policy takes them, and the
+        # action on the last of them is the first one sampled. The first
+        # P - 1 steps of the trajectory are those observations as the env
+        # would have stepped through them: the action ignored (`is_prompt`
+        # masks it out of a loss; a zero and log-probability 0 stand there),
+        # no reward, no end, and the pass's own values.
+        with jax.named_scope("prefill"):
+            env_state, obs = jax.vmap(env.prefill, out_axes=(0, 1))(rstate.env_state)
+            dist, values, policy_carry = policy.prefill(params, obs, policy_carry)
+        lead, trans = act(
+            RolloutState(env_state=env_state, obs=obs[-1]), dist, values[-1],
+            step_keys[P - 1])
+        zeros = jnp.zeros((P - 1, *trans.reward.shape), jnp.float32)
+        prompt = Transition(
+            obs=obs[:-1],
+            action=jnp.zeros((P - 1, *trans.action.shape), trans.action.dtype),
+            log_prob=zeros, value=values[:-1], reward=zeros, done=zeros,
+            terminated=zeros, final_obs=obs[1:],
+        )
+        (rstate, _), traj = jax.lax.scan(
+            step_fn, (lead, policy_carry), step_keys[P:])
+        traj = jax.tree.map(
+            lambda a, b, c: jnp.concatenate([a, b[None], c]), prompt, trans, traj)
         return rstate, traj
 
 

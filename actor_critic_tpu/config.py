@@ -223,6 +223,72 @@ PRESETS: dict[str, Preset] = {
         env_kwargs={"vocab_size": 8, "horizon": 16,
                     "prompt_min": 1, "prompt_max": 2},
     ),
+    # Token-level IMPALA over one chip's share of Mellum2-12B-A2.5B-Instruct
+    # (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/main/config.json):
+    # grouped-query attention, 32 query heads over 4 key/value heads of 128,
+    # three layers with a causal window of 1,024 and plain RoPE to one full
+    # layer with YaRN-scaled RoPE; 64 softmax-routed experts a layer, top-8,
+    # renormalised, no shared expert, no dense layer. 4 chips share each
+    # layer: this one holds 16 of the 64 experts and a quarter of the
+    # vocabulary (24,576 rows), and one period, 4 of the 28 layers. 595.3M
+    # parameters, 9.5 GB with the actor copy, gradients and RMSProp's moment.
+    # Rows of 4,096: prompts of 3,072-3,584 tokens whose first 3,072 go
+    # through one causal pass (`prefill_len`), the answer fills the row.
+    "impala_mellum2": Preset(
+        algo="impala",
+        env="jax:token_task",
+        config=impala.ImpalaConfig(
+            num_envs=8, rollout_steps=4096, actor_refresh_every=4,
+            gamma=1.0, lr=1e-4, entropy_coef=0.001,
+            seq=SeqPolicyConfig(
+                hidden_size=2304, num_attention_heads=32,
+                moe_intermediate_size=896, n_routed_experts=64,
+                num_experts_per_tok=8, scoring_func="softmax",
+                routed_scaling_factor=1.0, n_shared_experts=0,
+                first_k_dense_replace=0, num_hidden_layers=4,
+                layer_types=("sliding_attention",) * 3 + ("full_attention",),
+                num_key_value_heads=4, head_dim=128, sliding_window=1024,
+                rope_theta=500000.0, rope_factor=16.0,
+                rope_original_max_position_embeddings=8192,
+                rope_beta_fast=32.0, rope_beta_slow=1.0,
+                rope_attention_factor=1.2772588722239782,
+                experts_held=16, expert_offset=0,
+            ),
+        ),
+        iterations=200,
+        description="Token-level IMPALA over a chip's share of "
+        "Mellum2-12B-A2.5B (window and full GQA layers 3:1, 16 of 64 "
+        "softmax-routed experts, 4 layers), prompts prefilled in one pass",
+        env_kwargs={"vocab_size": 24576, "horizon": 4096, "prompt_min": 3072,
+                    "prompt_max": 3584, "prefill_len": 3072},
+    ),
+    # The same program at toy widths: a window of 4 in rows of 16, so the
+    # rings wrap three times.
+    "impala_mellum2_tiny": Preset(
+        algo="impala",
+        env="jax:token_task",
+        config=impala.ImpalaConfig(
+            num_envs=32, rollout_steps=16, actor_refresh_every=2,
+            gamma=1.0, lr=3e-3, rms_eps=1e-5, entropy_coef=0.003,
+            seq=SeqPolicyConfig(
+                hidden_size=64, num_attention_heads=4,
+                moe_intermediate_size=32, n_routed_experts=16,
+                num_experts_per_tok=2, scoring_func="softmax",
+                routed_scaling_factor=1.0, n_shared_experts=0,
+                first_k_dense_replace=0, num_hidden_layers=4,
+                layer_types=("sliding_attention",) * 3 + ("full_attention",),
+                num_key_value_heads=2, head_dim=8, sliding_window=4,
+                rope_theta=500000.0, rope_factor=16.0,
+                rope_original_max_position_embeddings=8,
+                rope_attention_factor=1.2772588722239782,
+                experts_held=4, compute_dtype="float32",
+            ),
+        ),
+        iterations=300,
+        description="impala_mellum2 at toy widths (CPU tests and drives)",
+        env_kwargs={"vocab_size": 8, "horizon": 16, "prompt_min": 2,
+                    "prompt_max": 3, "prefill_len": 2},
+    ),
     "a3c_pong": Preset(
         algo="a3c",
         env="jax:pong",

@@ -37,7 +37,7 @@ tests/test_scenarios.py), so fleets are reproducible.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +67,9 @@ class EnvSpec:
     # still-running episodes are never cut (and then wrongly excluded
     # from the finished-episode mean — common.evaluate docstring).
     episode_horizon: int = 0
+    # How many observations from a reset no action decides (a token env's
+    # prompt), which `JaxEnv.prefill` yields at once; 0: none.
+    prefill_len: int = 0
 
     @property
     def pixel_obs(self) -> bool:
@@ -87,6 +90,10 @@ class JaxEnv:
     spec: EnvSpec
     reset: Callable[[jax.Array], tuple[Any, jax.Array]]
     step: Callable[[Any, jax.Array], StepOutput]
+    # Where `spec.prefill_len` = P > 0: `prefill(state at a reset) -> (state
+    # at the last of the episode's first P observations, those observations
+    # [P, ...])`, as P - 1 steps would give them whatever the actions.
+    prefill: Optional[Callable[[Any], tuple[Any, jax.Array]]] = None
 
     def __hash__(self):
         return id(self)

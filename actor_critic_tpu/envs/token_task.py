@@ -21,6 +21,11 @@ Protocol (the JaxEnv conventions, with two readings of its own):
   carries a cache through the rollout relies on (`models/seq_policy.py`).
 - Prompt tokens are uniform over the ids other than EOS, prompt lengths
   log-uniform in `[prompt_min, prompt_max]`, both from the episode's key.
+- `prefill_len` = P (at most `prompt_min`, so the first P tokens of every row
+  are prompt; 0: none) is how much of the prompt `prefill` hands out at once,
+  for a policy that takes it in one pass (`common.rollout_scan`): the row's
+  first P observations, and the state at the last of them. The action on that
+  one is the first the env may read (it does where the prompt is P long).
 """
 
 from __future__ import annotations
@@ -49,11 +54,17 @@ def make_token_task(
     prompt_min: int = 16,
     prompt_max: int = 128,
     eos_id: int = 0,
+    prefill_len: int = 0,
 ) -> JaxEnv:
     if not 1 <= prompt_min <= prompt_max < horizon:
         raise ValueError(
             f"need 1 <= prompt_min <= prompt_max < horizon, got "
             f"{prompt_min}, {prompt_max}, {horizon}"
+        )
+    if not 0 <= prefill_len <= prompt_min:
+        raise ValueError(
+            f"prefill_len={prefill_len} must be at most prompt_min="
+            f"{prompt_min}: only prompt tokens are known before the policy acts"
         )
     if vocab_size < 2 or not 0 <= eos_id < vocab_size:
         raise ValueError(f"bad vocab_size / eos_id: {vocab_size}, {eos_id}")
@@ -123,11 +134,21 @@ def make_token_task(
             info={"terminated": done, "final_obs": obs_of(moved)},
         )
 
+    def prefill(state: TokenTaskState):
+        position = jnp.arange(prefill_len, dtype=jnp.int32)
+        obs = jnp.stack(
+            [state.prompt[:prefill_len], position,
+             (position + 1 < state.prompt_len).astype(jnp.int32)], axis=-1)
+        return state._replace(
+            position=position[-1], token=state.prompt[prefill_len - 1]), obs
+
     return JaxEnv(
         spec=EnvSpec(
             obs_shape=(3,), action_dim=vocab_size, discrete=True,
             obs_dtype=jnp.int32, can_truncate=False, episode_horizon=horizon,
+            prefill_len=prefill_len,
         ),
         reset=reset,
         step=step,
+        prefill=prefill if prefill_len else None,
     )

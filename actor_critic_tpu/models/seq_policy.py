@@ -1,8 +1,15 @@
-"""A decoder policy over tokens: latent attention (MLA) and sigmoid-routed
-experts of which this chip holds a share, with a cache through the rollout.
+"""A decoder policy over tokens: latent attention (MLA) or grouped-query
+attention in window and full layers, and routed experts of which this chip
+holds a share, with a cache through the rollout.
 
 The block, as the configuration's source publishes it (RMSNorm eps 1e-6;
-`x += MLA(norm(x))`; `x += FFN(norm(x))`; final norm; untied `lm_head`):
+`x += Attention(norm(x))`; `x += FFN(norm(x))`; final norm; untied
+`lm_head`). The configuration says, under the source's own key names, which
+attention each layer has (`layer_types`; none: MLA everywhere), how the router
+scores (`scoring_func`), whether a layer has a shared expert
+(`n_shared_experts`) and how many leading layers are dense
+(`first_k_dense_replace`); from the residual stream after attention on, every
+configuration runs the same code.
 
 - MLA: `c_q = norm(x W_qa)`; `q = c_q W_qb` -> heads x (nope + rope);
   `[c_kv, k_r] = x W_kva`; `c_kv = norm(c_kv)`; `[k_nope, v] = c_kv W_kvb` ->
@@ -10,11 +17,23 @@ The block, as the configuration's source publishes it (RMSNorm eps 1e-6;
   the one `k_r` all heads share; scores `q.k / sqrt(nope + rope)`, causal;
   `out = concat_h(softmax . v) W_o`. What decoding keeps per token and layer
   is `c_kv` and `k_r` (`kv_lora_rank + qk_rope_head_dim` values).
+- Grouped-query attention (`layer_types[l]`): `q = x W_q` -> heads x
+  `head_dim`, `k = x W_k`, `v = x W_v` -> `num_key_value_heads` x `head_dim`,
+  no bias; RoPE on the whole of `q` and `k`, pairs `(j, j + head_dim / 2)`;
+  query head `i` reads key/value head `i // (heads / kv heads)`; scores `q.k /
+  sqrt(head_dim)`. `"sliding_attention"`: the query at `t` sees keys `s` with
+  `0 <= t - s < sliding_window`, frequencies `theta ** (-2j / d)`.
+  `"full_attention"`: causal, frequencies scaled as YaRN does
+  (`rope_frequencies`), cos and sin times `rope_attention_factor`. What
+  decoding keeps per token and layer is the key after RoPE and the value; a
+  window layer keeps the last `sliding_window` of them, in a ring.
 - Expert layer: `s = sigmoid(x W_g)` (float32, all `n_routed_experts`); the
   top `num_experts_per_tok` by `s + b`; weights `s[idx] / sum(s[idx]) x
-  routed_scaling_factor`; `y = sum_i w_i E_i(x) + E_shared(x)`, each expert
-  `down(silu(gate x) * up x)`. The first `first_k_dense_replace` layers are
-  that MLP at `intermediate_size`, with no router.
+  routed_scaling_factor`; or `s = softmax(x W_g)` over all the experts, the
+  top by `s`, no `b`; `y = sum_i w_i E_i(x) + E_shared(x)` (the shared expert
+  where the layer has one), each expert `down(silu(gate x) * up x)`. The first
+  `first_k_dense_replace` layers are that MLP at `intermediate_size`, with no
+  router.
 - The chip's share: the layer is told `experts_held` and `expert_offset`. It
   routes over all experts at the published router width and adds `w_i E_i(x)`
   only for the chosen experts it holds (weights normalised over all chosen
@@ -53,7 +72,11 @@ token rows) is one more, so the `[heads, T, T]` scores and the wide halves of
 MLA are alive a trip at a time, in the backward pass too. The update therefore
 runs a trip of attention forward twice (the forward pass, the trip's own
 rematerialization) and the FFN likewise; the cut between the halves is there
-so that rebuilding the FFN's input does not run attention a third time.
+so that rebuilding the FFN's input does not run attention a third time. A
+grouped-query layer's trip is `GQA_ROWS` episodes, not rematerialized as a
+whole: inside it every block of `ATTN_QUERIES` queries is, and a block takes
+the keys of its band only (`gqa_unroll`), so a row of 4,096 fits and a window
+layer never multiplies what it would mask.
 
 The cache is a carry of the rollout scan and starts fresh with it, so an
 episode must be exactly one unroll and every row at the same position: the
@@ -61,19 +84,29 @@ token env guarantees both (`envs/token_task.py`) and `make_policy` refuses an
 env whose `episode_horizon` is not the unroll length. The cache slot of a step
 is row 0's position. It is one stacked pair for the whole model, which stays
 in HBM through the scan; a decode step writes one slot a layer in place and
-reads each layer's filled prefix once (`ops/mla_decode.py`).
+reads each layer's filled prefix once (`ops/mla_decode.py`). Grouped-query
+layers have one stacked pair a KIND, each at its own size (`init_cache`), and
+their policy can fill them from a prompt in one causal pass
+(`Policy.prefill`, `trunk(..., cache)`) before decoding starts.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from actor_critic_tpu.models.distributions import Categorical
 from actor_critic_tpu.ops.mla_decode import mla_decode_auto
+
+
+# The kinds of grouped-query layer, as the source's `layer_types` names them.
+GQA_KINDS = ("sliding_attention", "full_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,6 +130,24 @@ class SeqPolicyConfig:
     num_hidden_layers: int = 40
     rms_norm_eps: float = 1e-6
     rope_theta: float = 32e6
+    scoring_func: str = "sigmoid"     # or "softmax": no bias, no scaling
+    n_shared_experts: int = 1         # 0: the expert layer has no shared expert
+    # The attention of each layer: () is latent attention (MLA, the keys
+    # above) in every layer; else the kind of each of the first
+    # `num_hidden_layers` layers, "sliding_attention" or "full_attention":
+    # grouped-query attention over `num_key_value_heads` heads of
+    # `head_dim`, a causal window of `sliding_window` keys (the query's own
+    # counted) with plain RoPE, or full causal attention with RoPE scaled as
+    # `rope_parameters.full_attention` says (YaRN; a factor of 1 is plain).
+    layer_types: tuple[str, ...] = ()
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 1024
+    rope_factor: float = 1.0
+    rope_original_max_position_embeddings: int = 8192
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_attention_factor: float = 1.0
     # The chip's share of each expert layer.
     experts_held: int = 256
     expert_offset: int = 0
@@ -110,6 +161,27 @@ class SeqPolicyConfig:
             )
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim must be even (RoPE pairs)")
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func={self.scoring_func!r}: sigmoid or softmax")
+        if self.layer_types:
+            kinds = self.layer_types[:self.num_hidden_layers]
+            if len(kinds) < self.num_hidden_layers or set(kinds) - set(GQA_KINDS):
+                raise ValueError(
+                    f"layer_types must name {self.num_hidden_layers} layers, "
+                    f"each one of {GQA_KINDS}; got {self.layer_types}")
+            if self.num_attention_heads % self.num_key_value_heads or self.head_dim % 2:
+                raise ValueError(
+                    f"{self.num_attention_heads} query heads do not group over "
+                    f"{self.num_key_value_heads} key/value heads, or head_dim="
+                    f"{self.head_dim} is odd")
+
+    def attention(self, layer: int) -> str:
+        """The kind of layer `layer`'s attention: "mla" or one of `GQA_KINDS`."""
+        return self.layer_types[layer] if self.layer_types else "mla"
+
+    def cache_index(self, layer: int) -> int:
+        """Which layer of its kind's stacked cache a grouped-query layer is."""
+        return self.layer_types[:layer].count(self.layer_types[layer])
 
     @property
     def latent_dim(self) -> int:
@@ -117,6 +189,9 @@ class SeqPolicyConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
 
+# The scope round the attention half of a layer of each kind.
+SCOPE_OF = {"mla": "mla", "sliding_attention": "attn_window",
+            "full_attention": "attn_full"}
 # The floor under the sum of the chosen scores, as the source's code has it.
 _EPS = 1e-20
 # `e_score_correction_bias`: a seeded, non-zero, untrained buffer, uniform in
@@ -125,6 +200,12 @@ BIAS_SCALE = 0.05
 # Blocking of the passes (memory, never results), sized so that the shipped
 # preset's step program fits one v5e (15.2 GB by `memory_analysis()`).
 ATTN_ROWS = 8       # episodes a trip of the causal pass's attention
+# The grouped-query layers' causal pass also blocks over queries, so that a
+# row of 4,096 positions fits: a trip holds the `[heads, ATTN_QUERIES, keys]`
+# scores of `GQA_ROWS` episodes, and a window layer's block meets only the
+# keys of its band.
+GQA_ROWS = 1        # episodes a trip
+ATTN_QUERIES = 512  # queries a block
 MLP_ROWS = 8192     # token rows a trip of the dense MLP and the shared expert
 HEAD_ROWS = 4096    # token rows a trip of the lm_head
 MOE_ROWS = 24576    # held assignments a trip of the grouped matmuls
@@ -159,7 +240,15 @@ def init_params(key: jax.Array, cfg: SeqPolicyConfig, vocab_size: int) -> dict:
         layer = {
             "attn_norm": jnp.ones((H,), jnp.float32),
             "ffn_norm": jnp.ones((H,), jnp.float32),
-            "mla": {
+        }
+        if cfg.attention(i) != "mla":
+            kv = cfg.num_key_value_heads * cfg.head_dim
+            layer["attn"] = {
+                "w_q": mat(H, nh * cfg.head_dim), "w_k": mat(H, kv),
+                "w_v": mat(H, kv), "w_o": mat(nh * cfg.head_dim, H),
+            }
+        else:
+            layer["mla"] = {
                 "w_qa": mat(H, cfg.q_lora_rank),
                 "q_norm": jnp.ones((cfg.q_lora_rank,), jnp.float32),
                 "w_qb": mat(cfg.q_lora_rank,
@@ -169,19 +258,20 @@ def init_params(key: jax.Array, cfg: SeqPolicyConfig, vocab_size: int) -> dict:
                 "w_kvb": mat(cfg.kv_lora_rank,
                              nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
                 "w_o": mat(nh * cfg.v_head_dim, H),
-            },
-        }
+            }
         if i < cfg.first_k_dense_replace:
             layer["mlp"] = mlp(width=cfg.intermediate_size)
         else:
-            layer["moe"] = {
-                "router": mat(H, cfg.n_routed_experts),
-                "bias": jax.random.uniform(
+            layer["moe"] = {"router": mat(H, cfg.n_routed_experts)}
+            if cfg.scoring_func == "sigmoid":
+                layer["moe"]["bias"] = jax.random.uniform(
                     next(keys), (cfg.n_routed_experts,), jnp.float32,
-                    -BIAS_SCALE, BIAS_SCALE),
-                "experts": mlp(cfg.experts_held, width=cfg.moe_intermediate_size),
-                "shared": mlp(width=cfg.moe_intermediate_size),
-            }
+                    -BIAS_SCALE, BIAS_SCALE)
+            layer["moe"]["experts"] = mlp(
+                cfg.experts_held, width=cfg.moe_intermediate_size)
+            if cfg.n_shared_experts:
+                layer["moe"]["shared"] = mlp(
+                    width=cfg.n_shared_experts * cfg.moe_intermediate_size)
         p[f"layer_{i}"] = layer
     p["final_norm"] = jnp.ones((H,), jnp.float32)
     p["lm_head"] = mat(H, vocab_size, scale=0.01)
@@ -223,17 +313,19 @@ def _rope(x, positions, theta):
     return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
 
 
-def _map_rows(fn, rows: int, *xs):
+def _map_rows(fn, rows: int, *xs, remat: bool = True):
     """`fn` over the leading axis of `xs`, `rows` at a time, each trip
     rematerialized in the backward pass (so a trip's intermediates are never
-    all alive). One call where `rows` covers everything."""
+    all alive; `remat=False` where `fn` rematerializes smaller pieces itself).
+    One call where `rows` covers everything."""
     n = xs[0].shape[0]
     if rows >= n:
         return fn(*xs)
     while n % rows:  # the largest trip under `rows` that divides
         rows -= 1
     split = lambda x: x.reshape(n // rows, rows, *x.shape[1:])  # noqa: E731
-    out = jax.lax.map(lambda c: jax.checkpoint(fn)(*c), tuple(map(split, xs)))
+    out = jax.lax.map(lambda c: (jax.checkpoint(fn) if remat else fn)(*c),
+                      tuple(map(split, xs)))
     return jax.tree.map(lambda y: y.reshape(n, *y.shape[2:]), out)
 
 
@@ -305,13 +397,151 @@ def mla_step(p, h, positions, cache, layer: int, slot, cfg: SeqPolicyConfig):
     o = _einsum("ehc,chv->ehv", o_lat, w_kvb[..., dn:], cd)
     return _mm(o.reshape(h.shape[0], nh * dv), p["w_o"], cd), (c_all, r_all)
 
+# -- grouped-query attention, windowed and full ----------------------------
+
+def rope_frequencies(cfg: SeqPolicyConfig, kind: str):
+    """(inv_freq [head_dim / 2] float32, the factor on cos and sin) of a
+    grouped-query layer of `kind`. A window layer: `theta ** (-2j / d)`.
+    A full layer: YaRN, as `rope_parameters.full_attention` gives it. Pair
+    `j` turns `original_max_position_embeddings * inv_freq_j / 2 pi` times
+    over the original context; the pairs that turn `beta_fast` times or more
+    keep their frequency, those that turn `beta_slow` times or fewer take
+    `inv_freq_j / factor`, a linear ramp over the pair index between the two
+    blends the rest, at every position; cos and sin are multiplied by
+    `attention_factor`."""
+    d = cfg.head_dim
+    inv = cfg.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if kind != "full_attention" or cfg.rope_factor == 1.0:
+        return inv.astype(np.float32), 1.0
+
+    def pair_that_turns(times):
+        # jaxlint: disable=nonfinite-hazard (host arithmetic on the
+        # configuration's positive constants, at trace time; no array)
+        return d * math.log(cfg.rope_original_max_position_embeddings
+                            / (times * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(pair_that_turns(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(cfg.rope_beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv = inv / cfg.rope_factor * ramp + inv * (1.0 - ramp)
+    return inv.astype(np.float32), cfg.rope_attention_factor
+
+
+def _rope_halves(x, positions, freq):
+    """Rotate the pairs `(x[j], x[j + d/2])` of the last axis (the
+    `rotate_half` convention) by `position * inv_freq_j`; `positions`
+    broadcasts against `x.shape[:-1]`, `freq` is `rope_frequencies`'."""
+    inv, factor = freq
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+    x = x.astype(jnp.float32)
+    a, b = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _gqa_project(p, h, positions, cfg, kind, which: str):
+    """`h W_<which>` as `[.., heads, head_dim]`, RoPE applied to q and k."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    y = _mm(h, p[f"w_{which}"], cd, out=cd)
+    y = y.reshape(*h.shape[:-1], -1, cfg.head_dim)
+    if which == "v":
+        return y
+    return _rope_halves(y, positions[..., None], rope_frequencies(cfg, kind)).astype(cd)
+
+
+def window_of(cfg: SeqPolicyConfig, kind: str, horizon: int) -> int:
+    """How many keys a query of a layer of `kind` sees at most, its own
+    counted, and so the slots a row of that layer's cache has."""
+    return min(cfg.sliding_window, horizon) if kind == "sliding_attention" else horizon
+
+
+def gqa_unroll(p, h, positions, cfg: SeqPolicyConfig, kind: str):
+    """Causal grouped-query attention over `h [E, T, H]`: (out [E, T, H],
+    (k, v) each `[E, T, kv heads, head_dim]` in `compute_dtype`, the keys
+    after RoPE: what a cache keeps). Keys and values are computed for all
+    rows at once; the queries, the scores and the output projection run
+    `GQA_ROWS` episodes a trip and, inside a trip, `ATTN_QUERIES` queries a
+    block, each block rematerialized in the backward pass. A block of
+    queries `[q0, q1)` takes the keys `[q0 - window + 1, q1)` and no others:
+    a window layer never multiplies what lies outside its band."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    nkv, d = cfg.num_key_value_heads, cfg.head_dim
+    T = h.shape[1]
+    window = window_of(cfg, kind, T)
+    k = _gqa_project(p, h, positions, cfg, kind, "k")
+    v = _gqa_project(p, h, positions, cfg, kind, "v")
+
+    def block(q0, q1, h, k, v, positions):
+        lo = max(0, q0 - window + 1)
+        q = _gqa_project(p, h[:, q0:q1], positions[:, q0:q1], cfg, kind, "q")
+        q = q.reshape(*q.shape[:2], nkv, -1, d)
+        s = _einsum("eqkgd,eskd->ekgqs", q, k[:, lo:q1], cd) * d ** -0.5
+        ahead = jnp.arange(q0, q1)[:, None] - jnp.arange(lo, q1)[None, :]
+        s = jnp.where((ahead >= 0) & (ahead < window), s, -jnp.inf)
+        out = _einsum("ekgqs,eskd->eqkgd", jax.nn.softmax(s, axis=-1), v[:, lo:q1], cd)
+        return _mm(out.reshape(*out.shape[:2], -1), p["w_o"], cd)
+
+    def attend(h, k, v, positions):
+        return jnp.concatenate([
+            jax.checkpoint(functools.partial(block, q0, min(q0 + ATTN_QUERIES, T)))(
+                h, k, v, positions)
+            for q0 in range(0, T, ATTN_QUERIES)], axis=1)
+
+    return _map_rows(attend, GQA_ROWS, h, k, v, positions, remat=False), (k, v)
+
+
+def gqa_step(p, h, positions, cache, index: int, slot, cfg: SeqPolicyConfig, kind: str):
+    """One token a row through a grouped-query layer whose keys and values
+    are layer `index` of `cache = (k, v)`, each `[layers, E, kv heads, slots,
+    head_dim]`: the token's key (after RoPE) and value go into slot `slot
+    mod slots`, so a window layer's slots are a ring that keeps the last
+    `slots` positions (a key carries its own rotation, so the order of the
+    slots does not matter to the scores), and attention reads the slots
+    filled so far. Returns (out [E, H], cache)."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    nkv, d = cfg.num_key_value_heads, cfg.head_dim
+    slots = cache[0].shape[3]
+    q = _gqa_project(p, h, positions, cfg, kind, "q").reshape(h.shape[0], nkv, -1, d)
+    put = lambda buf, new: jax.lax.dynamic_update_slice(  # noqa: E731
+        buf, new.astype(buf.dtype)[None, :, :, None, :], (index, 0, 0, slot % slots, 0))
+    k_all = put(cache[0], _gqa_project(p, h, positions, cfg, kind, "k"))
+    v_all = put(cache[1], _gqa_project(p, h, positions, cfg, kind, "v"))
+    s = _einsum("ekgd,eksd->ekgs", q, k_all[index], cd) * d ** -0.5
+    s = jnp.where(jnp.arange(slots) <= slot, s, -jnp.inf)
+    o = _einsum("ekgs,eksd->ekgd", jax.nn.softmax(s, axis=-1), v_all[index], cd)
+    return _mm(o.reshape(h.shape[0], -1), p["w_o"], cd), (k_all, v_all)
+
+
+def gqa_fill(cache, index: int, k, v):
+    """Layer `index` of `cache` as a causal pass over positions `[0, P)`
+    leaves it: `k`, `v` `[E, P, kv heads, head_dim]` at slot `position mod
+    slots`, the last `slots` positions where the pass was longer."""
+    slots = cache[0].shape[3]
+    P = k.shape[1]
+
+    def put(buf, new):
+        new = jnp.swapaxes(new, 1, 2)[:, :, max(0, P - slots):]
+        if P > slots:  # position j of the kept ones sits at slot j mod slots
+            new = jnp.roll(new, (P - slots) % slots, axis=2)
+        return jax.lax.dynamic_update_slice(
+            buf, new.astype(buf.dtype)[None], (index, 0, 0, 0, 0))
+
+    return put(cache[0], k), put(cache[1], v)
+
 
 def route(p, h, cfg: SeqPolicyConfig):
-    """The published router: (idx [N, k] over all experts, weights [N, k])."""
-    s = jax.nn.sigmoid(jnp.matmul(
-        h.astype(jnp.float32), p["router"], precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(p["bias"]),
-                           cfg.num_experts_per_tok)
+    """The published router: (idx [N, k] over all experts, weights [N, k]).
+    Sigmoid scores are picked by score + bias; softmax scores (over all the
+    experts) by themselves, with no bias."""
+    logits = jnp.matmul(
+        h.astype(jnp.float32), p["router"], precision=jax.lax.Precision.HIGHEST)
+    if cfg.scoring_func == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(s, cfg.num_experts_per_tok)
+    else:
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(p["bias"]),
+                               cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + _EPS)
     return idx, weights * cfg.routed_scaling_factor
@@ -412,7 +642,7 @@ def _held_experts_dense(experts, h, weights_here, cd):
 def moe(p, h, cfg: SeqPolicyConfig):
     """The expert layer's part this chip computes for tokens `h [N, H]`:
     `sum_i w_i E_i(x)` over the chosen experts held here, plus the shared
-    expert. Returns (y [N, H], stats)."""
+    expert where the layer has one. Returns (y [N, H], stats)."""
     cd = jnp.dtype(cfg.compute_dtype)
     N, _ = h.shape
     held = cfg.experts_held
@@ -437,8 +667,9 @@ def moe(p, h, cfg: SeqPolicyConfig):
     else:
         y, done = _held_experts(cfg, R)(
             p["experts"], h, weights.reshape(A), (order, ends, sizes, landed))
-    with jax.named_scope("moe_shared"):
-        y = y + _map_rows(lambda x: _swiglu(p["shared"], x, cd), MLP_ROWS, h)
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            y = y + _map_rows(lambda x: _swiglu(p["shared"], x, cd), MLP_ROWS, h)
     mean_load = jnp.maximum(landed, 1).astype(jnp.float32) / held
     return y, {
         "routed_here_frac": landed.astype(jnp.float32) / A,
@@ -468,8 +699,19 @@ def init_cache(cfg: SeqPolicyConfig, num_envs: int, horizon: int):
     (its values are matmul operands only). Stacked, a rollout's carry is too
     large for the compiler to keep in VMEM between decode steps (a pair a
     layer it kept there, and evicted to HBM and fetched back every step):
-    its home is HBM, where a step writes one slot in place."""
+    its home is HBM, where a step writes one slot in place.
+
+    Grouped-query layers keep keys and values, each kind of layer at its own
+    size: one stacked pair `[layers of the kind, E, kv heads, slots,
+    head_dim]` a kind, `horizon` slots a row for the full layers and a ring
+    of `sliding_window` for the window layers: `{kind: (k, v)}`."""
     cd = jnp.dtype(cfg.compute_dtype)
+    if cfg.layer_types:
+        kinds = cfg.layer_types[:cfg.num_hidden_layers]
+        return {kind: tuple(
+            jnp.zeros((kinds.count(kind), num_envs, cfg.num_key_value_heads,
+                       window_of(cfg, kind, horizon), cfg.head_dim), cd)
+            for _ in "kv") for kind in GQA_KINDS}
     lead = (cfg.num_hidden_layers, num_envs, horizon)
     return (jnp.zeros((*lead, cfg.kv_lora_rank), cd),
             jnp.zeros((*lead, cfg.qk_rope_head_dim), cd))
@@ -484,9 +726,15 @@ def step(params, obs, cache, cfg: SeqPolicyConfig):
     slot = positions[0]
     x = jnp.take(p["embed"], tokens, axis=0)
     for i, layer in enumerate(layers):
-        with jax.named_scope("mla"):
+        kind = cfg.attention(i)
+        with jax.named_scope(SCOPE_OF[kind]):
             h = _rms(x, layer["attn_norm"], cfg.rms_norm_eps)
-            a, cache = mla_step(layer["mla"], h, positions, cache, i, slot, cfg)
+            if kind == "mla":
+                a, cache = mla_step(layer["mla"], h, positions, cache, i, slot, cfg)
+            else:
+                a, kv = gqa_step(layer["attn"], h, positions, cache[kind],
+                                 cfg.cache_index(i), slot, cfg, kind)
+                cache = {**cache, kind: kv}
             x = x + a
         y, _ = _ffn(layer, _rms(x, layer["ffn_norm"], cfg.rms_norm_eps), cfg)
         x = x + y
@@ -501,9 +749,11 @@ def _value(p, h):
     return v[..., 0] + p["value_head"]["bias"][0]
 
 
-def trunk(params, obs, cfg: SeqPolicyConfig):
+def trunk(params, obs, cfg: SeqPolicyConfig, cache=None):
     """The causal pass over `obs [E, T, 3]`: (final-normed hidden [E, T, H],
-    the expert layers' stats, layers-mean).
+    the expert layers' stats, layers-mean, `cache`). A cache of grouped-query
+    layers (`init_cache`'s) is returned as this pass over positions `[0, T)`
+    leaves it (the prefill); None stays None.
 
     Each half of a layer is rematerialized on its own, so the backward pass
     keeps the layer's input and the residual stream after attention. With
@@ -518,10 +768,13 @@ def trunk(params, obs, cfg: SeqPolicyConfig):
     E, T = tokens.shape
     x = jnp.take(p["embed"], tokens, axis=0)
 
-    def attn(layer, x):
-        with jax.named_scope("mla"):
+    def attn(kind, layer, x):
+        with jax.named_scope(SCOPE_OF[kind]):
             h = _rms(x, layer["attn_norm"], cfg.rms_norm_eps)
-            return x + mla_unroll(layer["mla"], h, positions, cfg)
+            if kind == "mla":
+                return x + mla_unroll(layer["mla"], h, positions, cfg), None
+            a, kv = gqa_unroll(layer["attn"], h, positions, cfg, kind)
+            return x + a, kv
 
     def ffn(layer, x):
         h = _rms(x, layer["ffn_norm"], cfg.rms_norm_eps).reshape(E * T, -1)
@@ -529,20 +782,23 @@ def trunk(params, obs, cfg: SeqPolicyConfig):
         return x + y.reshape(x.shape), stats
 
     stats = []
-    for layer in layers:
-        x = jax.checkpoint(attn)(layer, x)
+    for i, layer in enumerate(layers):
+        kind = cfg.attention(i)
+        x, kv = jax.checkpoint(attn, static_argnums=0)(kind, layer, x)
+        if cache is not None:
+            cache = {**cache, kind: gqa_fill(cache[kind], cfg.cache_index(i), *kv)}
         x, s = jax.checkpoint(ffn)(layer, x)
         if s is not None:
             stats.append(s)
     mean = {k: jnp.mean(jnp.stack([s[k] for s in stats])) for k in stats[0]} \
         if stats else {}
-    return _rms(x, p["final_norm"], cfg.rms_norm_eps), mean
+    return _rms(x, p["final_norm"], cfg.rms_norm_eps), mean, cache
 
 
 def logits_and_values(params, obs, cfg: SeqPolicyConfig):
     """(logits [E, T, V], values [E, T]) of the causal pass, logits whole:
     for tests and the benchmark's check at a few rows, not for the loss."""
-    h, _ = trunk(params, obs, cfg)
+    h, _, _ = trunk(params, obs, cfg)
     with jax.named_scope("lm_head"):
         logits = _mm(h, params["params"]["lm_head"], jnp.dtype(cfg.compute_dtype))
     return logits, _value(params["params"], h)
@@ -554,7 +810,7 @@ def unroll(params, obs, actions, cfg: SeqPolicyConfig):
     expert layers' stats). The `[E*T, V]` log-probabilities are never whole
     in memory: the head runs `HEAD_ROWS` token rows a trip."""
     cd = jnp.dtype(cfg.compute_dtype)
-    h, stats = trunk(params, obs, cfg)
+    h, stats, _ = trunk(params, obs, cfg)
     E, T, H = h.shape
     w = params["params"]["lm_head"]
 
@@ -600,9 +856,29 @@ def make_policy(spec, cfg: SeqPolicyConfig, horizon: int):
         log_prob, entropy, value, stats = unroll(
             params, obs, jnp.swapaxes(traj.action, 0, 1), cfg)
         mask = 1.0 - traj.obs[..., 2].astype(jnp.float32)
-        stats = {**stats, "response_frac": jnp.mean(mask)}
+        stats = {**stats, "response_frac": jnp.mean(mask), **shape_stats}
         return Unrolled(log_prob.T, entropy.T, value.T, mask, stats)
 
+    def policy_prefill(params, obs, cache):
+        h, _, cache = trunk(params, jnp.swapaxes(obs, 0, 1), cfg, cache)
+        with jax.named_scope("lm_head"):
+            logits = _mm(h[:, -1], params["params"]["lm_head"],
+                         jnp.dtype(cfg.compute_dtype))
+        return Categorical(logits), _value(params["params"], h).T, cache
+
+    # What the shape of the traffic alone decides, as counters of the rows:
+    # the key positions the window layers attend over what full causal layers
+    # would, and the positions one prefill pass fills over all positions.
+    shape_stats = {}
+    if cfg.layer_types:
+        kept = [min(t + 1, window_of(cfg, kind, horizon))
+                for kind in cfg.layer_types[:cfg.num_hidden_layers]
+                for t in range(horizon)]
+        shape_stats = {
+            "window_kept_frac": jnp.float32(
+                sum(kept) / (cfg.num_hidden_layers * horizon * (horizon + 1) / 2)),
+            "prefill_frac": jnp.float32(spec.prefill_len / horizon),
+        }
     return Policy(
         init_carry=lambda num_envs: init_cache(cfg, num_envs, horizon),
         step=policy_step,
@@ -610,4 +886,7 @@ def make_policy(spec, cfg: SeqPolicyConfig, horizon: int):
         # Every episode terminates at the unroll's last step, so V-trace
         # multiplies the bootstrap by zero: nothing to evaluate.
         bootstrap=lambda params, obs: jnp.zeros((obs.shape[0],), jnp.float32),
+        # The grouped-query layers' caches can be filled by one causal pass;
+        # the latent cache is decoded into from position 0.
+        prefill=policy_prefill if cfg.layer_types else None,
     )

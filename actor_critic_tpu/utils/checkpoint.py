@@ -326,6 +326,11 @@ def resume_or_init(ckpt: Checkpointer, init_state: Any) -> tuple[Any, int]:
     return ckpt.restore(init_state, step), step
 
 
+# How long `checkpointed_train` lets other threads run between the last row
+# and the tear-down: a few polls of a 2 ms watcher, once a run.
+LAST_ROW_YIELD_S = 0.01
+
+
 def checkpointed_train(
     step_fn: Callable[..., tuple[Any, dict]],
     init_state: Any,
@@ -495,6 +500,14 @@ def checkpointed_train(
             with telemetry.span("log", it=it):
                 log_fn(it, metrics)
         previous = metrics
+    # What follows the last row is tear-down: as the callers' frames go, the
+    # step's state and its compiled programs are destroyed, and that holds
+    # the interpreter (0.15-0.3 s at a 340 MB step program on a v5e: PERF.md,
+    # Findings, PR 34). Whoever follows the rows from a thread of this
+    # process (the telemetry sampler, a serving sidecar, the benchmark's row
+    # watcher, which polls every 2 ms) is let in first, so that it sees the
+    # last row when it appears and not when the tear-down ends.
+    time.sleep(LAST_ROW_YIELD_S)
     if ckpt is not None:
         ckpt.wait()
     return state, metrics

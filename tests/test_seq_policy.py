@@ -33,6 +33,8 @@ from actor_critic_tpu.ops import mla_decode, pallas_scan  # noqa: E402
 from benchmark import harness  # noqa: E402
 
 TINY = "impala_joyai_flash_tiny"
+GQA_TINY = "impala_mellum2_tiny"   # window 4 in rows of 16: the rings wrap three times
+BOTH = pytest.mark.parametrize("preset", [TINY, GQA_TINY])
 HP = dict(gamma=1.0, rho_bar=1.0, c_bar=1.0, lam=1.0, value_coef=0.5,
           entropy_coef=0.003)
 
@@ -44,22 +46,41 @@ def toy_blocking(monkeypatch):
     rows the grouped ones in several trips, as at the shipped sizes."""
     monkeypatch.setattr(sp, "MOE_ROWS", 256)
     monkeypatch.setattr(sp, "MOE_DENSE_TOKENS", 32)
+    # Blocks of 6 queries in rows of 16 against a window of 4: three blocks,
+    # the last one short, the second and third cut to their band.
+    monkeypatch.setattr(sp, "ATTN_QUERIES", 6)
 
 
 def _network(seq: sp.SeqPolicyConfig) -> dict:
     """What the benchmark's configuration file would say of `seq`."""
-    return dataclasses.asdict(seq)
+    net = dataclasses.asdict(seq)
+    if seq.layer_types:
+        net["rope_parameters"] = {
+            "sliding_attention": {"rope_type": "default", "rope_theta": seq.rope_theta},
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": seq.rope_theta,
+                "factor": seq.rope_factor,
+                "original_max_position_embeddings":
+                    seq.rope_original_max_position_embeddings,
+                "beta_fast": seq.rope_beta_fast, "beta_slow": seq.rope_beta_slow,
+                "attention_factor": seq.rope_attention_factor}}
+    return net
 
 
-def _reference():
-    return harness.load_module("reference", "impala_joyai_flash")
+def _reference(seq=None):
+    """The plain reference of the configuration whose block `seq` is."""
+    gqa = seq is not None and seq.layer_types
+    return harness.load_module(
+        "reference", "impala_mellum2" if gqa else "impala_joyai_flash")
 
 
-def _setup(sets=None, env_sets=None, seed=0):
-    """The tiny preset; with `env_sets`, over a vocabulary of 64."""
+def _setup(sets=None, env_sets=None, seed=0, preset=TINY):
+    """A tiny preset; with `env_sets`, over a vocabulary of 64."""
     if env_sets is not None:
         env_sets = {"vocab_size": 64, **env_sets}
-    preset = config_mod.resolve(TINY, None, None, sets or {}, env_overrides=env_sets)
+        if preset == GQA_TINY:
+            env_sets.setdefault("prefill_len", min(2, env_sets.get("prompt_min", 2)))
+    preset = config_mod.resolve(preset, None, None, sets or {}, env_overrides=env_sets)
     cfg = preset.config
     env, _ = train.build_env(preset.env, preset.algo, cfg, seed,
                              env_kwargs=preset.env_kwargs)
@@ -130,10 +151,14 @@ def _with_an_eos(env, cfg, policy, behaviour):
     raise AssertionError("no seed gave an EOS mid-row")
 
 
+@BOTH
 @pytest.mark.parametrize("compute_dtype, tol", [("float32", 2e-5), ("bfloat16", 6e-2)])
-def test_step_through_the_cache_equals_unroll_equals_reference(compute_dtype, tol):
+def test_step_through_the_cache_equals_unroll_equals_reference(
+        compute_dtype, tol, preset):
+    """The grouped-query preset's rollout prefills one position, then decodes
+    through rings of 4 slots that wrap three times in the row of 16."""
     env, cfg, policy = _setup({"num_envs": "6", "seq.compute_dtype": compute_dtype},
-                              {"prompt_min": 1, "prompt_max": 6})
+                              {"prompt_min": 1, "prompt_max": 6}, preset=preset)
     behaviour = impala.init_params(env, cfg, jax.random.key(1))
     traj = _with_an_eos(env, cfg, policy, behaviour)
     prompts = np.asarray(traj.obs[..., 2]).sum(axis=0)
@@ -151,7 +176,7 @@ def test_step_through_the_cache_equals_unroll_equals_reference(compute_dtype, to
     # And the plain reference's full forward pass gives the same logits.
     logits, values = sp.logits_and_values(
         behaviour, jnp.swapaxes(traj.obs, 0, 1), cfg.seq)
-    want_logits, want_values = _reference().forward(
+    want_logits, want_values = _reference(cfg.seq).forward(
         behaviour, traj.obs, _network(cfg.seq))
     scale = float(jnp.max(jnp.abs(want_logits)))
     assert float(jnp.max(jnp.abs(jnp.swapaxes(logits, 0, 1) - want_logits))) < tol * scale
@@ -159,9 +184,12 @@ def test_step_through_the_cache_equals_unroll_equals_reference(compute_dtype, to
     assert np.abs(np.asarray(out.value - want_values)).max() < tol
 
 
-def test_loss_and_gradients_agree_with_the_reference(monkeypatch):
+@BOTH
+def test_loss_and_gradients_agree_with_the_reference(monkeypatch, preset):
     monkeypatch.setattr(sp, "MOE_ROWS", 16)
-    env, cfg, policy = _setup({"num_envs": "6"}, {"prompt_min": 1, "prompt_max": 6})
+    monkeypatch.setattr(sp, "GQA_ROWS", 4)  # two trips of the causal pass's attention
+    env, cfg, policy = _setup({"num_envs": "6"}, {"prompt_min": 1, "prompt_max": 6},
+                              preset=preset)
     params = impala.init_params(env, cfg, jax.random.key(0))
     behaviour = impala.init_params(env, cfg, jax.random.key(1))
     rstate, traj = _rollout(env, cfg, policy, behaviour)
@@ -171,9 +199,12 @@ def test_loss_and_gradients_agree_with_the_reference(monkeypatch):
         return impala.impala_loss(p, policy, traj, rstate.obs, cfg, False)
 
     (loss, metrics), grads = jax.value_and_grad(program, has_aux=True)(params)
-    ref = _reference()
+    ref = _reference(cfg.seq)
     net = _network(cfg.seq)
-    want_all = ref.loss_and_targets(params, traj._asdict(), rstate.obs, hp, net)
+    # (The grouped-query reference also wants the seam's second rollout.)
+    want_all = ref.loss_and_targets(
+        params, {**traj._asdict(), "decode_obs": traj.obs, "decode_action": traj.action},
+        rstate.obs, hp, net)
     want = jnp.sum(want_all["loss"])   # the reference gives the loss as its terms
 
     def surrogate(p):
@@ -198,9 +229,10 @@ def test_loss_and_gradients_agree_with_the_reference(monkeypatch):
         scale = max(float(jnp.max(jnp.abs(w))), 1e-6)
         assert float(jnp.max(jnp.abs(g - w))) < 2e-4 * scale + 1e-7, \
             jax.tree_util.keystr(path)
-    # The frozen bias gets no gradient, the router does.
+    # The frozen bias (where the router has one) gets no gradient, the
+    # router does.
     moe = grads["params"]["layer_1"]["moe"]
-    assert float(jnp.max(jnp.abs(moe["bias"]))) == 0.0
+    assert float(jnp.max(jnp.abs(moe.get("bias", 0.0)))) == 0.0
     assert float(jnp.max(jnp.abs(moe["router"]))) > 0.0
 
 
@@ -576,6 +608,277 @@ def test_the_bias_changes_which_experts_are_chosen_and_not_their_weights():
     assert float(jnp.max(jnp.abs(w1.sum(-1) - seq.routed_scaling_factor))) < 1e-5
 
 
+# -- grouped-query layers: two kinds of cache, the prefill, the band ---------
+
+GQA_SEQ = config_mod.PRESETS[GQA_TINY].config.seq
+WINDOW = GQA_SEQ.sliding_window
+
+
+def test_token_task_prefill_is_what_stepping_through_the_prompt_gives():
+    env = make_token_task(vocab_size=32, horizon=12, prompt_min=4, prompt_max=6,
+                          prefill_len=4)
+    assert env.spec.prefill_len == 4
+    state, obs = env.reset(jax.random.key(2))
+    stepped, seen = state, [obs]
+    for _ in range(3):  # the actions are ignored while the prompt lasts
+        out = env.step(stepped, jnp.asarray(9))
+        stepped = out.state
+        seen.append(out.obs)
+    moved, first = env.prefill(state)
+    assert np.array_equal(np.asarray(first), np.stack(seen))
+    assert all(np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(jax.tree.map(np.asarray, moved._replace(key=None))),
+        jax.tree.leaves(jax.tree.map(np.asarray, stepped._replace(key=None)))))
+    with pytest.raises(ValueError, match="at most prompt_min"):
+        make_token_task(vocab_size=32, horizon=12, prompt_min=4, prompt_max=6,
+                        prefill_len=5)
+    assert make_token_task(vocab_size=32, horizon=12, prompt_min=4,
+                           prompt_max=6).prefill is None
+
+
+@pytest.mark.parametrize("P", [0, WINDOW - 1, WINDOW, WINDOW + 3])
+def test_prefill_then_decode_equals_decode_from_zero_equals_reference(P):
+    """The prompt's first P positions in one causal pass that fills both kinds
+    of cache (of a window layer the last `WINDOW` of them), then decoding:
+    the same trajectory as decoding from position 0 (the same keys sample the
+    same tokens), and the reference's full causal pass over those tokens gives
+    the behaviour log-probabilities the rollout recorded."""
+    sets = {"num_envs": "4"}
+    envs = {"prompt_min": 8, "prompt_max": 11}
+    env, cfg, policy = _setup(sets, {**envs, "prefill_len": P}, preset=GQA_TINY)
+    env0, _, policy0 = _setup(sets, {**envs, "prefill_len": 0}, preset=GQA_TINY)
+    assert env.spec.prefill_len == P and policy.prefill is not None
+    behaviour = impala.init_params(env, cfg, jax.random.key(1))
+    rstate, traj = _rollout(env, cfg, policy, behaviour)
+    rstate0, traj0 = _rollout(env0, cfg, policy0, behaviour)
+    assert all(np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(jax.tree.map(np.asarray, rstate.obs)),
+        jax.tree.leaves(jax.tree.map(np.asarray, rstate0.obs))))
+    for name in ("obs", "reward", "done", "terminated", "final_obs"):
+        assert np.array_equal(np.asarray(getattr(traj, name)),
+                              np.asarray(getattr(traj0, name))), name
+    assert traj.reward.shape == (cfg.rollout_steps, 4)
+    live = np.asarray(traj.obs[..., 2]) == 0
+    assert live[:max(P - 1, 0)].sum() == 0 and live.sum() > 0
+    assert np.array_equal(np.asarray(traj.action)[live], np.asarray(traj0.action)[live])
+    assert np.abs(np.asarray(traj.log_prob - traj0.log_prob))[live].max() < 2e-5
+    assert np.abs(np.asarray(traj.value - traj0.value)).max() < 2e-5
+    want_logits, want_values = _reference(cfg.seq).forward(
+        behaviour, traj.obs, _network(cfg.seq))
+    want = jnp.take_along_axis(
+        jax.nn.log_softmax(want_logits), traj.action[..., None], axis=-1)[..., 0]
+    assert np.abs(np.asarray(traj.log_prob - want))[live].max() < 2e-5
+    assert np.abs(np.asarray(traj.value - want_values)).max() < 2e-5
+
+
+def test_a_policy_that_cannot_prefill_is_stepped_through_the_prompt():
+    env, cfg, policy = _setup({"num_envs": "4"}, {"prompt_min": 4, "prefill_len": 3,
+                                                  "prompt_max": 6})
+    assert policy.prefill is None and env.spec.prefill_len == 3
+    _, traj = _rollout(env, cfg, policy, impala.init_params(env, cfg, jax.random.key(1)))
+    assert np.asarray(traj.log_prob)[:3].min() < 0.0  # sampled, not stood in for
+
+
+def _gqa_layer(kind, key=0):
+    params = sp.init_params(jax.random.key(key), GQA_SEQ, 32)
+    index = GQA_SEQ.layer_types.index(kind)
+    return params["params"][f"layer_{index}"]["attn"]
+
+
+@pytest.mark.parametrize("kind", sp.GQA_KINDS)
+def test_a_key_outside_the_band_changes_no_output_of_the_causal_pass(kind):
+    """Position `j`'s hidden state changed beyond recognition: the queries
+    before `j`, and in a window layer those `WINDOW` or more after it, give
+    the same output bit for bit. (Finite garbage: inside a block's slice a
+    masked key's probability is exactly zero, and zero times a finite value
+    is zero.)"""
+    E, T, j = 2, 16, 5
+    p = _gqa_layer(kind)
+    h = jax.random.normal(jax.random.key(3), (E, T, GQA_SEQ.hidden_size))
+    positions = jnp.broadcast_to(jnp.arange(T), (E, T))
+    out, _ = sp.gqa_unroll(p, h, positions, GQA_SEQ, kind)
+    moved, _ = sp.gqa_unroll(p, h.at[:, j].set(1e4), positions, GQA_SEQ, kind)
+    same = np.all(np.asarray(out) == np.asarray(moved), axis=(0, 2))
+    sees = [t >= j and (kind == "full_attention" or t - j < WINDOW) for t in range(T)]
+    assert same.tolist() == [not s for s in sees]
+
+
+@pytest.mark.parametrize("position", [0, WINDOW - 2, WINDOW, 3 * WINDOW + 1])
+@pytest.mark.parametrize("kind", sp.GQA_KINDS)
+def test_the_decode_reads_no_slot_that_is_not_filled(kind, position):
+    """NaN keys and huge values in every slot past the fill (a ring that has
+    wrapped has none): the step's output is finite and the same. A masked
+    score is selected away, so a NaN key never reaches the softmax; a masked
+    slot's probability is exactly zero, and what a cache holds there is zeros
+    or an older position's values, never NaN."""
+    E = 3
+    p = _gqa_layer(kind)
+    slots = sp.window_of(GQA_SEQ, kind, 16)
+    shape = (2, E, GQA_SEQ.num_key_value_heads, slots, GQA_SEQ.head_dim)
+    k, v = (jax.random.normal(jax.random.key(i), shape) for i in (4, 5))
+    unfilled = (jnp.arange(slots) > position)[:, None]
+    dirty = (jnp.where(unfilled, jnp.nan, k), jnp.where(unfilled, 1e30, v))
+    h = jax.random.normal(jax.random.key(6), (E, GQA_SEQ.hidden_size))
+    positions = jnp.full((E,), position)
+    want, (k1, _) = sp.gqa_step(p, h, positions, (k, v), 1, position, GQA_SEQ, kind)
+    got, _ = sp.gqa_step(p, h, positions, dirty, 1, position, GQA_SEQ, kind)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    # The token's key went to slot `position mod slots` of layer 1 alone.
+    changed = np.any(np.asarray(k1 != k), axis=(1, 2, 4))
+    assert changed[0].sum() == 0 and changed[1].tolist() == [
+        s == position % slots for s in range(slots)]
+
+
+def test_each_kind_of_layer_has_its_cache_at_its_own_size():
+    cache = sp.init_cache(GQA_SEQ, 5, 16)
+    lead = (5, GQA_SEQ.num_key_value_heads)
+    assert [a.shape for a in cache["sliding_attention"]] == [
+        (3, *lead, WINDOW, GQA_SEQ.head_dim)] * 2
+    assert [a.shape for a in cache["full_attention"]] == [
+        (1, *lead, 16, GQA_SEQ.head_dim)] * 2
+    # 3 x 4 + 16 of the 4 x 16 slots a row that a uniform cache would keep.
+    slots = sum(a.shape[0] * a.shape[3] for a, _ in cache.values())
+    assert slots == 28
+
+
+def test_yarn_frequencies_of_the_published_keys_against_a_hand_count():
+    """`rope_parameters.full_attention` of the source: theta 5e5, factor 16,
+    original context 8,192, beta_fast 32, beta_slow 1, head size 128. The pair
+    that turns n times over 8,192 positions is 128 ln(8192 / (2 pi n)) / (2 ln
+    5e5): 18.08 for n = 32, 34.98 for n = 1, so pairs up to 18 keep their
+    frequency, pairs from 35 on take a sixteenth, and pair 19 is 1/17 of the
+    way between."""
+    seq = config_mod.PRESETS["impala_mellum2"].config.seq
+    inv, factor = sp.rope_frequencies(seq, "full_attention")
+    plain, one = sp.rope_frequencies(seq, "sliding_attention")
+    base = 5e5 ** (-np.arange(64) / 64.0)
+    assert one == 1.0 and np.allclose(plain, base, rtol=1e-6)
+    assert factor == 1.2772588722239782 == pytest.approx(0.1 * np.log(16.0) + 1.0)
+    assert np.allclose(inv[:19], base[:19], rtol=1e-6)
+    assert np.allclose(inv[35:], base[35:] / 16.0, rtol=1e-6)
+    assert inv[19] == pytest.approx(base[19] * (16 / 17) + base[19] / 16 / 17, rel=1e-6)
+    assert inv[26] == pytest.approx(base[26] * (9 / 17) + base[26] / 16 * (8 / 17), rel=1e-6)
+    # The reference writes the same arithmetic out for itself.
+    ref_inv, ref_factor = _reference(seq).yarn_frequencies(
+        128, _network(seq)["rope_parameters"]["full_attention"])
+    assert np.array_equal(ref_inv, inv) and ref_factor == factor
+
+
+def test_the_softmax_router_is_the_references_ties_included():
+    """Softmax over all the logits, top-k by probability, renormalised over
+    the chosen, no bias, no scaling; two experts with the same router column
+    tie at every token, and the lower index wins on both sides."""
+    seq = dataclasses.replace(GQA_SEQ, n_routed_experts=64, experts_held=16,
+                              num_experts_per_tok=8)
+    router = jax.random.normal(jax.random.key(7), (seq.hidden_size, 64))
+    router = router.at[:, 9].set(router[:, 3]).at[:, 40].set(router[:, 41])
+    h = jax.random.normal(jax.random.key(8), (256, seq.hidden_size))
+    idx, w = sp.route({"router": router}, h, seq)
+    want_idx, want_w = _reference(seq).route(router, h, 8)
+    assert np.array_equal(np.asarray(idx), np.asarray(want_idx))
+    assert np.abs(np.asarray(w - want_w)).max() < 1e-6
+    assert np.abs(np.asarray(w).sum(-1) - 1.0).max() < 1e-6
+    both = (np.asarray(idx) == 3).any(-1) & (np.asarray(idx) == 9).any(-1)
+    first = np.argmax(np.asarray(idx) == 3, -1) < np.argmax(np.asarray(idx) == 9, -1)
+    assert both.sum() > 10 and first[both].all()
+    prob = jax.nn.softmax(h @ router)
+    assert np.allclose(np.asarray(w), np.asarray(
+        jnp.take_along_axis(prob, idx, -1) / jnp.take_along_axis(prob, idx, -1).sum(
+            -1, keepdims=True)), atol=1e-6)
+
+
+@pytest.mark.parametrize("dense_tokens", [0, 4096])
+def test_the_four_shares_of_a_softmax_routed_layer_add_up_to_the_uncut_reference(
+        dense_tokens, monkeypatch):
+    """64 experts, top-8, 16 held at offsets 0, 16, 32, 48, no shared expert:
+    the four chips' parts sum to the reference's layer with every expert."""
+    monkeypatch.setattr(sp, "MOE_DENSE_TOKENS", dense_tokens)
+    seq = dataclasses.replace(GQA_SEQ, n_routed_experts=64, experts_held=16,
+                              num_experts_per_tok=8)
+    whole = dataclasses.replace(seq, experts_held=64)
+    full = sp.init_params(jax.random.key(0), whole, 32)["params"]["layer_1"]["moe"]
+    assert set(full) == {"router", "experts"}
+    h = jax.random.normal(jax.random.key(5), (48, seq.hidden_size))
+    ref = _reference(seq)
+    want = ref.moe(full, h, {**_network(whole), "expert_offset": 0})
+    total, landed = 0.0, 0.0
+    for offset in (0, 16, 32, 48):
+        cut = dataclasses.replace(seq, expert_offset=offset)
+        part = {**full, "experts": jax.tree.map(
+            lambda a: a[offset:offset + 16], full["experts"])}
+        y, stats = sp.moe(part, h, cut)
+        assert float(jnp.max(jnp.abs(y - ref.moe(part, h, _network(cut))))) < 1e-5
+        total, landed = total + y, landed + float(stats["routed_here_frac"])
+    assert landed == pytest.approx(1.0)
+    assert float(jnp.max(jnp.abs(total - want))) < 2e-5
+
+
+def test_the_grouped_query_preset_is_the_configuration_the_benchmark_states():
+    with open(os.path.join(ROOT, "benchmark/configs/impala_mellum2.json")) as fh:
+        cfg = json.load(fh)
+    preset = config_mod.PRESETS["impala_mellum2"]
+    seq, net = preset.config.seq, cfg["network"]
+    for key, value in net.items():
+        if hasattr(seq, key):
+            got = getattr(seq, key)
+            assert (list(got) if isinstance(got, tuple) else got) == value, key
+    assert net["layer_types"] == cfg["layer_types"][:4] == list(seq.layer_types)
+    yarn = cfg["rope_parameters"]["full_attention"]
+    assert net["rope_parameters"] == cfg["rope_parameters"]
+    assert (yarn["factor"], yarn["original_max_position_embeddings"], yarn["beta_fast"],
+            yarn["beta_slow"], yarn["attention_factor"], yarn["rope_theta"]) == (
+        seq.rope_factor, seq.rope_original_max_position_embeddings, seq.rope_beta_fast,
+        seq.rope_beta_slow, seq.rope_attention_factor, seq.rope_theta)
+    assert cfg["rope_parameters"]["sliding_attention"]["rope_theta"] == seq.rope_theta
+    for key in ("hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim",
+                "sliding_window", "moe_intermediate_size", "num_experts_per_tok",
+                "num_hidden_layers", "rms_norm_eps"):
+        assert cfg[key] == net[key] == getattr(seq, key), key
+    assert cfg["num_experts"] == seq.experts_held == 16
+    assert cfg["published"]["num_experts"] == seq.n_routed_experts == 64
+    assert (seq.scoring_func, seq.n_shared_experts, seq.first_k_dense_replace,
+            seq.routed_scaling_factor) == ("softmax", 0, 0, 1.0)
+    assert cfg["norm_topk_prob"] is True and set(cfg["mlp_layer_types"]) == {"sparse"}
+    assert net["vocab_size"] == preset.env_kwargs["vocab_size"] == cfg["vocab_size"]
+    algo = cfg["algorithm"]
+    for key in ("num_envs", "rollout_steps", "gamma", "lam", "rho_bar", "c_bar",
+                "value_coef", "entropy_coef", "lr", "actor_refresh_every"):
+        assert algo[key] == getattr(preset.config, key), key
+    assert preset.env_kwargs["horizon"] == preset.config.rollout_steps == 4096
+    assert preset.env_kwargs["prefill_len"] == preset.env_kwargs["prompt_min"] == 3072
+    shapes = jax.eval_shape(
+        lambda: sp.init_params(jax.random.key(0), seq, net["vocab_size"]))
+    count = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    assert count == 595_155_457 and "595,155,457" in cfg["deployment"]["parameters_here"]
+
+
+# sha256 of `jit(train_step).lower(abstract state).as_text()` on the CPU, at
+# the commit before grouped-query layers and the prefill came (PERF.md,
+# Findings): what the standing cells run has not changed by an operation.
+PARENT_STEPS = {
+    ("impala_pong", 64): "219e8237219554c914b2017673191b49b87a929f91be90f3cabf9529cb73105f",
+    ("impala_pong", 4096): "4d1d084ed5f57706826f78d4e87b8cc5435bcc197d141e7606556de295f1ee23",
+    ("impala_joyai_flash", 64): "4cf61bebebfc0114bc90a009cb5824840a4dc0273b8e10095bce0a47167a2f4f",
+}
+
+
+@pytest.mark.parametrize("name, num_envs", list(PARENT_STEPS))
+def test_the_standing_cells_step_lowers_to_the_parents_text(
+        name, num_envs, monkeypatch):
+    import hashlib
+
+    monkeypatch.undo()  # the shipped blocking, not `toy_blocking`'s
+    preset = config_mod.resolve(name, None, None, {"num_envs": str(num_envs)})
+    cfg = preset.config
+    env, _ = train.build_env(preset.env, preset.algo, cfg, 0,
+                             env_kwargs=preset.env_kwargs)
+    state = jax.eval_shape(lambda: impala.init_state(env, cfg, jax.random.key(0)))
+    text = jax.jit(impala.make_train_step(env, cfg), donate_argnums=0).lower(
+        state).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_STEPS[name, num_envs]
+
+
 # -- the preset, the seam, the eval ------------------------------------------
 
 def test_the_preset_refuses_an_episode_that_is_not_one_unroll():
@@ -654,8 +957,9 @@ def test_greedy_eval_threads_the_cache():
     assert got == pytest.approx(float(ret.mean()), abs=1e-6)
 
 
-def test_the_tiny_presets_return_rises_on_the_copy_task():
-    env, cfg, _ = _setup()
+@BOTH
+def test_the_tiny_presets_return_rises_on_the_copy_task(preset):
+    env, cfg, _ = _setup(preset=preset)
     state = impala.init_state(env, cfg, jax.random.key(0))
     step = jax.jit(impala.make_train_step(env, cfg), donate_argnums=0)
     returns = []
