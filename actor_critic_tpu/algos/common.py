@@ -80,13 +80,17 @@ class Policy(NamedTuple):
     last step. A policy that can take the part of an episode no action
     decides (`EnvSpec.prefill_len` observations: a prompt) in one pass
     offers `prefill(params, obs [P, E, ...], carry) -> (dist of the last of
-    them, value [P, E], carry)`; one without it is stepped through them."""
+    them, value [P, E], carry)`; one without it is stepped through them. A
+    policy that counts something as it acts offers `rollout_metrics(carry
+    after the last step) -> dict` of scalars for the rows
+    (`rollout_scan(..., policy_metrics=True)`)."""
 
     init_carry: Callable[[int], Any]
     step: Callable[[Any, jax.Array, Any], tuple[Any, jax.Array, Any]]
     unroll: Callable[[Any, "Transition"], Unrolled]
     bootstrap: Callable[[Any, jax.Array], jax.Array]
     prefill: Optional[Callable[[Any, jax.Array, Any], tuple[Any, jax.Array, Any]]] = None
+    rollout_metrics: Optional[Callable[[Any], dict]] = None
 
 
 def feedforward_policy(
@@ -140,6 +144,7 @@ def rollout_scan(
     rstate: RolloutState,
     key: jax.Array,
     num_steps: int,
+    policy_metrics: bool = False,
 ) -> tuple[RolloutState, Transition]:
     """Collect `num_steps` of experience from the vmapped env batch.
 
@@ -151,9 +156,17 @@ def rollout_scan(
     and the policy can take them in one pass (`Policy.prefill`), the rollout
     starts with that pass: `rstate` must then stand at a reset, as it does
     where an episode is exactly one rollout. Returns time-major Transition
-    with arrays [T, E, ...].
+    with arrays [T, E, ...]; with `policy_metrics` a third value, what the
+    policy counted over the rollout (`Policy.rollout_metrics` of the carry
+    after the last step; `{}` for a policy that counts nothing).
     """
     policy = as_policy(policy)
+
+    def result(rstate, policy_carry, traj):
+        if not policy_metrics:
+            return rstate, traj
+        counted = policy.rollout_metrics
+        return rstate, traj, counted(policy_carry) if counted else {}
 
     def act(carry: RolloutState, dist, value, step_key: jax.Array):
         """Sample a row's action, step its env: (next state, the transition)."""
@@ -189,9 +202,9 @@ def rollout_scan(
         can_prefill = policy.prefill is not None and env.prefill is not None
         P = env.spec.prefill_len if can_prefill else 0
         if not P:
-            (rstate, _), traj = jax.lax.scan(
+            (rstate, policy_carry), traj = jax.lax.scan(
                 step_fn, (rstate, policy_carry), step_keys)
-            return rstate, traj
+            return result(rstate, policy_carry, traj)
         # The env gives the first P observations of every row at once (no
         # action decides them), one pass of the policy takes them, and the
         # action on the last of them is the first one sampled. The first
@@ -212,11 +225,11 @@ def rollout_scan(
             log_prob=zeros, value=values[:-1], reward=zeros, done=zeros,
             terminated=zeros, final_obs=obs[1:],
         )
-        (rstate, _), traj = jax.lax.scan(
+        (rstate, policy_carry), traj = jax.lax.scan(
             step_fn, (lead, policy_carry), step_keys[P:])
         traj = jax.tree.map(
             lambda a, b, c: jnp.concatenate([a, b[None], c]), prompt, trans, traj)
-        return rstate, traj
+        return result(rstate, policy_carry, traj)
 
 
 class OffPolicyTransition(NamedTuple):

@@ -272,9 +272,9 @@ def make_train_step(
         key, rkey = jax.random.split(state.key)
 
         # Actors run the STALE params; behaviour log-probs are recorded.
-        new_rollout, traj = rollout_scan(
+        new_rollout, traj, counted = rollout_scan(
             env, policy, state.actor_params, state.rollout, rkey,
-            cfg.rollout_steps,
+            cfg.rollout_steps, policy_metrics=True,
         )
 
         grad_fn = jax.value_and_grad(impala_loss, has_aux=True)
@@ -282,6 +282,7 @@ def make_train_step(
             state.params, policy, traj, new_rollout.obs, cfg,
             env.spec.can_truncate,
         )
+        metrics = {**metrics, **counted}
         with jax.named_scope("optimizer"):
             grads = pmesh.pmean_tree(grads, axis_name)
             updates, new_opt_state = opt.update(

@@ -46,10 +46,16 @@ configuration runs the same code.
   is read from the routing: balanced routing pays for one trip, the worst
   case (every token to held experts only) for all of them.
   `moe_dropped` counts what the trips missed. A pass of at most
-  `MOE_DENSE_TOKENS` tokens (a decode step: 64 tokens, 2 an expert) instead
-  runs every held expert on every token as one batched matmul and weights the
-  results: the same sum, the held experts' weights read once a step, and a
-  time that does not move with the routing.
+  `MOE_DENSE_TOKENS` tokens (a decode step) instead runs held experts on
+  every token and weights the results: the same sum. Where it expects fewer
+  than `MOE_KERNEL_ASSIGNMENTS` assignments an expert (8 tokens x 8 of 64: 1
+  an expert) it runs only the experts some token chose (`ops/moe_decode.py`:
+  one kernel on a TPU where the experts tile): the chosen experts' weights
+  read once a step, so the time follows how many were chosen, and
+  `decode_experts_read_frac` in the rows says how many. Elsewhere (64 tokens
+  x 8 of 256: 2 an expert; off a TPU) it runs every held expert as batched
+  matmuls: every held expert's weights read once a step, a time that does
+  not move with the routing, `decode_experts_read_frac` 1.
 - Heads: logits over the vocabulary slice the env draws its ids from, and a
   scalar value on the final norm's output.
 
@@ -103,6 +109,7 @@ import numpy as np
 
 from actor_critic_tpu.models.distributions import Categorical
 from actor_critic_tpu.ops.mla_decode import mla_decode_auto
+from actor_critic_tpu.ops import moe_decode
 
 
 # The kinds of grouped-query layer, as the source's `layer_types` names them.
@@ -209,9 +216,20 @@ ATTN_QUERIES = 512  # queries a block
 MLP_ROWS = 8192     # token rows a trip of the dense MLP and the shared expert
 HEAD_ROWS = 4096    # token rows a trip of the lm_head
 MOE_ROWS = 24576    # held assignments a trip of the grouped matmuls
-# Up to this many tokens a pass (a decode step), every held expert runs on
-# every token: the weights are read once whatever the routing.
+# Up to this many tokens a pass (a decode step), each held expert runs on
+# every token and its weights are read once.
 MOE_DENSE_TOKENS = 128
+# Of those passes, the ones that expect fewer than this many assignments an
+# expert (`N x num_experts_per_tok / n_routed_experts`: 1.0 at Mellum's 8
+# rows, 2.0 at JoyAI's 64) read only the experts some token chose
+# (`ops/moe_decode.py`); the others run the batched matmuls over every held
+# expert. What decided it (my chip runs, PR 35, six seeds a side): at 1.0 a
+# third of the held experts is read by no step and the cell gained 17.6%
+# (9,790 -> 11,514 steps/s, spread 0.38%); at 2.0 four fifths are read, the
+# kernel gave +0.6% (15,857 -> 15,956) and a time that follows the seed's
+# routing (`decode_experts_read_frac` 0.775-0.827): a six-seed spread of
+# 0.58% where the batched matmuls have 0.11%, over half the benchmark's bound.
+MOE_KERNEL_ASSIGNMENTS = 2.0
 
 
 # -- parameters -----------------------------------------------------------
@@ -628,21 +646,21 @@ def _held_experts(cfg: SeqPolicyConfig, R: int):
     return run
 
 
-def _held_experts_dense(experts, h, weights_here, cd):
-    """`sum_e weights_here[n, e] E_e(h[n])` with every held expert on every
-    token: for the few tokens of a decode step."""
-    with jax.named_scope("moe_experts"):
-        act = jax.nn.silu(_einsum("nh,ehw->enw", h, experts["w_gate"], cd)) \
-            * _einsum("nh,ehw->enw", h, experts["w_up"], cd)
-        out = _einsum("enw,ewh->enh", act, experts["w_down"], cd)
-    with jax.named_scope("moe_route"):
-        return jnp.einsum("enh,ne->nh", out, weights_here)
+def reads_chosen_only(cfg: SeqPolicyConfig, N: int) -> bool:
+    """Whether a pass of `N` tokens fetches only the held experts some token
+    chose: few enough tokens, few enough assignments an expert, and the
+    kernel engages (a TPU, experts that tile). Static: read from shapes."""
+    expected = N * cfg.num_experts_per_tok / cfg.n_routed_experts
+    return (N <= MOE_DENSE_TOKENS and expected < MOE_KERNEL_ASSIGNMENTS
+            and moe_decode.engages(cfg.hidden_size, cfg.moe_intermediate_size))
 
 
 def moe(p, h, cfg: SeqPolicyConfig):
     """The expert layer's part this chip computes for tokens `h [N, H]`:
     `sum_i w_i E_i(x)` over the chosen experts held here, plus the shared
-    expert where the layer has one. Returns (y [N, H], stats)."""
+    expert where the layer has one. Returns (y [N, H], stats, the share of
+    the held experts whose weights a pass that `reads_chosen_only` read: None
+    for every other pass)."""
     cd = jnp.dtype(cfg.compute_dtype)
     N, _ = h.shape
     held = cfg.experts_held
@@ -662,11 +680,17 @@ def moe(p, h, cfg: SeqPolicyConfig):
         with jax.named_scope("moe_route"):
             weights_here = jnp.zeros((N, held + 1), jnp.float32).at[
                 jnp.arange(N)[:, None], group.reshape(idx.shape)].add(weights)
-        y = _held_experts_dense(p["experts"], h, weights_here[:, :held], cd)
+        weights_here = weights_here[:, :held]
+        if reads_chosen_only(cfg, N):
+            read = jnp.sum(sizes > 0).astype(jnp.float32) / held
+            y = moe_decode.moe_decode(p["experts"], h, weights_here, sizes, cd)
+        else:
+            y, read = moe_decode.reference(p["experts"], h, weights_here, cd), None
         done = landed
     else:
         y, done = _held_experts(cfg, R)(
             p["experts"], h, weights.reshape(A), (order, ends, sizes, landed))
+        read = None
     if "shared" in p:
         with jax.named_scope("moe_shared"):
             y = y + _map_rows(lambda x: _swiglu(p["shared"], x, cd), MLP_ROWS, h)
@@ -675,15 +699,16 @@ def moe(p, h, cfg: SeqPolicyConfig):
         "routed_here_frac": landed.astype(jnp.float32) / A,
         "expert_load_max_over_mean": jnp.max(sizes).astype(jnp.float32) / mean_load,
         "moe_dropped": (landed - done).astype(jnp.float32),
-    }
+    }, read
 
 
 def _ffn(layer, h, cfg: SeqPolicyConfig):
-    """The layer's FFN on `h [N, H]`: (y, the expert layer's stats or None)."""
+    """The layer's FFN on `h [N, H]`: `moe`'s three for an expert layer, (y,
+    None, None) for a dense one."""
     if "moe" in layer:
         return moe(layer["moe"], h, cfg)
     cd = jnp.dtype(cfg.compute_dtype)
-    return _map_rows(lambda x: _swiglu(layer["mlp"], x, cd), MLP_ROWS, h), None
+    return _map_rows(lambda x: _swiglu(layer["mlp"], x, cd), MLP_ROWS, h), None, None
 
 
 def _layers(params):
@@ -719,12 +744,15 @@ def init_cache(cfg: SeqPolicyConfig, num_envs: int, horizon: int):
 
 def step(params, obs, cache, cfg: SeqPolicyConfig):
     """Decode one token a row: `obs [E, 3]` -> (logits [E, V], value [E],
-    cache)."""
+    cache, the share of the held experts' weights this step read as a mean
+    over the expert layers: None where it read them all, `reads_chosen_only`
+    false)."""
     cd = jnp.dtype(cfg.compute_dtype)
     p, layers = _layers(params)
     tokens, positions = obs[:, 0], obs[:, 1]
     slot = positions[0]
     x = jnp.take(p["embed"], tokens, axis=0)
+    reads = []
     for i, layer in enumerate(layers):
         kind = cfg.attention(i)
         with jax.named_scope(SCOPE_OF[kind]):
@@ -736,12 +764,15 @@ def step(params, obs, cache, cfg: SeqPolicyConfig):
                                  cfg.cache_index(i), slot, cfg, kind)
                 cache = {**cache, kind: kv}
             x = x + a
-        y, _ = _ffn(layer, _rms(x, layer["ffn_norm"], cfg.rms_norm_eps), cfg)
+        y, _, read = _ffn(layer, _rms(x, layer["ffn_norm"], cfg.rms_norm_eps), cfg)
         x = x + y
+        if read is not None:
+            reads.append(read)
     h = _rms(x, p["final_norm"], cfg.rms_norm_eps)
     with jax.named_scope("lm_head"):
         logits = _mm(h, p["lm_head"], cd)
-    return logits, _value(p, h), cache
+    read = jnp.mean(jnp.stack(reads)) if reads else None
+    return logits, _value(p, h), cache, read
 
 
 def _value(p, h):
@@ -778,7 +809,7 @@ def trunk(params, obs, cfg: SeqPolicyConfig, cache=None):
 
     def ffn(layer, x):
         h = _rms(x, layer["ffn_norm"], cfg.rms_norm_eps).reshape(E * T, -1)
-        y, stats = _ffn(layer, h, cfg)
+        y, stats, _ = _ffn(layer, h, cfg)
         return x + y.reshape(x.shape), stats
 
     stats = []
@@ -847,9 +878,20 @@ def make_policy(spec, cfg: SeqPolicyConfig, horizon: int):
             f"--env-set horizon={horizon} (a carry across unrolls is not built)"
         )
 
-    def policy_step(params, obs, cache):
-        logits, value, cache = step(params, obs, cache, cfg)
-        return Categorical(logits), value, cache
+    # The carry: the cache and, where a decode step reads only the chosen
+    # experts, what the steps read of the held experts' weights (the sum of
+    # `step`'s shares, the steps counted); () where every step reads all.
+    def init_carry(num_envs):
+        counted = (jnp.float32(0.0), jnp.float32(0.0)) \
+            if reads_chosen_only(cfg, num_envs) else ()
+        return init_cache(cfg, num_envs, horizon), counted
+
+    def policy_step(params, obs, carry):
+        cache, counted = carry
+        logits, value, cache, read = step(params, obs, cache, cfg)
+        if counted:
+            counted = (counted[0] + read, counted[1] + 1.0)
+        return Categorical(logits), value, (cache, counted)
 
     def policy_unroll(params, traj):
         obs = jnp.swapaxes(traj.obs, 0, 1)
@@ -859,12 +901,18 @@ def make_policy(spec, cfg: SeqPolicyConfig, horizon: int):
         stats = {**stats, "response_frac": jnp.mean(mask), **shape_stats}
         return Unrolled(log_prob.T, entropy.T, value.T, mask, stats)
 
-    def policy_prefill(params, obs, cache):
-        h, _, cache = trunk(params, jnp.swapaxes(obs, 0, 1), cfg, cache)
+    def policy_prefill(params, obs, carry):
+        h, _, cache = trunk(params, jnp.swapaxes(obs, 0, 1), cfg, carry[0])
         with jax.named_scope("lm_head"):
             logits = _mm(h[:, -1], params["params"]["lm_head"],
                          jnp.dtype(cfg.compute_dtype))
-        return Categorical(logits), _value(params["params"], h).T, cache
+        return Categorical(logits), _value(params["params"], h).T, (cache, carry[1])
+
+    def rollout_metrics(carry):
+        if not carry[1]:
+            return {"decode_experts_read_frac": jnp.float32(1.0)}
+        read, steps = carry[1]
+        return {"decode_experts_read_frac": read / jnp.maximum(steps, 1.0)}
 
     # What the shape of the traffic alone decides, as counters of the rows:
     # the key positions the window layers attend over what full causal layers
@@ -880,7 +928,7 @@ def make_policy(spec, cfg: SeqPolicyConfig, horizon: int):
             "prefill_frac": jnp.float32(spec.prefill_len / horizon),
         }
     return Policy(
-        init_carry=lambda num_envs: init_cache(cfg, num_envs, horizon),
+        init_carry=init_carry,
         step=policy_step,
         unroll=policy_unroll,
         # Every episode terminates at the unroll's last step, so V-trace
@@ -889,4 +937,8 @@ def make_policy(spec, cfg: SeqPolicyConfig, horizon: int):
         # The grouped-query layers' caches can be filled by one causal pass;
         # the latent cache is decoded into from position 0.
         prefill=policy_prefill if cfg.layer_types else None,
+        # The actor's own routing, counted as it decodes: exact, where the
+        # update's re-evaluation of the trajectory routes under newer weights.
+        rollout_metrics=rollout_metrics
+        if cfg.num_hidden_layers > cfg.first_k_dense_replace else None,
     )

@@ -113,10 +113,12 @@ def parent_impala_loss(params, apply_fn, traj, bootstrap_obs, cfg,
 
 def _as_parent(mod, env, cfg, monkeypatch):
     """`mod.make_train_step` with the parent's bodies swapped in."""
-    def rollout(env_, policy, *rest):
+    def rollout(env_, policy, *rest, policy_metrics=False):
         apply_fn = parent_impala_loss.apply_fn \
             if isinstance(policy, common.Policy) else policy
-        return parent_rollout_scan(env_, apply_fn, *rest)
+        out = parent_rollout_scan(env_, apply_fn, *rest)
+        # A feed-forward policy counts nothing over a rollout.
+        return (*out, {}) if policy_metrics else out
 
     monkeypatch.setattr(mod, "rollout_scan", rollout)
     if mod is impala:

@@ -452,8 +452,16 @@ def test_the_rollout_compiled_for_a_v5e_leaves_the_cache_in_hbm(v5e, monkeypatch
               if kernel in c]
     assert len(bodies) == 1, "the decode kernels are in one computation: the scan's body"
     body = bodies[0].splitlines()
+    # One attention kernel a layer and nothing else: at 64 rows a step (2
+    # assignments an expert) the experts run as batched matmuls.
     assert sum(kernel in line for line in body) == layers
     assert all("mla_decode" in line for line in body if kernel in line)
+    # The experts' weights reach them as the `compute_dtype` copies made once
+    # outside the scan: no float32 expert matrix is read a step.
+    held, H, W = (cfg.seq.experts_held, cfg.seq.hidden_size,
+                  cfg.seq.moe_intermediate_size)
+    wide = re.compile(rf"f32\[{held},(?:{H},{W}|{W},{H})\]")
+    assert [line.strip()[:200] for line in body if wide.search(line)] == []
     dtype = {"bfloat16": "bf16", "float32": "f32"}[cfg.seq.compute_dtype]
     T, widths = cfg.rollout_steps, f"{cfg.seq.kv_lora_rank}|{cfg.seq.qk_rope_head_dim}"
     cache_sized = re.compile(rf"{dtype}\[(?:\d+,)*{T},(?:{widths})\]\{{[^}}]*\}}")
@@ -465,6 +473,66 @@ def test_the_rollout_compiled_for_a_v5e_leaves_the_cache_in_hbm(v5e, monkeypatch
     carried = [shape for shape in cache_sized.findall(root)
                if shape.startswith(f"{dtype}[{layers},{cfg.num_envs},")]
     assert len(carried) == 2 and not any("S(1)" in shape for shape in carried), carried
+
+
+def test_the_long_context_rollout_compiled_for_a_v5e_reads_its_experts_by_the_kernel(
+        v5e, monkeypatch):
+    """`rollout_scan` at the `impala_mellum2` preset as shipped, compiled for
+    the described chip. The scan's body calls the experts' kernel once a
+    layer and holds no matmul over the held experts' stacked weights, which
+    reach the kernel as `compute_dtype` copies made outside the scan; and
+    the compiler moves neither kind of cache asynchronously (with no cost
+    estimate on the kernel it staged the full layer's cache through VMEM
+    every step, and the step waited on it: PERF.md, Findings, PR 35)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.undo()  # the shipped blocking, not `toy_blocking`'s
+    monkeypatch.setattr(pallas_scan, "on_tpu", lambda: True)
+    preset = config_mod.resolve("impala_mellum2", None, None, {})
+    cfg = preset.config
+    env, _ = train.build_env(preset.env, preset.algo, cfg, 0,
+                             env_kwargs=preset.env_kwargs)
+    policy = impala.make_policy(env, cfg)
+    key = jax.random.key(0)
+    on_chip = lambda make: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e),
+        jax.eval_shape(make))
+    rollout = jax.jit(lambda p, r, k: common.rollout_scan(
+        env, policy, p, r, k, cfg.rollout_steps))
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = rollout.lower(
+            on_chip(lambda: impala.init_params(env, cfg, key)),
+            on_chip(lambda: common.init_rollout(env, key, cfg.num_envs)),
+            on_chip(lambda: key)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    kernel = 'custom_call_target="tpu_custom_call"'
+    # (The prefill's grouped matmuls are the compiler's own kernels, in the
+    # loops of its trips.)
+    bodies = [c for c in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+              if kernel in c and "moe_decode" in c]
+    assert len(bodies) == 1, "the decode's kernels are in one computation: the scan's body"
+    body = bodies[0].splitlines()
+    calls = [line for line in body if kernel in line]
+    seq = cfg.seq
+    assert len(calls) == seq.num_hidden_layers and all("moe_decode" in c for c in calls)
+    held, H, W = seq.experts_held, seq.hidden_size, seq.moe_intermediate_size
+    stacked = re.compile(rf"(bf16|f32)\[{held},(?:{H},{W}|{W},{H})\]")
+    carries = re.compile(r" (?:parameter|get-tuple-element|tuple)\(")
+    users = [line.strip()[:160] for line in body[1:] if stacked.search(line)
+             and kernel not in line and not carries.search(line)]
+    assert users == [], "only the kernel reads the held experts' stacked weights"
+    assert not any(m.group(1) == "f32" for line in body
+                   for m in stacked.finditer(line))
+    E, kv, d = cfg.num_envs, seq.num_key_value_heads, seq.head_dim
+    caches = re.compile(rf"bf16\[\d+,{E},{kv},(?:{seq.sliding_window}|{cfg.rollout_steps}),{d}\]")
+    moved = [line.strip()[:200] for line in body
+             if re.search(r" (?:copy|slice)-start\(", line) and caches.search(line)]
+    assert moved == []
 
 
 # -- the expert layer and the chip's share ----------------------------------
@@ -498,7 +566,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_reference(
         cut = dataclasses.replace(seq, expert_offset=i * seq.experts_held)
         lo, hi = cut.expert_offset, cut.expert_offset + cut.experts_held
         part = {**full, "experts": jax.tree.map(lambda a: a[lo:hi], full["experts"])}
-        y, stats = sp.moe(part, h, cut)
+        y, stats, _ = sp.moe(part, h, cut)
         # Each share computes the shared expert whole; count it once.
         total = total + (y - shared)
         landed += float(stats["routed_here_frac"])
@@ -524,7 +592,7 @@ def test_every_token_to_one_held_expert_drops_nothing(
     bias = jnp.zeros_like(layer["bias"]).at[jnp.array([2, 9])].set(10.0)
     layer = {**layer, "bias": bias}
     h = jax.random.normal(jax.random.key(6), (40, seq.hidden_size))
-    y, stats = jax.jit(lambda p, h: sp.moe(p, h, seq))(layer, h)
+    y, stats, _ = jax.jit(lambda p, h: sp.moe(p, h, seq))(layer, h)
     assert float(stats["moe_dropped"]) == 0.0
     assert float(stats["routed_here_frac"]) == pytest.approx(0.5)
     assert float(stats["expert_load_max_over_mean"]) == pytest.approx(seq.experts_held)
@@ -807,7 +875,7 @@ def test_the_four_shares_of_a_softmax_routed_layer_add_up_to_the_uncut_reference
         cut = dataclasses.replace(seq, expert_offset=offset)
         part = {**full, "experts": jax.tree.map(
             lambda a: a[offset:offset + 16], full["experts"])}
-        y, stats = sp.moe(part, h, cut)
+        y, stats, _ = sp.moe(part, h, cut)
         assert float(jnp.max(jnp.abs(y - ref.moe(part, h, _network(cut))))) < 1e-5
         total, landed = total + y, landed + float(stats["routed_here_frac"])
     assert landed == pytest.approx(1.0)
@@ -855,11 +923,15 @@ def test_the_grouped_query_preset_is_the_configuration_the_benchmark_states():
 
 # sha256 of `jit(train_step).lower(abstract state).as_text()` on the CPU, at
 # the commit before grouped-query layers and the prefill came (PERF.md,
-# Findings): what the standing cells run has not changed by an operation.
+# Findings): what the pixel cells run has not changed by an operation since.
+# The token cell's is PR 35's: its step gained one output, the constant 1.0
+# of `decode_experts_read_frac` (its decode runs the batched matmuls over
+# every held expert: `MOE_KERNEL_ASSIGNMENTS`), and is otherwise the
+# parent's `4cf61beb...` operation for operation; nothing else may move it.
 PARENT_STEPS = {
     ("impala_pong", 64): "219e8237219554c914b2017673191b49b87a929f91be90f3cabf9529cb73105f",
     ("impala_pong", 4096): "4d1d084ed5f57706826f78d4e87b8cc5435bcc197d141e7606556de295f1ee23",
-    ("impala_joyai_flash", 64): "4cf61bebebfc0114bc90a009cb5824840a4dc0273b8e10095bce0a47167a2f4f",
+    ("impala_joyai_flash", 64): "03b2be6673c3e12ed4f3525fd129330562ad5b8a609be6ca3572768660d3ebdb",
 }
 
 
