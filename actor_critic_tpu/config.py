@@ -317,7 +317,7 @@ PRESETS: dict[str, Preset] = {
                 num_key_value_heads=4, head_dim=128, rope_theta=10000000.0,
                 sa_config=SparseAttention(
                     indexer_head_dim=64, indexer_num_heads=16,
-                    q_chunk_size=512, topk=2048),
+                    q_chunk_size=512, kv_chunk_size=512, topk=2048),
                 experts_held=16, expert_offset=0,
             ),
         ),
@@ -346,7 +346,7 @@ PRESETS: dict[str, Preset] = {
                 num_key_value_heads=2, head_dim=8, rope_theta=10000000.0,
                 sa_config=SparseAttention(
                     indexer_head_dim=4, indexer_num_heads=2,
-                    q_chunk_size=4, topk=5),
+                    q_chunk_size=4, kv_chunk_size=4, topk=5),
                 experts_held=4, compute_dtype="float32",
             ),
         ),

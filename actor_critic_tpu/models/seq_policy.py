@@ -118,11 +118,12 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 import numpy as np
 
 from actor_critic_tpu.models.distributions import Categorical
 from actor_critic_tpu.ops.mla_decode import mla_decode_auto
-from actor_critic_tpu.ops import moe_decode
+from actor_critic_tpu.ops import moe_decode, sparse_attention
 
 
 # The kinds of grouped-query layer, as the source's `layer_types` names them.
@@ -139,13 +140,15 @@ class SparseAttention:
     to the `topk` causal keys of the highest indexer score `I[t, s] = sum_j
     w[t, j] relu(q_j[t] . k[s])`: `indexer_num_heads` query heads of
     `indexer_head_dim`, one key a position (the source's
-    `indexer_num_kv_heads` is 1). `q_chunk_size` is the causal pass's block
-    of queries; the source's `kv_chunk_size` tiles its kernels and no result
-    depends on it."""
+    `indexer_num_kv_heads` is 1). `q_chunk_size` x `kv_chunk_size` tile the
+    causal pass's square: its selection runs `q_chunk_size` queries a block,
+    and `sparse_tiles_frac` counts the tiles that hold a selected pair; no
+    result depends on either."""
 
     indexer_head_dim: int = 64
     indexer_num_heads: int = 16
     q_chunk_size: int = 512
+    kv_chunk_size: int = 512
     topk: int = 2048
 
     def __post_init__(self):
@@ -689,48 +692,98 @@ def top_k_mask(scores, valid, k: int):
     return valid & (above | (tie & (jnp.cumsum(tie, axis=-1) <= room)))
 
 
+def _chunk(size: int, T: int) -> int:
+    """The largest block of at most `size` that divides `T`."""
+    size = min(size, T)
+    while T % size:
+        size -= 1
+    return size
+
+
+# The name of a row's selection, which the kernel's backward pass keeps.
+KEEP = "sparse_keep"
+
+
 def dsa_unroll(p, h, positions, cfg: SeqPolicyConfig):
     """Causal sparse attention over `h [E, T, H]`: (out [E, T, H], (k, v,
-    index keys): what the cache keeps). Keys, values and the indexer's keys
-    are computed for all rows at once; the queries run `GQA_ROWS` episodes a
-    trip and `q_chunk_size` queries a block, the blocks one `lax.map` (a
-    loop the compiler meets once), each block rematerialized in the backward
-    pass. A block scores its queries against every key of the row, masked
-    to the causal keys the indexer selects: a dense pass over the whole
-    square, of which the queries use `sparse_kept_frac` of the causal
-    pairs."""
+    index keys): what the cache keeps, `sparse_tiles_frac`). Keys, values
+    and the indexer's keys are computed for all rows at once; the queries
+    run `GQA_ROWS` episodes a trip, and the indexer selects their keys
+    `q_chunk_size` queries a block, the blocks one `lax.map` (a loop the
+    compiler meets once).
+
+    Where `sparse_attention.takes_kernel` (a TPU, shapes that tile), the
+    blocks' selections are stacked into the row's `keep [T, T]` and one
+    kernel attends over the `q_chunk_size` x `kv_chunk_size` tiles that hold
+    a selected pair; no score reaches HBM. The row is rematerialized in the
+    backward pass but for `keep` and the kernel's residuals, so the mask's
+    tiled copies are never alive across the rows. Elsewhere each block
+    scores its queries against every key of the row, masked to its
+    selection, and is rematerialized in the backward pass
+    (`sparse_attention.reference`: the tiny preset, the CPU).
+
+    `sparse_tiles_frac` is the share of those tiles of the square that hold
+    at least one selected pair, over the rows, read off the selection the
+    pass built."""
     cd = jnp.dtype(cfg.compute_dtype)
     nkv, d = cfg.num_key_value_heads, cfg.head_dim
     T = h.shape[1]
-    Q = min(cfg.sa_config.q_chunk_size, T)
-    while T % Q:
-        Q -= 1
+    Q = _chunk(cfg.sa_config.q_chunk_size, T)
+    K = _chunk(cfg.sa_config.kv_chunk_size, T)
     k = _gqa_project(p, h, positions, cfg, SPARSE, "k")
     v = _gqa_project(p, h, positions, cfg, SPARSE, "v")
     with jax.named_scope("dsa_index"):
         keys = index_keys(p["indexer"], h, positions, cfg)
 
-    def attend(h, k, v, keys, positions):
+    def select(h, keys, positions, q0):
+        hq = jax.lax.dynamic_slice_in_dim(h, q0, Q, axis=1)
+        pq = jax.lax.dynamic_slice_in_dim(positions, q0, Q, axis=1)
+        with jax.named_scope("dsa_index"):
+            scores = index_scores(p["indexer"], hq, pq, keys, cfg)
+            causal = (q0 + jnp.arange(Q))[:, None] >= jnp.arange(T)[None, :]
+            keep = top_k_mask(jax.lax.stop_gradient(scores), causal,
+                              cfg.sa_config.topk)
+        return hq, pq, keep
+
+    def tiles_held(keep):  # [e, Q, T] -> [e]
+        return jnp.sum(jnp.any(keep.reshape(keep.shape[0], Q, T // K, K), axis=(1, 3)),
+                       axis=-1)
+
+    def kernel(h, k, v, keys, positions):
         def block(q0):
-            hq = jax.lax.dynamic_slice_in_dim(h, q0, Q, axis=1)
-            pq = jax.lax.dynamic_slice_in_dim(positions, q0, Q, axis=1)
-            with jax.named_scope("dsa_index"):
-                scores = index_scores(p["indexer"], hq, pq, keys, cfg)
-                causal = (q0 + jnp.arange(Q))[:, None] >= jnp.arange(T)[None, :]
-                keep = top_k_mask(jax.lax.stop_gradient(scores), causal,
-                                  cfg.sa_config.topk)
+            keep = select(h, keys, positions, q0)[2]
+            return keep, tiles_held(keep)
+
+        keep, held = jax.lax.map(block, jnp.arange(0, T, Q))
+        keep = checkpoint_name(jnp.swapaxes(keep, 0, 1).reshape(h.shape[0], T, T), KEEP)
+        # The projection of `_gqa_project`, the scale folded into q in
+        # float32 before its one cast.
+        q = _mm(h, p["w_q"], cd, out=cd).reshape(*h.shape[:2], nkv, -1, d)
+        if "q_norm" in p:
+            q = _rms(q, p["q_norm"], cfg.rms_norm_eps)
+        q = _rope_halves(q, positions[..., None, None], rope_frequencies(cfg, SPARSE))
+        out = sparse_attention.attend((q * d ** -0.5).astype(cd), k, v, keep,
+                                      block_q=Q, block_kv=K)
+        return _mm(out.reshape(*h.shape[:2], -1), p["w_o"], cd), jnp.sum(held, 0)
+
+    def blocks(h, k, v, keys, positions):
+        def block(q0):
+            hq, pq, keep = select(h, keys, positions, q0)
             q = _gqa_project(p, hq, pq, cfg, SPARSE, "q")
             q = q.reshape(*q.shape[:2], nkv, -1, d)
-            s = _einsum("eqkgd,eskd->ekgqs", q, k, cd) * d ** -0.5
-            s = jnp.where(keep[:, None, None], s, -jnp.inf)
-            out = _einsum("ekgqs,eskd->eqkgd", jax.nn.softmax(s, axis=-1), v, cd)
-            return _mm(out.reshape(*out.shape[:2], -1), p["w_o"], cd)
+            out = sparse_attention.reference(q, k, v, keep, d ** -0.5)
+            return _mm(out.reshape(*out.shape[:2], -1), p["w_o"], cd), tiles_held(keep)
 
-        out = jax.lax.map(jax.checkpoint(block), jnp.arange(0, T, Q))
-        return jnp.swapaxes(out, 0, 1).reshape(h.shape)
+        out, held = jax.lax.map(jax.checkpoint(block), jnp.arange(0, T, Q))
+        return jnp.swapaxes(out, 0, 1).reshape(h.shape), jnp.sum(held, 0)
 
-    out = _map_rows(attend, GQA_ROWS, h, k, v, keys, positions, remat=False)
-    return out, (k, v, keys)
+    if sparse_attention.takes_kernel(T, d, Q, K):
+        attend = jax.checkpoint(kernel, policy=jax.checkpoint_policies.save_only_these_names(
+            KEEP, sparse_attention.RESIDUALS))
+    else:
+        attend = blocks
+    out, held = _map_rows(attend, GQA_ROWS, h, k, v, keys, positions, remat=False)
+    return out, (k, v, keys), jnp.mean(held) / ((T // Q) * (T // K))
 
 
 def dsa_step(p, h, positions, cache, index: int, slot, cfg: SeqPolicyConfig):
@@ -1008,7 +1061,8 @@ def _value(p, h):
 
 def trunk(params, obs, cfg: SeqPolicyConfig, cache=None):
     """The causal pass over `obs [E, T, 3]`: (final-normed hidden [E, T, H],
-    the expert layers' stats, layers-mean, `cache`). A cache of grouped-query
+    the expert layers' stats and the sparse layers' `sparse_tiles_frac`,
+    layers-mean, `cache`). A cache of grouped-query
     layers (`init_cache`'s) is returned as this pass over positions `[0, T)`
     leaves it (the prefill); None stays None.
 
@@ -1029,29 +1083,33 @@ def trunk(params, obs, cfg: SeqPolicyConfig, cache=None):
         with jax.named_scope(SCOPE_OF[kind]):
             h = _rms(x, layer["attn_norm"], cfg.rms_norm_eps)
             if kind == "mla":
-                return x + mla_unroll(layer["mla"], h, positions, cfg), None
+                return x + mla_unroll(layer["mla"], h, positions, cfg), None, None
             if kind == SPARSE:
-                a, kv = dsa_unroll(layer["attn"], h, positions, cfg)
-            else:
-                a, kv = gqa_unroll(layer["attn"], h, positions, cfg, kind)
-            return x + a, kv
+                a, kv, held = dsa_unroll(layer["attn"], h, positions, cfg)
+                return x + a, kv, held
+            a, kv = gqa_unroll(layer["attn"], h, positions, cfg, kind)
+            return x + a, kv, None
 
     def ffn(layer, x):
         h = _rms(x, layer["ffn_norm"], cfg.rms_norm_eps).reshape(E * T, -1)
         y, stats, _ = _ffn(layer, h, cfg)
         return x + y.reshape(x.shape), stats
 
-    stats = []
+    stats, held = [], []
     for i, layer in enumerate(layers):
         kind = cfg.attention(i)
-        x, kv = jax.checkpoint(attn, static_argnums=0)(kind, layer, x)
+        x, kv, tiles = jax.checkpoint(attn, static_argnums=0)(kind, layer, x)
         if cache is not None:
             cache = {**cache, kind: gqa_fill(cache[kind], cfg.cache_index(i), *kv)}
+        if tiles is not None:
+            held.append(tiles)
         x, s = jax.checkpoint(ffn)(layer, x)
         if s is not None:
             stats.append(s)
     mean = {k: jnp.mean(jnp.stack([s[k] for s in stats])) for k in stats[0]} \
         if stats else {}
+    if held:
+        mean["sparse_tiles_frac"] = jnp.mean(jnp.stack(held))
     return _rms(x, p["final_norm"], cfg.rms_norm_eps), mean, cache
 
 

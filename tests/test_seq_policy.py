@@ -543,6 +543,45 @@ def test_the_long_context_rollout_compiled_for_a_v5e_reads_its_experts_by_the_ke
     assert moved == []
 
 
+@pytest.mark.parametrize("kernel", [True, False])
+def test_the_sparse_pass_compiled_for_a_v5e_never_holds_the_scores(v5e, kernel, monkeypatch):
+    """One sparse layer of `impala_keye_vl2` as shipped over a row of 8,192,
+    forward and backward, compiled for the described chip. With the kernel
+    (forward, dq and dkv) no buffer holds a block's float32 attention scores
+    `[.., heads, 512, 8192]`: the indexer's one score a pair, `[.., 512,
+    8192]`, is all, and the forward runs once: the row's rematerialization
+    keeps the kernel's residuals. The dense masked blocks, the same program
+    off a TPU, hold them: the test can tell."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(pallas_scan, "on_tpu", lambda: kernel)
+    seq = config_mod.PRESETS["impala_keye_vl2"].config.seq
+    T, H = 8192, seq.hidden_size
+    on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e)  # noqa: E731
+    p = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: sp.init_params(jax.random.key(0), seq, 8))["params"]["layer_0"]["attn"])
+    h = on_chip(jax.ShapeDtypeStruct((1, T, H), jnp.float32))
+    positions = jnp.arange(T)[None]
+    grad = jax.jit(jax.grad(lambda p, h: jnp.sum(sp.dsa_unroll(p, h, positions, seq)[0]),
+                            argnums=(0, 1)))
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = grad.lower(p, h).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    lead = [np.prod([int(n) for n in m.split(",") if n])
+            for m in re.findall(r"f32\[((?:\d+,)*)512,8192\]", text)]
+    assert 1 in lead  # the indexer's scores
+    if kernel:  # forward, dq, dkv: the row's rematerialization keeps the forward's residuals
+        assert calls == 3 and set(lead) == {1}
+    else:
+        assert calls == 0 and max(lead) == seq.num_attention_heads
+
+
 # -- the expert layer and the chip's share ----------------------------------
 
 def _expert_layer(seq, key=0):
@@ -1194,10 +1233,8 @@ def test_the_sparse_preset_is_the_configuration_the_benchmark_states():
             assert getattr(seq, key) == value, key
     assert net["sa_config"] == cfg["sa_config"]
     assert dataclasses.asdict(seq.sa_config) == {
-        k: v for k, v in net["sa_config"].items()
-        if k not in ("indexer_num_kv_heads", "kv_chunk_size")}
-    # One indexer key a position, as the program builds it; the source's
-    # kernel tiling of keys is no part of any result.
+        k: v for k, v in net["sa_config"].items() if k != "indexer_num_kv_heads"}
+    # One indexer key a position, as the program builds it.
     assert net["sa_config"]["indexer_num_kv_heads"] == 1
     assert seq.kinds == (sp.SPARSE,) * 4 and seq.layer_types == ()
     assert net["rope_scaling"] == cfg["rope_scaling"] and seq.rope_theta == cfg["rope_theta"]
@@ -1237,6 +1274,12 @@ PARENT_STEPS = {
     # The window-and-full cell's as shipped, at PR 35's commit: the sparse
     # kind shares its projection, cache and prefill code and moves none of it.
     ("impala_mellum2", 8): "7f0b0181a524a070e85f157d888c7001b19d305532bf072cd41917579cebf130",
+    # The sparse cell's as shipped. Off a TPU its causal pass takes the
+    # dense masked blocks, so its text is PR 36's `83a905c3...` but for one
+    # output, `sparse_tiles_frac` (a block's tiles that hold a selected pair,
+    # counted after its attention, summed over blocks, meaned over rows and
+    # layers): no operation of the parent's is gone or changed.
+    ("impala_keye_vl2", 4): "a7175f5f2336219ddad961f914f43b75d607eb04983d9dbcf01a96683cc74463",
 }
 
 
