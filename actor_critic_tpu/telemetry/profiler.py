@@ -24,6 +24,10 @@ Two introspection tools that run INSIDE a live training process:
   attribution table). The funnel is internal JAX API, so the hook is
   best-effort: if the import shape changes, telemetry degrades to the
   `jax.monitoring` counter (sampler.py) instead of breaking the run.
+  While a session is installed the call is also a `compile` span, and
+  jax's own trace and lowering phases are `jit_trace` / `jit_lower` spans
+  (a listener on its `jax.monitoring` events), so `spans.jsonl` and a
+  profiler capture show where a first dispatch's wait goes.
 """
 
 from __future__ import annotations
@@ -33,6 +37,8 @@ import signal
 import threading
 import time
 from typing import Optional
+
+from actor_critic_tpu.telemetry import session as _session
 
 DEFAULT_PROFILE_ITERS = 5
 
@@ -176,20 +182,14 @@ class WindowedProfiler:
 
             start_trace(path)
         except Exception as e:  # profiler unavailable: report, don't die
-            from actor_critic_tpu.telemetry import session as _session
-
             _session.event("profile_failed", error=str(e)[:500])
             return
         self._active_dir = path
         self._remaining = n
         self._t_start = time.perf_counter()
-        from actor_critic_tpu.telemetry import session as _session
-
         _session.event("profile_start", path=path, iters=n)
 
     def _stop(self, path: str, dur_s: float) -> None:
-        from actor_critic_tpu.telemetry import session as _session
-
         try:
             from actor_critic_tpu.utils.profiling import stop_trace
 
@@ -220,8 +220,6 @@ def tick() -> None:
     """Per-iteration hook the training loops call: routes to the current
     session's profiler (no-op — one import-free attribute read — when no
     session or no profiler is installed)."""
-    from actor_critic_tpu.telemetry import session as _session
-
     s = _session.current()
     if s is not None and s.profiler is not None:
         s.profiler.tick()
@@ -238,8 +236,6 @@ def install_sigusr2(iters: int = DEFAULT_PROFILE_ITERS) -> bool:
         return False
 
     def _handler(signum, frame):
-        from actor_critic_tpu.telemetry import session as _session
-
         s = _session.current()
         if s is not None and s.profiler is not None:
             # request_arm, not arm(): the handler runs ON the training
@@ -315,14 +311,99 @@ def _cost_fields(executable) -> dict:
     return out
 
 
+# jax times its own compile phases on the calling thread, with the
+# function's name: `record_scalar(event, start)` on entry and
+# `record_event_duration_secs(event, seconds)` on exit
+# (`jax._src.dispatch.log_elapsed_time`). A listener on each end of the
+# pair opens and closes a real span, so the phase is mirrored into a running
+# profiler capture like any other span.
+_TRACE_PHASE = "jit_trace"
+_LOWER_PHASE = "jit_lower"
+_PHASE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": _TRACE_PHASE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": _LOWER_PHASE,
+}
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# Per thread: `open`, the phases under way as (event, fun_name, span or
+# None for a phase inside another), innermost last; `retrieve_s`, the
+# persistent cache's retrieval time in the compile under way.
+_phase_tls = threading.local()
+# jaxlint: thread-owned=compile-lock (tested and set only inside
+# ensure_compile_introspection, under _compile_lock)
+_phase_listeners_installed = False
+
+
+def _on_phase_start(event: str, value, **kwargs) -> None:
+    if _session.current() is None:
+        return
+    try:
+        phase = _PHASE_OF_EVENT.get(event)
+        if phase is None:
+            return
+        fun = kwargs.get("fun_name")
+        stack = getattr(_phase_tls, "open", None)
+        if stack is None:
+            stack = _phase_tls.open = []
+        sp = None
+        # Only the outermost phase is a span: every jitted function a
+        # trace calls (each `jnp` operation is one), and every function a
+        # lowering traces, is traced inside it, and a span apiece would be
+        # thousands a step.
+        if not stack:
+            sp = _session.span(phase, fun=str(fun))
+            sp.__enter__()
+        stack.append((event, fun, sp))
+    except Exception:
+        pass  # a listener that raised would fail jax's own compile
+
+
+def _on_phase_end(event: str, duration_secs: float, **kwargs) -> None:
+    # A span opened while a session was installed is closed even when the
+    # session has gone since: it sits on the thread's open-span stack.
+    if _session.current() is None and not getattr(_phase_tls, "open", None):
+        return
+    try:
+        if event == _RETRIEVAL_EVENT:
+            _phase_tls.retrieve_s = round(float(duration_secs), 6)
+            return
+        stack = getattr(_phase_tls, "open", None)
+        if not stack:
+            return
+        opened, fun, sp = stack[-1]
+        if opened == event and fun == kwargs.get("fun_name"):
+            stack.pop()
+            if sp is not None:
+                sp.__exit__(None, None, None)
+    except Exception:
+        pass
+
+
+def _ensure_phase_listeners() -> None:
+    global _phase_listeners_installed
+    if _phase_listeners_installed:
+        return
+    try:
+        import jax.monitoring
+
+        jax.monitoring.register_scalar_listener(_on_phase_start)
+        jax.monitoring.register_event_duration_secs_listener(_on_phase_end)
+        _phase_listeners_installed = True
+    except (ImportError, AttributeError):
+        pass
+
+
 def ensure_compile_introspection() -> bool:
     """Idempotently wrap JAX's compile funnel
     (`jax._src.compiler.compile_or_get_cached`) so each XLA compilation
     produces one structured record: process-global ring + a `compile`
-    event on any installed session. Best-effort — returns False (and
-    changes nothing) if the internal funnel moved."""
+    event on any installed session, and a `compile` span around the call.
+    Also listens to jax's own compile-phase events, which become
+    `jit_trace` / `jit_lower` spans while a session is installed.
+    Best-effort — returns False (and changes nothing) if the internal
+    funnel moved."""
     global _introspection_installed
     with _compile_lock:
+        _ensure_phase_listeners()
         if _introspection_installed:
             return True
         try:
@@ -359,24 +440,34 @@ def ensure_compile_introspection() -> bool:
             from actor_critic_tpu.utils.compile_cache import thread_cache_hits
 
             hits_before = thread_cache_hits()
-            t0 = time.perf_counter()
-            executable = original(*args, **kwargs)
-            record = {
-                "name": name if name is not None else "?",
-                "compile_s": round(time.perf_counter() - t0, 4),
-                **_cost_fields(executable),
-            }
-            # Persistent-cache attribution: a hit event on THIS thread
-            # during the call means this "compile" deserialized a cached
-            # executable, not recompiled (the event fires on the
-            # compiling thread, so the AOT warmup thread's hits are
-            # never counted here).
-            if thread_cache_hits() > hits_before:
-                record["cache_hit"] = True
-            if mosaic:
-                record["mosaic_calls"] = mosaic
-            if sig is not None:
-                record["signature"] = sig[:2000]
+            _phase_tls.retrieve_s = None
+            # The call is a `compile` span (cache key, retrieval and load
+            # on a hit, the backend compile on a miss), with the record
+            # below as its args.
+            with _session.span("compile") as sp:
+                t0 = time.perf_counter()
+                executable = original(*args, **kwargs)
+                record = {
+                    "name": name if name is not None else "?",
+                    "compile_s": round(time.perf_counter() - t0, 4),
+                    **_cost_fields(executable),
+                }
+                # Persistent-cache attribution: a hit event on THIS thread
+                # during the call means this "compile" deserialized a
+                # cached executable, not recompiled (the event fires on
+                # the compiling thread, so the AOT warmup thread's hits
+                # are never counted here).
+                if thread_cache_hits() > hits_before:
+                    record["cache_hit"] = True
+                if mosaic:
+                    record["mosaic_calls"] = mosaic
+                if sig is not None:
+                    record["signature"] = sig[:2000]
+                sp.annotate(**{
+                    **record, "cache_hit": "cache_hit" in record,
+                    "retrieve_s": _phase_tls.retrieve_s,
+                    "thread": threading.current_thread().name,
+                })
             _record_compile(record)
             return executable
 
@@ -391,8 +482,6 @@ def _record_compile(record: dict) -> None:
         _compile_total += 1
         _compile_records.append(record)
         del _compile_records[:-_COMPILE_RING_MAX]
-    from actor_critic_tpu.telemetry import session as _session
-
     try:
         _session.event("compile", **record)
     except Exception:

@@ -19,6 +19,7 @@ the most recently entered phase anywhere in the process.
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -124,6 +125,11 @@ class _Span:
         if s is not None:
             s.tracer.complete(self._name, self._t0, dur, self._args)
 
+    def annotate(self, **args) -> None:
+        """Add args known only once the work has run (a compile's cache
+        hit, a warm-up entry's error); they ride the event at exit."""
+        self._args = {**(self._args or {}), **args}
+
 
 def span(name: str, **args) -> _Span:
     """`with telemetry.span("update", it=12):` around a loop phase."""
@@ -172,6 +178,8 @@ def current() -> Optional["TelemetrySession"]:
 
 
 def set_current(session: Optional["TelemetrySession"]) -> None:
+    """Install `session` (or none): spans emit to it, and while one is
+    installed a `gc.callbacks` hook times full collections."""
     global _SESSION, _ANNOTATION
     _SESSION = session
     _ANNOTATION = None
@@ -182,6 +190,48 @@ def set_current(session: Optional["TelemetrySession"]) -> None:
             from jax.profiler import TraceAnnotation as _ANNOTATION
         except ImportError:
             pass
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+    elif _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+        _GC_OPEN.clear()
+
+
+GC_PHASE = "gc"
+# The full collection under way: [(perf_counter at its start, annotation)].
+# jaxlint: thread-owned=collector (written by the gc hook, which the
+# collector calls one collection at a time, start then stop, never two at
+# once; set_current clears it after the hook is removed; a lock here could
+# deadlock a collection that starts while the lock is held)
+_GC_OPEN: list = []
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """A generation-2 collection as a `gc` span on the thread that
+    triggered it, mirrored as `ac:gc`. Younger generations return at the
+    first test. A collection can start inside any allocation, this
+    module's and the tracer's locked sections included, so nothing here
+    takes a lock: the event goes through the tracer's `defer`."""
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        annotation = None
+        if _ANNOTATION is not None:
+            annotation = _ANNOTATION(ANNOTATION_PREFIX + GC_PHASE)
+            annotation.__enter__()
+        _GC_OPEN[:] = [(time.perf_counter(), annotation)]
+        return
+    if not _GC_OPEN:
+        return  # the hook was installed mid-collection
+    t0, annotation = _GC_OPEN.pop()
+    dur = time.perf_counter() - t0
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    s = _SESSION
+    if s is not None:
+        s.tracer.defer(GC_PHASE, t0, dur, {
+            "generation": 2, "collected": info.get("collected", 0),
+        })
 
 
 def open_spans() -> list[str]:
@@ -475,6 +525,7 @@ class TelemetrySession:
         self.last_observation = None
         self._rates = {}
         self._prev_observe = None
+        self.tracer.flush_deferred()
         for fh in (self._spans_fh, self._resources_fh, self._events_fh):
             try:
                 fh.close()

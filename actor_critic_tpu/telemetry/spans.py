@@ -14,6 +14,7 @@ unix epoch so wall-clock can be recovered.
 
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
@@ -47,6 +48,15 @@ CANONICAL_PHASES = frozenset({
     "serve_queue_wait", # enqueue -> dispatcher pops it into a flush
     "serve_dispatch",   # one micro-batch flush through engine.act
     "serve_respond",    # response serialization + socket write
+    # Start-up, from `train.main`'s arguments to its first dispatch.
+    "startup",          # arguments parsed -> the loop's first step is next
+    "init_env",         # build_env / build_actor_pools
+    "init_state",       # the fused trainer's state on the device
+    "jit_trace",        # jax tracing a function to a jaxpr (profiler.py)
+    "jit_lower",        # jax lowering a jaxpr to an MLIR module (profiler.py)
+    "compile",          # compile_or_get_cached: cache load or backend compile
+    "warmup",           # one AOT warm-up plan entry (compile_cache.py)
+    "gc",               # a full (generation-2) garbage collection
 })
 
 
@@ -75,6 +85,14 @@ class SpanTracer:
         # clock all processes on the host share).
         self._epoch0 = time.time()
         self._named_pids: set[int] = set()
+        # Complete events recorded where the lock above may already be held
+        # on the same thread (a garbage-collection callback can run inside
+        # any allocation, this tracer's own writes included): appended
+        # here without a lock and written by the next `_write`, or by
+        # `flush_deferred`.
+        # jaxlint: thread-owned=any (a deque's append and popleft are
+        # atomic: each event is queued once and written once)
+        self._deferred: collections.deque = collections.deque()
         self._write({
             "name": "process_name", "ph": "M", "pid": self._pid, "tid": 0,
             "args": {"name": "train"},
@@ -111,6 +129,25 @@ class SpanTracer:
                 mirror(evt)
             except Exception:
                 pass
+        if self._deferred:
+            self.flush_deferred()
+
+    def _complete_evt(
+        self, name: str, start_pc: float, dur_s: float,
+        args: Optional[dict], tid: Optional[int] = None,
+    ) -> dict:
+        evt = {
+            "name": name,
+            "ph": "X",
+            "ts": round((start_pc - self._t0) * 1e6, 1),
+            "dur": round(dur_s * 1e6, 1),
+            "pid": self._pid,
+            "tid": (threading.get_ident() if tid is None else tid) & 0x7FFFFFFF,
+            "cat": "phase",
+        }
+        if args:
+            evt["args"] = args
+        return evt
 
     def complete(
         self, name: str, start_pc: float, dur_s: float,
@@ -118,18 +155,28 @@ class SpanTracer:
     ) -> None:
         """Emit a ph:"X" complete event; `start_pc` is the span's entry
         `perf_counter()` reading, `dur_s` its duration in seconds."""
-        evt = {
-            "name": name,
-            "ph": "X",
-            "ts": round((start_pc - self._t0) * 1e6, 1),
-            "dur": round(dur_s * 1e6, 1),
-            "pid": self._pid,
-            "tid": threading.get_ident() & 0x7FFFFFFF,
-            "cat": "phase",
-        }
-        if args:
-            evt["args"] = args
-        self._write(evt)
+        self._write(self._complete_evt(name, start_pc, dur_s, args))
+
+    def defer(
+        self, name: str, start_pc: float, dur_s: float, args: Optional[dict],
+    ) -> None:
+        """`complete` for a caller that must take no lock: the event is
+        queued (a deque append is atomic) under the calling thread's id and
+        written by the next emission on any thread."""
+        self._deferred.append(
+            (name, start_pc, dur_s, args, threading.get_ident())
+        )
+
+    def flush_deferred(self) -> None:
+        """Write the events `defer` queued."""
+        items = []
+        while True:
+            try:
+                items.append(self._deferred.popleft())
+            except IndexError:
+                break
+        for item in items:
+            self._write(self._complete_evt(*item))
 
     def name_process(self, pid: int, name: str) -> None:
         """Emit a process_name metadata event for a FOREIGN pid (e.g. an

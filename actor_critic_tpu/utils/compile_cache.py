@@ -27,7 +27,8 @@ module is the prevention layer, three parts:
    and the checkpoint restores, so time-to-first-step hides compile
    instead of serializing on it. Compiled executables land in the
    persistent cache; the training loop's own first dispatch then
-   re-traces and *hits* the cache instead of compiling.
+   re-traces and *hits* the cache instead of compiling. Each entry is a
+   `warmup` span on the `aot-warmup` thread.
    `scripts/check_warmup_registry.py` (tier-1, via
    tests/test_warmup_registry.py) fails when a `jax.jit` entry point in
    `algos/` or `models/` is neither registered here nor listed in
@@ -475,10 +476,10 @@ class WarmupRunner:
 
     Runs each thunk on a daemon thread (XLA compilation releases the
     GIL, so it genuinely overlaps host-side env spawn/reset/restore),
-    records per-entry compile wall + outcome, and emits a
-    `warmup_compile` telemetry event per entry plus one `warmup_done`
-    summary. `wait()` is for tests/benches; the training loop never
-    joins it."""
+    records per-entry compile wall + outcome in `results`, and times each
+    entry as a `warmup` span (args `entry`, and `error` where it failed),
+    which holds the entry's `jit_*` and `compile` spans. `wait()` is for
+    tests/benches; the training loop never joins it."""
 
     def __init__(self, plan: list[tuple[str, Callable]]):
         self._plan = plan
@@ -502,31 +503,18 @@ class WarmupRunner:
         for name, thunk in self._plan:
             t0 = time.perf_counter()
             row = {"entry": name}
-            try:
-                thunk()
-                row["compile_s"] = round(time.perf_counter() - t0, 4)
-            except Exception as e:  # warmup must never take the run down
-                row["error"] = str(e)[:500]
+            with _session.span("warmup", entry=name) as sp:
+                try:
+                    thunk()
+                    row["compile_s"] = round(time.perf_counter() - t0, 4)
+                except Exception as e:  # warmup must never take the run down
+                    row["error"] = str(e)[:500]
+                    sp.annotate(error=row["error"])
             self.results.append(row)
-            try:
-                _session.event("warmup_compile", **row)
-            except Exception:
-                pass
         # The thunks close over envs and jitted programs; a finished
         # runner stays listed in _STARTED_RUNNERS and keeps only its rows.
         self._plan = []
         self._done.set()
-        try:
-            _session.event(
-                "warmup_done",
-                entries=len(self.results),
-                errors=sum(1 for r in self.results if "error" in r),
-                total_s=round(
-                    sum(r.get("compile_s", 0.0) for r in self.results), 3
-                ),
-            )
-        except Exception:
-            pass
 
     def wait(self, timeout: Optional[float] = None) -> bool:
         return self._done.wait(timeout)

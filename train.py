@@ -282,7 +282,12 @@ def run_fused(env, preset, args, logger) -> dict:
 
     mod = fused_module(preset.algo)
     cfg = preset.config
-    state = mod.init_state(env, cfg, jax.random.key(args.seed))
+    with telemetry.span("init_state"):
+        state = mod.init_state(env, cfg, jax.random.key(args.seed))
+        if telemetry.current() is not None:
+            # Traced runs wait here, so the device's share of the state's
+            # making lands in this span and not in the first `update`.
+            jax.block_until_ready(state)
     raw_step = mod.make_train_step(env, cfg)
     chunk = max(1, getattr(args, "chunk", 1))
     if chunk > 1:
@@ -445,6 +450,7 @@ def run_fused(env, preset, args, logger) -> dict:
         gauge_key = sampler.register_gauge(
             "mixture_eval", lambda: dict(eval_matrix)
         )
+    end_startup(args)
     try:
         state, metrics = checkpointed_train(
             step_tracking, state, args.iterations,
@@ -537,6 +543,7 @@ def run_multihost(pools, preset, args, logger) -> dict:
         last.update(m)
         logger.log(it, m)
 
+    end_startup(args)
     _, _, summary = multihost.train_multihost(
         pools, preset.config, args.iterations,
         rank=rank, world=world,
@@ -631,6 +638,7 @@ def run_host_async(pools, preset, args, logger) -> dict:
         gateway, publish_hook = start_serving_sidecar(
             preset, pools[0].spec, args
         )
+    end_startup(args)
     try:
         if preset.algo == "ppo":
             ppo.train_host_async(
@@ -697,6 +705,7 @@ def run_host(pool, preset, args, logger) -> dict:
         overlap=not args.no_overlap,
     )
     offpolicy = dict(common, save_replay=not args.no_save_replay)
+    end_startup(args)
     try:
         if preset.algo == "ppo":
             ppo.train_host(pool, preset.config, **common)
@@ -1006,6 +1015,26 @@ def main(argv=None) -> int:
     )
     p.add_argument("--list-presets", action="store_true")
     args = p.parse_args(argv)
+    # `startup` runs from here to the loop's first step: the function that
+    # runs the loop closes it (`end_startup`), and any other way out of
+    # main closes it below, so no span is left open.
+    args.startup = telemetry.span("startup")
+    args.startup.__enter__()
+    try:
+        return run_main(args)
+    finally:
+        end_startup(args)
+
+
+def end_startup(args) -> None:
+    """Close main's `startup` span, once: the loop's first step is next."""
+    span, args.startup = getattr(args, "startup", None), None
+    if span is not None:
+        span.__exit__(None, None, None)
+
+
+def run_main(args) -> int:
+    """main's body once the arguments are parsed."""
     if args.telemetry_port is not None and not args.telemetry_dir:
         raise SystemExit(
             "--telemetry-port requires --telemetry-dir (the exporter "
@@ -1204,7 +1233,6 @@ def main(argv=None) -> int:
         # on-disk cache so resumed legs start near-instantly.
         compile_cache.enable_persistent_cache(cache_dir)
         print(f"compile cache: {cache_dir}", flush=True)
-    pools = None
     if args.async_actors > 0:
         if (args.ckpt_dir or args.resume) and (
             preset.algo != "ppo" or args.distributed
@@ -1222,109 +1250,115 @@ def main(argv=None) -> int:
                 "always act through the numpy mirror); ignored",
                 flush=True,
             )
-        pools = build_actor_pools(preset, args, args.async_actors)
-        env, fused = pools[0], False
-    else:
-        env, fused = build_env(
-            preset.env, preset.algo, preset.config, args.seed,
-            scale_actions=args.scale_actions, env_kwargs=preset.env_kwargs,
-            workers=args.workers,
-        )
-    if fused and args.workers > 1:
-        print("--workers applies to host pools only; ignored for jax:* "
-              "envs (their rollouts are fused on-device)", flush=True)
-    # Host pools carry their ACTION convention in the checkpoint metrics
-    # too (host_loop's _pool_scale_actions), but env_kwargs exist only
-    # here — the sidecar guards both paths against resuming into a
-    # different env (kwargs) or convention (fused envs).
-    check_env_convention(
-        args.ckpt_dir, preset.env, args.scale_actions, args.resume,
-        env_kwargs=preset.env_kwargs,
-    )
-
+    pools = None
     telemetry_session = None
-    if args.telemetry_dir:
-        telemetry_session = telemetry.TelemetrySession(
-            args.telemetry_dir,
-            run_info={
-                "algo": preset.algo,
-                "env": preset.env,
-                "iterations": args.iterations,
-                "seed": args.seed,
-                "config": dataclasses.asdict(preset.config),
-            },
-            resource_interval_s=args.telemetry_sample_s,
-            serve_port=args.telemetry_port,
-            serve_host=args.telemetry_bind,
-        )
-        telemetry.set_current(telemetry_session)
-        if telemetry_session.exporter is not None:
-            print(
-                f"telemetry exporter: {telemetry_session.exporter.url}"
-                "/metrics /healthz /profile?iters=N",
-                flush=True,
-            )
-        # `kill -USR2 <pid>` arms an on-demand profile capture even when
-        # no --telemetry-port was given.
-        from actor_critic_tpu.telemetry.profiler import install_sigusr2
-
-        install_sigusr2()
-
-    if args.warmup and cache_dir is None:
-        # AOT-compiled executables are never installed into the jit
-        # dispatch cache (JAX AOT contract) — without the persistent
-        # cache to carry them to the loop's own jit objects, warmup
-        # would just compile everything twice on a contended host.
-        print(
-            "AOT warmup skipped: requires the persistent compile cache "
-            "(--compile-cache-dir none turned it off)",
-            flush=True,
-        )
-    elif args.warmup:
-        # Background AOT warmup: compile every registered entry point
-        # (abstract arg shapes from spec + config) while the host side
-        # resets pools / restores checkpoints. XLA compilation releases
-        # the GIL, so this genuinely overlaps; each compile lands in the
-        # persistent cache, so the loop's own first dispatch re-traces
-        # and hits instead of compiling.
-        ctx = compile_cache.WarmupContext(
-            algo=preset.algo, fused=fused, spec=env.spec,
-            cfg=preset.config, env=env if fused else None,
-            chunk=max(1, args.chunk) if fused else 1,
-            iterations=args.iterations, eval_every=args.eval_every,
-            eval_envs=args.eval_envs, overlap=not args.no_overlap,
-            resume=args.resume,
-            async_actors=args.async_actors,
-            async_correction=args.async_correction,
-            data_plane=args.data_plane,
-            plane_codec=args.data_plane_codec,
-            queue_depth=args.queue_depth,
-        )
-        plan = compile_cache.plan_warmup(ctx)
-        if plan:
-            print(
-                f"AOT warmup: {len(plan)} entry point(s) compiling in "
-                "the background: " + ", ".join(n for n, _ in plan),
-                flush=True,
-            )
-            compile_cache.WarmupRunner(plan).start()
-
     watchdog = None
-    if args.stall_timeout > 0:
-        from actor_critic_tpu.utils.watchdog import StallWatchdog
-
-        if getattr(args, "chunk", 1) > 1:
-            # One heartbeat per chunk: a timeout shorter than a chunk's
-            # wall time would misread normal progress as a stall and
-            # kill/resume in a loop that never clears the chunk.
-            print(
-                f"watchdog with --chunk {args.chunk}: --stall-timeout "
-                f"{args.stall_timeout:g}s must exceed one chunk's wall "
-                "time or the run will be killed mid-chunk", flush=True,
-            )
-        watchdog = StallWatchdog(args.stall_timeout).start()
-    t0 = time.time()
     try:
+        # The session comes before the environment is built, so that no
+        # start-up span closes before it exists, and after the
+        # distributed handshake and the cache configuration, which must
+        # come before the backend starts.
+        if args.telemetry_dir:
+            telemetry_session = telemetry.TelemetrySession(
+                args.telemetry_dir,
+                run_info={
+                    "algo": preset.algo,
+                    "env": preset.env,
+                    "iterations": args.iterations,
+                    "seed": args.seed,
+                    "config": dataclasses.asdict(preset.config),
+                },
+                resource_interval_s=args.telemetry_sample_s,
+                serve_port=args.telemetry_port,
+                serve_host=args.telemetry_bind,
+            )
+            telemetry.set_current(telemetry_session)
+            if telemetry_session.exporter is not None:
+                print(
+                    f"telemetry exporter: {telemetry_session.exporter.url}"
+                    "/metrics /healthz /profile?iters=N",
+                    flush=True,
+                )
+            # `kill -USR2 <pid>` arms an on-demand profile capture even when
+            # no --telemetry-port was given.
+            from actor_critic_tpu.telemetry.profiler import install_sigusr2
+
+            install_sigusr2()
+        with telemetry.span("init_env"):
+            if args.async_actors > 0:
+                pools = build_actor_pools(preset, args, args.async_actors)
+                env, fused = pools[0], False
+            else:
+                env, fused = build_env(
+                    preset.env, preset.algo, preset.config, args.seed,
+                    scale_actions=args.scale_actions,
+                    env_kwargs=preset.env_kwargs, workers=args.workers,
+                )
+        if fused and args.workers > 1:
+            print("--workers applies to host pools only; ignored for jax:* "
+                  "envs (their rollouts are fused on-device)", flush=True)
+        # Host pools carry their ACTION convention in the checkpoint metrics
+        # too (host_loop's _pool_scale_actions), but env_kwargs exist only
+        # here — the sidecar guards both paths against resuming into a
+        # different env (kwargs) or convention (fused envs).
+        check_env_convention(
+            args.ckpt_dir, preset.env, args.scale_actions, args.resume,
+            env_kwargs=preset.env_kwargs,
+        )
+
+        if args.warmup and cache_dir is None:
+            # AOT-compiled executables are never installed into the jit
+            # dispatch cache (JAX AOT contract) — without the persistent
+            # cache to carry them to the loop's own jit objects, warmup
+            # would just compile everything twice on a contended host.
+            print(
+                "AOT warmup skipped: requires the persistent compile cache "
+                "(--compile-cache-dir none turned it off)",
+                flush=True,
+            )
+        elif args.warmup:
+            # Background AOT warmup: compile every registered entry point
+            # (abstract arg shapes from spec + config) while the host side
+            # resets pools / restores checkpoints. XLA compilation releases
+            # the GIL, so this genuinely overlaps; each compile lands in the
+            # persistent cache, so the loop's own first dispatch re-traces
+            # and hits instead of compiling.
+            ctx = compile_cache.WarmupContext(
+                algo=preset.algo, fused=fused, spec=env.spec,
+                cfg=preset.config, env=env if fused else None,
+                chunk=max(1, args.chunk) if fused else 1,
+                iterations=args.iterations, eval_every=args.eval_every,
+                eval_envs=args.eval_envs, overlap=not args.no_overlap,
+                resume=args.resume,
+                async_actors=args.async_actors,
+                async_correction=args.async_correction,
+                data_plane=args.data_plane,
+                plane_codec=args.data_plane_codec,
+                queue_depth=args.queue_depth,
+            )
+            plan = compile_cache.plan_warmup(ctx)
+            if plan:
+                print(
+                    f"AOT warmup: {len(plan)} entry point(s) compiling in "
+                    "the background: " + ", ".join(n for n, _ in plan),
+                    flush=True,
+                )
+                compile_cache.WarmupRunner(plan).start()
+
+        if args.stall_timeout > 0:
+            from actor_critic_tpu.utils.watchdog import StallWatchdog
+
+            if getattr(args, "chunk", 1) > 1:
+                # One heartbeat per chunk: a timeout shorter than a chunk's
+                # wall time would misread normal progress as a stall and
+                # kill/resume in a loop that never clears the chunk.
+                print(
+                    f"watchdog with --chunk {args.chunk}: --stall-timeout "
+                    f"{args.stall_timeout:g}s must exceed one chunk's wall "
+                    "time or the run will be killed mid-chunk", flush=True,
+                )
+            watchdog = StallWatchdog(args.stall_timeout).start()
+        t0 = time.time()
         with JsonlLogger(args.metrics, echo=not args.quiet) as logger:
             if fused:
                 final = run_fused(env, preset, args, logger)
