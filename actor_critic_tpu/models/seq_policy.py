@@ -123,7 +123,7 @@ import numpy as np
 
 from actor_critic_tpu.models.distributions import Categorical
 from actor_critic_tpu.ops.mla_decode import mla_decode_auto
-from actor_critic_tpu.ops import moe_decode, sparse_attention
+from actor_critic_tpu.ops import moe_decode, sparse_attention, topk_select
 
 
 # The kinds of grouped-query layer, as the source's `layer_types` names them.
@@ -672,7 +672,9 @@ def top_k_mask(scores, valid, k: int):
     mask: the `min(k, valid)` largest, ties to the lower position. No sort:
     the k-th largest score is found `RADIX_BITS` bits a pass on the scores'
     bits read as integers in the order of the floats; every key above it is
-    kept, and of the keys equal to it the first ones."""
+    kept, and of the keys equal to it the first ones. The reference of
+    `ops/topk_select.py`'s kernel, which the sparse layers take instead on a
+    TPU (`topk_select.engages`), and their path everywhere else."""
     if k >= scores.shape[-1]:
         return jnp.broadcast_to(valid, scores.shape)
     bits = jax.lax.bitcast_convert_type(
@@ -690,6 +692,16 @@ def top_k_mask(scores, valid, k: int):
     above, tie = key > kth, key == kth
     room = k - jnp.sum(above, axis=-1, keepdims=True)
     return valid & (above | (tie & (jnp.cumsum(tie, axis=-1) <= room)))
+
+
+def _select_prefix(scores, lengths, k: int):
+    """`top_k_mask` of `scores [.., S]` where each row's valid keys are the
+    prefix `[0, lengths)` (`lengths` broadcast to the rows), as the kernel
+    of `ops/topk_select.py`: the same mask, bool."""
+    S = scores.shape[-1]
+    lengths = jnp.broadcast_to(lengths, scores.shape[:-1]).reshape(-1)
+    keep = topk_select.select(jax.lax.stop_gradient(scores).reshape(-1, S), lengths, k)
+    return keep.reshape(scores.shape) != 0
 
 
 def _chunk(size: int, T: int) -> int:
@@ -710,7 +722,9 @@ def dsa_unroll(p, h, positions, cfg: SeqPolicyConfig):
     and the indexer's keys are computed for all rows at once; the queries
     run `GQA_ROWS` episodes a trip, and the indexer selects their keys
     `q_chunk_size` queries a block, the blocks one `lax.map` (a loop the
-    compiler meets once).
+    compiler meets once). A block's selection is the kernel of
+    `ops/topk_select.py` where `topk_select.engages` (a TPU, rows of whole
+    tiles), `top_k_mask` elsewhere: the same mask.
 
     Where `sparse_attention.takes_kernel` (a TPU, shapes that tile), the
     blocks' selections are stacked into the row's `keep [T, T]` and one
@@ -740,9 +754,13 @@ def dsa_unroll(p, h, positions, cfg: SeqPolicyConfig):
         pq = jax.lax.dynamic_slice_in_dim(positions, q0, Q, axis=1)
         with jax.named_scope("dsa_index"):
             scores = index_scores(p["indexer"], hq, pq, keys, cfg)
-            causal = (q0 + jnp.arange(Q))[:, None] >= jnp.arange(T)[None, :]
-            keep = top_k_mask(jax.lax.stop_gradient(scores), causal,
-                              cfg.sa_config.topk)
+            with jax.named_scope("dsa_select"):
+                if topk_select.engages(T, cfg.sa_config.topk):
+                    keep = _select_prefix(scores, q0 + jnp.arange(Q) + 1, cfg.sa_config.topk)
+                else:
+                    causal = (q0 + jnp.arange(Q))[:, None] >= jnp.arange(T)[None, :]
+                    keep = top_k_mask(jax.lax.stop_gradient(scores), causal,
+                                      cfg.sa_config.topk)
         return hq, pq, keep
 
     def tiles_held(keep):  # [e, Q, T] -> [e]
@@ -792,7 +810,8 @@ def dsa_step(p, h, positions, cache, index: int, slot, cfg: SeqPolicyConfig):
     E, kv heads, T, head_dim]` twice and `[layers, E, T, indexer_head_dim]`:
     the token's three go into slot `slot`, the indexer scores every filled
     slot, and attention reads the `topk` it selects (a masked read of the
-    whole cache). Returns (out [E, H], cache)."""
+    whole cache; the selection as in `dsa_unroll`). Returns (out [E, H],
+    cache)."""
     cd = jnp.dtype(cfg.compute_dtype)
     nkv, d = cfg.num_key_value_heads, cfg.head_dim
     k_all, v_all, keys_all = cache
@@ -808,7 +827,11 @@ def dsa_step(p, h, positions, cache, index: int, slot, cfg: SeqPolicyConfig):
             (index, 0, slot, 0))
         scores = index_scores(p["indexer"], h[:, None], positions[:, None],
                               keys_all[index], cfg)[:, 0]
-        keep = top_k_mask(scores, jnp.arange(slots) <= slot, cfg.sa_config.topk)
+        with jax.named_scope("dsa_select"):
+            if topk_select.engages(slots, cfg.sa_config.topk):
+                keep = _select_prefix(scores, slot + 1, cfg.sa_config.topk)
+            else:
+                keep = top_k_mask(scores, jnp.arange(slots) <= slot, cfg.sa_config.topk)
     s = _einsum("ekgd,eksd->ekgs", q, k_all[index], cd) * d ** -0.5
     s = jnp.where(keep[:, None, None], s, -jnp.inf)
     o = _einsum("ekgs,eksd->ekgd", jax.nn.softmax(s, axis=-1), v_all[index], cd)

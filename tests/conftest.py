@@ -19,6 +19,7 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -29,3 +30,18 @@ def new_compile_records(c0: int) -> list:
     from actor_critic_tpu.telemetry import profiler
 
     return profiler.compile_records_since(c0)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One chip of a described (not attached) v5e host, for the TPU's own
+    compiler; described here, inside a test, and nowhere at import time."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])

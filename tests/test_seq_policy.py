@@ -405,21 +405,6 @@ def test_the_decode_takes_the_kernel_where_the_cache_tiles_on_a_tpu(
         mla_decode.mla_decode(*_decode_inputs(4, jnp.float32), 0, 3, 0.125)
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    """One chip of a described (not attached) v5e host, for the TPU's own
-    compiler; described here, inside a test, and nowhere at import time."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
-    except Exception as e:  # no libtpu, or another process holds it
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
 def test_the_rollout_compiled_for_a_v5e_leaves_the_cache_in_hbm(v5e, monkeypatch):
     """`rollout_scan` at the shipped preset's widths and two layers, compiled
     for the described chip. In the scan's body the carried cache pair is at
@@ -546,12 +531,15 @@ def test_the_long_context_rollout_compiled_for_a_v5e_reads_its_experts_by_the_ke
 @pytest.mark.parametrize("kernel", [True, False])
 def test_the_sparse_pass_compiled_for_a_v5e_never_holds_the_scores(v5e, kernel, monkeypatch):
     """One sparse layer of `impala_keye_vl2` as shipped over a row of 8,192,
-    forward and backward, compiled for the described chip. With the kernel
-    (forward, dq and dkv) no buffer holds a block's float32 attention scores
-    `[.., heads, 512, 8192]`: the indexer's one score a pair, `[.., 512,
-    8192]`, is all, and the forward runs once: the row's rematerialization
-    keeps the kernel's residuals. The dense masked blocks, the same program
-    off a TPU, hold them: the test can tell."""
+    forward and backward, compiled for the described chip. With the kernels
+    no buffer holds a block's float32 attention scores `[.., heads, 512,
+    8192]`: the indexer's one score a pair, `[.., 512, 8192]`, is all. Four
+    kernels: attention's forward, dq and dkv, and the selection
+    (`ops/topk_select.py`) once, in the forward's loop over the blocks: the
+    row's rematerialization keeps the kernel's residuals and the row's
+    `keep`, so neither the attention forward nor the selection runs again
+    in the backward pass. The dense masked blocks with the radix select,
+    the same program off a TPU, hold the scores: the test can tell."""
     from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(pallas_scan, "on_tpu", lambda: kernel)
@@ -576,8 +564,10 @@ def test_the_sparse_pass_compiled_for_a_v5e_never_holds_the_scores(v5e, kernel, 
     lead = [np.prod([int(n) for n in m.split(",") if n])
             for m in re.findall(r"f32\[((?:\d+,)*)512,8192\]", text)]
     assert 1 in lead  # the indexer's scores
-    if kernel:  # forward, dq, dkv: the row's rematerialization keeps the forward's residuals
-        assert calls == 3 and set(lead) == {1}
+    selections = [line for line in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line and "topk_select" in line]
+    if kernel:  # forward, dq, dkv and the selection: nothing of the forward runs again
+        assert calls == 4 and len(selections) == 1 and set(lead) == {1}
     else:
         assert calls == 0 and max(lead) == seq.num_attention_heads
 
