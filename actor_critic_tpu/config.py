@@ -34,6 +34,20 @@ class Preset:
     env_kwargs: dict = dataclasses.field(default_factory=dict)
 
 
+# The block of Qwen3-Next-80B-A3B-Instruct as its config.json publishes it,
+# under the source's key names (the counts of the layers, of the experts held
+# and of the vocabulary are each preset's own).
+QWEN3_NEXT = dict(
+    hidden_size=2048, num_attention_heads=16, num_key_value_heads=2, head_dim=256,
+    moe_intermediate_size=512, n_routed_experts=512, num_experts_per_tok=10,
+    scoring_func="softmax", routed_scaling_factor=1.0, n_shared_experts=0,
+    shared_expert_intermediate_size=512, first_k_dense_replace=0,
+    num_hidden_layers=4, full_attention_interval=4, linear_conv_kernel_dim=4,
+    linear_key_head_dim=128, linear_num_key_heads=16, linear_num_value_heads=32,
+    linear_value_head_dim=128, partial_rotary_factor=0.25, rope_theta=10000000.0,
+    gated_attention=True, zero_centered_norm=True,
+)
+
 PRESETS: dict[str, Preset] = {
     # BASELINE.json:7 — the ≥1M env-steps/sec north-star config.
     # lr+entropy annealed to 0 over the run: the flat-coefficient config
@@ -353,6 +367,60 @@ PRESETS: dict[str, Preset] = {
         iterations=300,
         description="impala_keye_vl2 at toy widths (CPU tests and drives)",
         env_kwargs={"vocab_size": 8, "horizon": 16, "prompt_min": 2,
+                    "prompt_max": 3, "prefill_len": 2},
+    ),
+    # Token-level IMPALA over one chip's share of Qwen3-Next-80B-A3B-Instruct
+    # (https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/config.json):
+    # three Gated DeltaNet layers (16 key heads and 32 value heads of 128, a
+    # conv of 4) to one gated full-attention layer (16 query heads over 2
+    # key/value heads of 256, RoPE on a quarter of each head, zero-centred
+    # norms); 512 softmax-routed experts a layer, top-10, renormalised, and a
+    # gated shared expert. 32 chips share each layer: this one holds 16 of
+    # the 512 experts and an eighth of the vocabulary (18,992 rows), and one
+    # period, 4 of the 48 layers. 424.3M parameters, 6.79 GB with the actor
+    # copy, gradients and RMSProp's moment (at 32 experts, 16 chips a layer,
+    # the step passed the chip in `memory_analysis()`). Rows of 16,384: prompts of
+    # 14,336-15,360 tokens whose first 14,336 go through one causal pass
+    # (`prefill_len`), the answer fills the row.
+    "impala_qwen3_next": Preset(
+        algo="impala",
+        env="jax:token_task",
+        config=impala.ImpalaConfig(
+            num_envs=2, rollout_steps=16384, actor_refresh_every=4,
+            gamma=1.0, lr=1e-4, entropy_coef=0.001,
+            seq=SeqPolicyConfig(**QWEN3_NEXT, experts_held=16, expert_offset=0),
+        ),
+        iterations=200,
+        description="Token-level IMPALA over a chip's share of "
+        "Qwen3-Next-80B-A3B (Gated DeltaNet and gated full attention 3:1, "
+        "16 of 512 softmax-routed experts and a gated shared expert, 4 "
+        "layers), prompts prefilled in one pass",
+        env_kwargs={"vocab_size": 18992, "horizon": 16384, "prompt_min": 14336,
+                    "prompt_max": 15360, "prefill_len": 14336},
+    ),
+    # The same program at toy widths: every count of the block in its
+    # published ratio (2 key heads to 4 value heads, 8 query heads to 1
+    # key/value head, RoPE on a quarter of the head, 64 experts, top-10, 4
+    # held), rows of 19 (not a multiple of the chunk), a prompt prefix
+    # prefilled in one pass.
+    "impala_qwen3_next_tiny": Preset(
+        algo="impala",
+        env="jax:token_task",
+        config=impala.ImpalaConfig(
+            num_envs=32, rollout_steps=19, actor_refresh_every=2,
+            gamma=1.0, lr=3e-3, rms_eps=1e-5, entropy_coef=0.003,
+            seq=SeqPolicyConfig(**{
+                **QWEN3_NEXT, "hidden_size": 64, "num_attention_heads": 8,
+                "num_key_value_heads": 1, "head_dim": 16,
+                "linear_key_head_dim": 8, "linear_num_key_heads": 2,
+                "linear_num_value_heads": 4, "linear_value_head_dim": 8,
+                "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+                "n_routed_experts": 64, "compute_dtype": "float32"},
+                experts_held=4),
+        ),
+        iterations=300,
+        description="impala_qwen3_next at toy widths (CPU tests and drives)",
+        env_kwargs={"vocab_size": 8, "horizon": 19, "prompt_min": 2,
                     "prompt_max": 3, "prefill_len": 2},
     ),
     "a3c_pong": Preset(

@@ -38,11 +38,21 @@ configuration runs the same code.
   (ties to the lower position, as `lax.top_k`). What decoding keeps per
   token and layer is the key, the value and `k^I`. The selection is
   discrete, so the indexer gets no gradient from the loss.
+- Hybrid layers (`full_attention_interval`): layer `i` is full grouped-query
+  attention where `(i + 1) % interval == 0`, Gated DeltaNet otherwise
+  (`LINEAR`: `models/gated_delta.py`, a delta-rule recurrence over a matrix
+  state, chunked in the causal pass). Here the full layers may gate their
+  output (`gated_attention`: `sigmoid(x W_gate)` on attention's output,
+  per-head norms on q and k), turn only `partial_rotary_factor` of each
+  head by RoPE, and every norm may be zero-centred (`1 + w`). What decoding
+  keeps of a linear layer is its state and the last inputs of its conv.
 - Expert layer: `s = sigmoid(x W_g)` (float32, all `n_routed_experts`); the
   top `num_experts_per_tok` by `s + b`; weights `s[idx] / sum(s[idx]) x
   routed_scaling_factor`; or `s = softmax(x W_g)` over all the experts, the
   top by `s`, no `b`; `y = sum_i w_i E_i(x) + E_shared(x)` (the shared expert
-  where the layer has one), each expert `down(silu(gate x) * up x)`. The first
+  where the layer has one, times `sigmoid(x w_sg)` where the configuration
+  gives `shared_expert_intermediate_size`), each expert `down(silu(gate x) *
+  up x)`. The first
   `first_k_dense_replace` layers are that MLP at `intermediate_size`, with no
   router.
 - The chip's share: the layer is told `experts_held` and `expert_offset`. It
@@ -121,7 +131,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 import numpy as np
 
+from actor_critic_tpu.models import gated_delta
 from actor_critic_tpu.models.distributions import Categorical
+from actor_critic_tpu.models.gated_delta import LINEAR
+from actor_critic_tpu.models.matmul import einsum as _einsum, mm as _mm
 from actor_critic_tpu.ops.mla_decode import mla_decode_auto
 from actor_critic_tpu.ops import moe_decode, sparse_attention, topk_select
 
@@ -199,6 +212,31 @@ class SeqPolicyConfig:
     # (`SPARSE`, plain RoPE), its q and k heads RMS-normed before RoPE, each
     # query reading the keys an indexer selects; `layer_types` is then ().
     sa_config: SparseAttention | None = None
+    # Set: layer `i` is full grouped-query attention where `(i + 1) %
+    # full_attention_interval == 0` and Gated DeltaNet (`LINEAR`,
+    # `models/gated_delta.py`, the `linear_*` keys below) otherwise, as
+    # `qwen3_next` lays its layers out; `layer_types` is then ().
+    full_attention_interval: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_key_head_dim: int = 128
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_value_head_dim: int = 128
+    # RoPE on the first `partial_rotary_factor x head_dim` dimensions of q
+    # and k only (pairs `(j, j + rotary / 2)`); the rest pass through.
+    partial_rotary_factor: float = 1.0
+    # `qwen3_next`'s full layers: `q_proj` doubles its width for an output
+    # gate (`out = (softmax(s) v * sigmoid(h W_gate)) W_o`, its half stored as
+    # `w_gate`), and q and k are RMS-normed per head before RoPE.
+    gated_attention: bool = False
+    # Every norm of the block (the layer norms, the final one, q and k)
+    # keeps its weight about zero and scales by `1 + w`, as `qwen3_next`'s
+    # RMSNorm does; the weights start at 0.
+    zero_centered_norm: bool = False
+    # Set: the shared expert has this width and its output is scaled by
+    # `sigmoid(h w_sg)` (the Qwen convention whose key this is), in place
+    # of `n_shared_experts x moe_intermediate_size` ungated.
+    shared_expert_intermediate_size: int = 0
     # The chip's share of each expert layer.
     experts_held: int = 256
     expert_offset: int = 0
@@ -216,6 +254,13 @@ class SeqPolicyConfig:
             raise ValueError(f"scoring_func={self.scoring_func!r}: sigmoid or softmax")
         if self.layer_types and self.sa_config is not None:
             raise ValueError("sa_config makes every layer sparse: no layer_types")
+        if self.full_attention_interval and (self.layer_types or self.sa_config):
+            raise ValueError("full_attention_interval lays the layers out: no "
+                             "layer_types, no sa_config")
+        if self.linear_num_value_heads % self.linear_num_key_heads:
+            raise ValueError(
+                f"{self.linear_num_value_heads} value heads do not group over "
+                f"{self.linear_num_key_heads} key heads")
         if self.layer_types:
             kinds = self.layer_types[:self.num_hidden_layers]
             if len(kinds) < self.num_hidden_layers or set(kinds) - set(GQA_KINDS):
@@ -230,10 +275,13 @@ class SeqPolicyConfig:
                     f"{self.head_dim} is odd")
 
     def attention(self, layer: int) -> str:
-        """The kind of layer `layer`'s attention: "mla", one of `GQA_KINDS`
-        or `SPARSE`."""
+        """The kind of layer `layer`'s attention: "mla", one of `GQA_KINDS`,
+        `SPARSE` or `LINEAR`."""
         if self.sa_config is not None:
             return SPARSE
+        if self.full_attention_interval:
+            return "full_attention" if (layer + 1) % self.full_attention_interval == 0 \
+                else LINEAR
         return self.layer_types[layer] if self.layer_types else "mla"
 
     @property
@@ -244,11 +292,17 @@ class SeqPolicyConfig:
     def grouped(self) -> bool:
         """Grouped-query layers (a cache of keys and values a kind, a
         prefill), not latent attention."""
-        return bool(self.layer_types) or self.sa_config is not None
+        return bool(self.layer_types or self.full_attention_interval) \
+            or self.sa_config is not None
 
     def cache_index(self, layer: int) -> int:
         """Which layer of its kind's stacked cache a grouped-query layer is."""
         return self.kinds[:layer].count(self.kinds[layer])
+
+    @property
+    def rotary_dim(self) -> int:
+        """Dimensions of a grouped-query head that RoPE turns."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def latent_dim(self) -> int:
@@ -258,7 +312,8 @@ class SeqPolicyConfig:
 
 # The scope round the attention half of a layer of each kind.
 SCOPE_OF = {"mla": "mla", "sliding_attention": "attn_window",
-            "full_attention": "attn_full", SPARSE: "attn_sparse"}
+            "full_attention": "attn_full", SPARSE: "attn_sparse",
+            LINEAR: "attn_linear"}
 # The floor under the sum of the chosen scores, as the source's code has it.
 _EPS = 1e-20
 # `e_score_correction_bias`: a seeded, non-zero, untrained buffer, uniform in
@@ -273,6 +328,7 @@ ATTN_ROWS = 8       # episodes a trip of the causal pass's attention
 # keys of its band.
 GQA_ROWS = 1        # episodes a trip
 ATTN_QUERIES = 512  # queries a block
+LINEAR_ROWS = 1     # episodes a trip of the linear layers' causal pass
 MLP_ROWS = 8192     # token rows a trip of the dense MLP and the shared expert
 HEAD_ROWS = 4096    # token rows a trip of the lm_head
 MOE_ROWS = 24576    # held assignments a trip of the grouped matmuls
@@ -311,20 +367,33 @@ def init_params(key: jax.Array, cfg: SeqPolicyConfig, vocab_size: int) -> dict:
         return {"w_gate": mat(*lead, H, width), "w_up": mat(*lead, H, width),
                 "w_down": mat(*lead, width, H)}
 
+    def uniform(shape, lo, hi):
+        return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
+
+    norm = jnp.zeros if cfg.zero_centered_norm else jnp.ones
+
     p: dict[str, Any] = {
         "embed": jax.random.normal(next(keys), (vocab_size, H), jnp.float32)
     }
     for i in range(cfg.num_hidden_layers):
         layer = {
-            "attn_norm": jnp.ones((H,), jnp.float32),
-            "ffn_norm": jnp.ones((H,), jnp.float32),
+            "attn_norm": norm((H,), jnp.float32),
+            "ffn_norm": norm((H,), jnp.float32),
         }
-        if cfg.attention(i) != "mla":
+        if cfg.attention(i) == LINEAR:
+            layer["linear"] = gated_delta.init_params(mat, uniform, cfg)
+        elif cfg.attention(i) != "mla":
             kv = cfg.num_key_value_heads * cfg.head_dim
             layer["attn"] = {
                 "w_q": mat(H, nh * cfg.head_dim), "w_k": mat(H, kv),
                 "w_v": mat(H, kv), "w_o": mat(nh * cfg.head_dim, H),
             }
+            if cfg.gated_attention:
+                layer["attn"].update({
+                    "w_gate": mat(H, nh * cfg.head_dim),
+                    "q_norm": norm((cfg.head_dim,), jnp.float32),
+                    "k_norm": norm((cfg.head_dim,), jnp.float32),
+                })
             if cfg.attention(i) == SPARSE:
                 sa = cfg.sa_config
                 layer["attn"].update({
@@ -360,11 +429,14 @@ def init_params(key: jax.Array, cfg: SeqPolicyConfig, vocab_size: int) -> dict:
                     -BIAS_SCALE, BIAS_SCALE)
             layer["moe"]["experts"] = mlp(
                 cfg.experts_held, width=cfg.moe_intermediate_size)
-            if cfg.n_shared_experts:
+            if cfg.shared_expert_intermediate_size:
+                layer["moe"]["shared"] = mlp(width=cfg.shared_expert_intermediate_size)
+                layer["moe"]["shared_gate"] = mat(H, 1)
+            elif cfg.n_shared_experts:
                 layer["moe"]["shared"] = mlp(
                     width=cfg.n_shared_experts * cfg.moe_intermediate_size)
         p[f"layer_{i}"] = layer
-    p["final_norm"] = jnp.ones((H,), jnp.float32)
+    p["final_norm"] = norm((H,), jnp.float32)
     p["lm_head"] = mat(H, vocab_size, scale=0.01)
     p["value_head"] = {"kernel": mat(H, 1), "bias": jnp.zeros((1,), jnp.float32)}
     return {"params": p}
@@ -377,18 +449,10 @@ def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def _mm(x, w, cd, out=jnp.float32):
-    """`x @ w` over the last axis: `compute_dtype` operands, float32 sums.
-    `out=cd` hands the result on rounded, where it is a matmul operand only."""
-    return jax.lax.dot_general(
-        x.astype(cd), w.astype(cd), (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(out)
-
-
-def _einsum(spec, a, b, cd):
-    return jnp.einsum(spec, a.astype(cd), b.astype(cd),
-                      preferred_element_type=jnp.float32)
+def _norm(cfg: SeqPolicyConfig, x, w):
+    """The block's RMSNorm of `x` by the weight `w` (`1 + w` where the
+    configuration's norms are zero-centred)."""
+    return _rms(x, 1.0 + w if cfg.zero_centered_norm else w, cfg.rms_norm_eps)
 
 
 def _rope(x, positions, theta):
@@ -500,7 +564,7 @@ def rope_frequencies(cfg: SeqPolicyConfig, kind: str):
     `inv_freq_j / factor`, a linear ramp over the pair index between the two
     blends the rest, at every position; cos and sin are multiplied by
     `attention_factor`."""
-    d = cfg.head_dim
+    d = cfg.rotary_dim
     inv = cfg.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
     if kind != "full_attention" or cfg.rope_factor == 1.0:
         return inv.astype(np.float32), 1.0
@@ -531,16 +595,30 @@ def _rope_halves(x, positions, freq):
 
 
 def _gqa_project(p, h, positions, cfg, kind, which: str):
-    """`h W_<which>` as `[.., heads, head_dim]`, RoPE applied to q and k,
-    after the per-head RMSNorm where the layer has one (`q_norm`, `k_norm`)."""
+    """`h W_<which>` as `[.., heads, head_dim]`, RoPE applied to q and k (to
+    their first `rotary_dim` dimensions), after the per-head RMSNorm where
+    the layer has one (`q_norm`, `k_norm`)."""
     cd = jnp.dtype(cfg.compute_dtype)
     y = _mm(h, p[f"w_{which}"], cd, out=cd)
     y = y.reshape(*h.shape[:-1], -1, cfg.head_dim)
     if which == "v":
         return y
     if f"{which}_norm" in p:
-        y = _rms(y, p[f"{which}_norm"], cfg.rms_norm_eps)
-    return _rope_halves(y, positions[..., None], rope_frequencies(cfg, kind)).astype(cd)
+        y = _norm(cfg, y, p[f"{which}_norm"])
+    freq = rope_frequencies(cfg, kind)
+    rot = cfg.rotary_dim
+    if rot == cfg.head_dim:
+        return _rope_halves(y, positions[..., None], freq).astype(cd)
+    turned = _rope_halves(y[..., :rot], positions[..., None], freq)
+    return jnp.concatenate([turned, y[..., rot:].astype(jnp.float32)], axis=-1).astype(cd)
+
+
+def _gate(p, h, out, cd):
+    """`out [.., heads x head_dim]` times the output gate `sigmoid(h W_gate)`
+    where the layer has one."""
+    if "w_gate" not in p:
+        return out
+    return out * jax.nn.sigmoid(_mm(h, p["w_gate"], cd))
 
 
 def window_of(cfg: SeqPolicyConfig, kind: str, horizon: int) -> int:
@@ -573,7 +651,8 @@ def gqa_unroll(p, h, positions, cfg: SeqPolicyConfig, kind: str):
         ahead = jnp.arange(q0, q1)[:, None] - jnp.arange(lo, q1)[None, :]
         s = jnp.where((ahead >= 0) & (ahead < window), s, -jnp.inf)
         out = _einsum("ekgqs,eskd->eqkgd", jax.nn.softmax(s, axis=-1), v[:, lo:q1], cd)
-        return _mm(out.reshape(*out.shape[:2], -1), p["w_o"], cd)
+        out = _gate(p, h[:, q0:q1], out.reshape(*out.shape[:2], -1), cd)
+        return _mm(out, p["w_o"], cd)
 
     def attend(h, k, v, positions):
         return jnp.concatenate([
@@ -603,7 +682,7 @@ def gqa_step(p, h, positions, cache, index: int, slot, cfg: SeqPolicyConfig, kin
     s = _einsum("ekgd,eksd->ekgs", q, k_all[index], cd) * d ** -0.5
     s = jnp.where(jnp.arange(slots) <= slot, s, -jnp.inf)
     o = _einsum("ekgs,eksd->ekgd", jax.nn.softmax(s, axis=-1), v_all[index], cd)
-    return _mm(o.reshape(h.shape[0], -1), p["w_o"], cd), (k_all, v_all)
+    return _mm(_gate(p, h, o.reshape(h.shape[0], -1), cd), p["w_o"], cd), (k_all, v_all)
 
 
 def gqa_fill(cache, index: int, k, v, keys=None):
@@ -778,7 +857,7 @@ def dsa_unroll(p, h, positions, cfg: SeqPolicyConfig):
         # float32 before its one cast.
         q = _mm(h, p["w_q"], cd, out=cd).reshape(*h.shape[:2], nkv, -1, d)
         if "q_norm" in p:
-            q = _rms(q, p["q_norm"], cfg.rms_norm_eps)
+            q = _norm(cfg, q, p["q_norm"])
         q = _rope_halves(q, positions[..., None, None], rope_frequencies(cfg, SPARSE))
         out = sparse_attention.attend((q * d ** -0.5).astype(cd), k, v, keep,
                                       block_q=Q, block_kv=K)
@@ -983,8 +1062,14 @@ def moe(p, h, cfg: SeqPolicyConfig):
             p["experts"], h, weights.reshape(A), (order, ends, sizes, landed))
         read = None
     if "shared" in p:
+        def shared(x):
+            out = _swiglu(p["shared"], x, cd)
+            if "shared_gate" in p:
+                out = out * jax.nn.sigmoid(_mm(x, p["shared_gate"], cd))
+            return out
+
         with jax.named_scope("moe_shared"):
-            y = y + _map_rows(lambda x: _swiglu(p["shared"], x, cd), MLP_ROWS, h)
+            y = y + _map_rows(shared, MLP_ROWS, h)
     mean_load = jnp.maximum(landed, 1).astype(jnp.float32) / held
     return y, {
         "routed_here_frac": landed.astype(jnp.float32) / A,
@@ -1022,8 +1107,22 @@ def init_cache(cfg: SeqPolicyConfig, num_envs: int, horizon: int):
     head_dim]` a kind, `horizon` slots a row for the full layers and a ring
     of `sliding_window` for the window layers: `{kind: (k, v)}`. Sparse
     layers keep the indexer's keys beside them, `[layers, E, horizon,
-    indexer_head_dim]`: `{SPARSE: (k, v, index keys)}`."""
+    indexer_head_dim]`: `{SPARSE: (k, v, index keys)}`. Linear layers keep
+    their state and conv tail (`models/gated_delta.py`), a size that does not
+    grow with `horizon`, beside the full layers' keys and values: `{LINEAR:
+    (state [layers, E, n_v, d_k, d_v] float32, tail [layers, E, K - 1,
+    channels]), "full_attention": (k, v)}`."""
     cd = jnp.dtype(cfg.compute_dtype)
+    if cfg.full_attention_interval:
+        linear = cfg.kinds.count(LINEAR)
+        state = (linear, num_envs, cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+        tail = (linear, num_envs, cfg.linear_conv_kernel_dim - 1,
+                gated_delta.conv_channels(cfg))
+        kv = (cfg.kinds.count("full_attention"), num_envs, cfg.num_key_value_heads,
+              horizon, cfg.head_dim)
+        return {LINEAR: (jnp.zeros(state, jnp.float32), jnp.zeros(tail, cd)),
+                "full_attention": (jnp.zeros(kv, cd), jnp.zeros(kv, cd))}
     if cfg.sa_config is not None:
         lead = (cfg.num_hidden_layers, num_envs)
         kv = (*lead, cfg.num_key_value_heads, horizon, cfg.head_dim)
@@ -1054,9 +1153,13 @@ def step(params, obs, cache, cfg: SeqPolicyConfig):
     for i, layer in enumerate(layers):
         kind = cfg.attention(i)
         with jax.named_scope(SCOPE_OF[kind]):
-            h = _rms(x, layer["attn_norm"], cfg.rms_norm_eps)
+            h = _norm(cfg, x, layer["attn_norm"])
             if kind == "mla":
                 a, cache = mla_step(layer["mla"], h, positions, cache, i, slot, cfg)
+            elif kind == LINEAR:
+                a, kv = gated_delta.step(layer["linear"], h, cache[kind],
+                                         cfg.cache_index(i), cfg)
+                cache = {**cache, kind: kv}
             elif kind == SPARSE:
                 a, kv = dsa_step(layer["attn"], h, positions, cache[kind],
                                  cfg.cache_index(i), slot, cfg)
@@ -1066,11 +1169,11 @@ def step(params, obs, cache, cfg: SeqPolicyConfig):
                                  cfg.cache_index(i), slot, cfg, kind)
                 cache = {**cache, kind: kv}
             x = x + a
-        y, _, read = _ffn(layer, _rms(x, layer["ffn_norm"], cfg.rms_norm_eps), cfg)
+        y, _, read = _ffn(layer, _norm(cfg, x, layer["ffn_norm"]), cfg)
         x = x + y
         if read is not None:
             reads.append(read)
-    h = _rms(x, p["final_norm"], cfg.rms_norm_eps)
+    h = _norm(cfg, x, p["final_norm"])
     with jax.named_scope("lm_head"):
         logits = _mm(h, p["lm_head"], cd)
     read = jnp.mean(jnp.stack(reads)) if reads else None
@@ -1085,7 +1188,9 @@ def _value(p, h):
 def trunk(params, obs, cfg: SeqPolicyConfig, cache=None):
     """The causal pass over `obs [E, T, 3]`: (final-normed hidden [E, T, H],
     the expert layers' stats and the sparse layers' `sparse_tiles_frac`,
-    layers-mean, `cache`). A cache of grouped-query
+    layers-mean, and the linear layers' `gdn_chunk_logdecay_min`, the least
+    sum of the decay's exponent over a chunk of any layer, head and row,
+    `cache`). A cache of grouped-query or linear
     layers (`init_cache`'s) is returned as this pass over positions `[0, T)`
     leaves it (the prefill); None stays None.
 
@@ -1104,9 +1209,13 @@ def trunk(params, obs, cfg: SeqPolicyConfig, cache=None):
 
     def attn(kind, layer, x):
         with jax.named_scope(SCOPE_OF[kind]):
-            h = _rms(x, layer["attn_norm"], cfg.rms_norm_eps)
+            h = _norm(cfg, x, layer["attn_norm"])
             if kind == "mla":
                 return x + mla_unroll(layer["mla"], h, positions, cfg), None, None
+            if kind == LINEAR:
+                a, state, lowest = _map_rows(
+                    lambda h: gated_delta.unroll(layer["linear"], h, cfg), LINEAR_ROWS, h)
+                return x + a, state, jnp.min(lowest)
             if kind == SPARSE:
                 a, kv, held = dsa_unroll(layer["attn"], h, positions, cfg)
                 return x + a, kv, held
@@ -1114,18 +1223,21 @@ def trunk(params, obs, cfg: SeqPolicyConfig, cache=None):
             return x + a, kv, None
 
     def ffn(layer, x):
-        h = _rms(x, layer["ffn_norm"], cfg.rms_norm_eps).reshape(E * T, -1)
+        h = _norm(cfg, x, layer["ffn_norm"]).reshape(E * T, -1)
         y, stats, _ = _ffn(layer, h, cfg)
         return x + y.reshape(x.shape), stats
 
-    stats, held = [], []
+    stats, held, lowest = [], [], []
     for i, layer in enumerate(layers):
         kind = cfg.attention(i)
-        x, kv, tiles = jax.checkpoint(attn, static_argnums=0)(kind, layer, x)
+        x, kv, counted = jax.checkpoint(attn, static_argnums=0)(kind, layer, x)
         if cache is not None:
-            cache = {**cache, kind: gqa_fill(cache[kind], cfg.cache_index(i), *kv)}
-        if tiles is not None:
-            held.append(tiles)
+            fill = gated_delta.fill if kind == LINEAR else gqa_fill
+            cache = {**cache, kind: fill(cache[kind], cfg.cache_index(i), *kv)}
+        if kind == LINEAR:
+            lowest.append(counted)
+        elif counted is not None:
+            held.append(counted)
         x, s = jax.checkpoint(ffn)(layer, x)
         if s is not None:
             stats.append(s)
@@ -1133,7 +1245,9 @@ def trunk(params, obs, cfg: SeqPolicyConfig, cache=None):
         if stats else {}
     if held:
         mean["sparse_tiles_frac"] = jnp.mean(jnp.stack(held))
-    return _rms(x, p["final_norm"], cfg.rms_norm_eps), mean, cache
+    if lowest:
+        mean["gdn_chunk_logdecay_min"] = jnp.min(jnp.stack(lowest))
+    return _norm(cfg, x, p["final_norm"]), mean, cache
 
 
 def logits_and_values(params, obs, cfg: SeqPolicyConfig):
@@ -1236,6 +1350,8 @@ def make_policy(spec, cfg: SeqPolicyConfig, horizon: int):
                 / (horizon * (horizon + 1) / 2)),
             "prefill_frac": jnp.float32(spec.prefill_len / horizon),
         }
+    elif cfg.full_attention_interval:
+        shape_stats = {"prefill_frac": jnp.float32(spec.prefill_len / horizon)}
     elif cfg.layer_types:
         kept = [min(t + 1, window_of(cfg, kind, horizon))
                 for kind in cfg.layer_types[:cfg.num_hidden_layers]
