@@ -100,10 +100,13 @@ MLA are alive a trip at a time, in the backward pass too. The update therefore
 runs a trip of attention forward twice (the forward pass, the trip's own
 rematerialization) and the FFN likewise; the cut between the halves is there
 so that rebuilding the FFN's input does not run attention a third time. A
-grouped-query layer's trip is `GQA_ROWS` episodes, not rematerialized as a
-whole: inside it every block of `ATTN_QUERIES` queries is, and a block takes
-the keys of its band only (`gqa_unroll`), so a row of 4,096 fits and a window
-layer never multiplies what it would mask.
+grouped-query layer's trip is `GQA_ROWS` episodes. On a TPU where the row
+tiles, its causal pass is one kernel a row under the layer's band
+(`gqa_unroll`, `ops/sparse_attention.py`), which never writes the scores and
+keeps its output and log-sum-exp for the backward pass. Elsewhere the trip is
+not rematerialized as a whole: inside it every block of `ATTN_QUERIES`
+queries is, and a block takes the keys of its band only, so a row of 4,096
+fits and a window layer never multiplies what it would mask.
 
 The cache is a carry of the rollout scan and starts fresh with it, so an
 episode must be exactly one unroll and every row at the same position: the
@@ -325,9 +328,10 @@ ATTN_ROWS = 8       # episodes a trip of the causal pass's attention
 # The grouped-query layers' causal pass also blocks over queries, so that a
 # row of 4,096 positions fits: a trip holds the `[heads, ATTN_QUERIES, keys]`
 # scores of `GQA_ROWS` episodes, and a window layer's block meets only the
-# keys of its band.
+# keys of its band. The kernel that takes the pass on a TPU tiles the row's
+# square by `ATTN_QUERIES` queries and keys.
 GQA_ROWS = 1        # episodes a trip
-ATTN_QUERIES = 512  # queries a block
+ATTN_QUERIES = 512  # queries a block; the kernel's tile
 LINEAR_ROWS = 1     # episodes a trip of the linear layers' causal pass
 MLP_ROWS = 8192     # token rows a trip of the dense MLP and the shared expert
 HEAD_ROWS = 4096    # token rows a trip of the lm_head
@@ -594,10 +598,11 @@ def _rope_halves(x, positions, freq):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
-def _gqa_project(p, h, positions, cfg, kind, which: str):
+def _gqa_project(p, h, positions, cfg, kind, which: str, scale: float = 1.0):
     """`h W_<which>` as `[.., heads, head_dim]`, RoPE applied to q and k (to
     their first `rotary_dim` dimensions), after the per-head RMSNorm where
-    the layer has one (`q_norm`, `k_norm`)."""
+    the layer has one (`q_norm`, `k_norm`); q or k times `scale` in float32
+    before the one cast to `compute_dtype`."""
     cd = jnp.dtype(cfg.compute_dtype)
     y = _mm(h, p[f"w_{which}"], cd, out=cd)
     y = y.reshape(*h.shape[:-1], -1, cfg.head_dim)
@@ -608,9 +613,11 @@ def _gqa_project(p, h, positions, cfg, kind, which: str):
     freq = rope_frequencies(cfg, kind)
     rot = cfg.rotary_dim
     if rot == cfg.head_dim:
-        return _rope_halves(y, positions[..., None], freq).astype(cd)
-    turned = _rope_halves(y[..., :rot], positions[..., None], freq)
-    return jnp.concatenate([turned, y[..., rot:].astype(jnp.float32)], axis=-1).astype(cd)
+        y = _rope_halves(y, positions[..., None], freq)
+    else:
+        turned = _rope_halves(y[..., :rot], positions[..., None], freq)
+        y = jnp.concatenate([turned, y[..., rot:].astype(jnp.float32)], axis=-1)
+    return (y if scale == 1.0 else y * scale).astype(cd)
 
 
 def _gate(p, h, out, cd):
@@ -632,10 +639,19 @@ def gqa_unroll(p, h, positions, cfg: SeqPolicyConfig, kind: str):
     (k, v) each `[E, T, kv heads, head_dim]` in `compute_dtype`, the keys
     after RoPE: what a cache keeps). Keys and values are computed for all
     rows at once; the queries, the scores and the output projection run
-    `GQA_ROWS` episodes a trip and, inside a trip, `ATTN_QUERIES` queries a
-    block, each block rematerialized in the backward pass. A block of
-    queries `[q0, q1)` takes the keys `[q0 - window + 1, q1)` and no others:
-    a window layer never multiplies what lies outside its band."""
+    `GQA_ROWS` episodes a trip. A query `t` attends the keys `[t - window +
+    1, t]` and no others.
+
+    Where `sparse_attention.takes_kernel` (a TPU, rows of whole
+    `ATTN_QUERIES` tiles, heads of whole lanes), a trip's queries are
+    projected whole and ONE kernel a row attends under the band, a static
+    mask (`sparse_attention.attend_band`): a tile outside the band is
+    neither computed nor fetched, no score reaches HBM. The trip is
+    rematerialized in the backward pass but for the kernel's residuals.
+    Elsewhere (the CPU, the tiny presets) a trip runs `ATTN_QUERIES` queries
+    a block, each block rematerialized in the backward pass; a block of
+    queries `[q0, q1)` takes the keys `[q0 - window + 1, q1)` only, so a
+    window layer never multiplies what lies outside its band."""
     cd = jnp.dtype(cfg.compute_dtype)
     nkv, d = cfg.num_key_value_heads, cfg.head_dim
     T = h.shape[1]
@@ -654,12 +670,25 @@ def gqa_unroll(p, h, positions, cfg: SeqPolicyConfig, kind: str):
         out = _gate(p, h[:, q0:q1], out.reshape(*out.shape[:2], -1), cd)
         return _mm(out, p["w_o"], cd)
 
-    def attend(h, k, v, positions):
+    def blocks(h, k, v, positions):
         return jnp.concatenate([
             jax.checkpoint(functools.partial(block, q0, min(q0 + ATTN_QUERIES, T)))(
                 h, k, v, positions)
             for q0 in range(0, T, ATTN_QUERIES)], axis=1)
 
+    def kernel(h, k, v, positions):
+        q = _gqa_project(p, h, positions, cfg, kind, "q", scale=d ** -0.5)
+        with jax.named_scope("attn_causal"):
+            out = sparse_attention.attend_band(
+                q.reshape(*q.shape[:2], nkv, -1, d), k, v, window=window,
+                block_q=ATTN_QUERIES, block_kv=ATTN_QUERIES)
+        return _mm(_gate(p, h, out.reshape(*h.shape[:2], -1), cd), p["w_o"], cd)
+
+    if sparse_attention.takes_kernel(T, d, ATTN_QUERIES, ATTN_QUERIES):
+        attend = jax.checkpoint(kernel, policy=jax.checkpoint_policies.save_only_these_names(
+            sparse_attention.RESIDUALS))
+    else:
+        attend = blocks
     return _map_rows(attend, GQA_ROWS, h, k, v, positions, remat=False), (k, v)
 
 
